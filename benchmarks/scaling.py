@@ -78,8 +78,8 @@ import json
 import os
 import sys
 
-# Force the virtual CPU mesh BEFORE jax backend init (common.py honors
-# JAX_PLATFORMS=cpu; set both here so a bare invocation works too).
+# Force the virtual CPU mesh BEFORE jax is imported, so a bare invocation
+# works too.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
@@ -93,19 +93,15 @@ from xprof import collective_overlap, ensure_cpu_op_events  # noqa: E402
 
 ensure_cpu_op_events()
 
-from common import median_ratio, slope_time_paired, sync  # noqa: E402  (sets backend)
+from common import median_ratio, slope_time_paired, sync  # noqa: E402
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import optax  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-import horovod_tpu  # noqa: E402,F401  (installs jax API-drift shims first)
-from jax import shard_map  # noqa: E402  (compat-installed on older jax)
+from jax import shard_map  # noqa: E402
 
 S_SHORT, S_LONG = 4, 16
 LLAMA_S_SHORT, LLAMA_S_LONG = 8, 40   # longer: averages per-call dispatch
@@ -141,7 +137,7 @@ def _resnet_arms(hvd, rng, loss_fn):
         dopt = distributed(optax.sgd(0.1, momentum=0.9),
                            axis_name=axis_name)
         state = create_train_state(model, jax.random.PRNGKey(0), images[:1],
-                                   dopt)
+                                   dopt, mesh=mesh)
         steps = {k: make_train_step(model, dopt, loss_fn, mesh=mesh,
                                     axis_name=axis_name,
                                     scan_steps=k, donate=False,

@@ -23,10 +23,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from horovod_tpu.platform import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 import numpy as np
 import torch  # noqa: F401  (torch API init expects it importable)
 

@@ -23,8 +23,6 @@ import sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(
     _os.path.abspath(__file__))))  # run in-repo without pip install
 
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 
 import flax.linen as nn
 from flax.linen import partitioning as nn_partitioning

@@ -12,14 +12,6 @@ and membership changes (graceful generation restart with state carried via
 persisted commits).
 """
 
-import os
-
-# Honor an explicit CPU request before any computation: some images
-# pre-register an accelerator plugin, where the env var alone is not enough.
-if os.environ.get("JAX_PLATFORMS") == "cpu":
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -31,9 +31,6 @@ import sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(
     _os.path.abspath(__file__))))  # run in-repo without pip install
 
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
-
 import jax
 import numpy as np
 import optax

@@ -29,18 +29,12 @@ import sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(
     _os.path.abspath(__file__))))  # run in-repo without pip install
 
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import horovod_tpu as hvd
 from horovod_tpu.parallel import (create_mesh, pipeline_1f1b_value_and_grad,

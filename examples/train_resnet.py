@@ -24,9 +24,6 @@ import sys as _sys
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(
     _os.path.abspath(__file__))))  # run in-repo without pip install
 
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -94,7 +91,7 @@ def main():
     for i in range(args.warmup):
         state, loss = step(state, images, labels)
     if args.warmup:
-        float(loss)  # value fetch: a real sync even on remote-tunnel backends
+        float(loss)  # value fetch: waits for the device
     t0 = time.perf_counter()
     for i in range(args.steps):
         state, loss = step(state, images, labels)

@@ -18,8 +18,24 @@ Two ways to use the collectives:
   global mesh, for startup broadcast, tools and parity tests.
 """
 
-from . import compat
-compat.install()  # before collectives/train import shard_map (see compat.py)
+import os as _os
+
+import jax as _jax
+
+
+def _place_compile_cache() -> None:
+    """Give JAX's persistent compilation cache a home every entry point
+    shares. ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and
+    nothing is touched. Unset: ``<checkout>/.jax_cache`` — a fixed path,
+    because a directory that moves between runs never hits."""
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    checkout = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
+    _jax.config.update("jax_compilation_cache_dir",
+                       _os.path.join(checkout, ".jax_cache"))
+
+
+_place_compile_cache()
 
 from . import collectives, core
 from .collectives import (Adasum, Average, Compression, Max, Min, Product,

@@ -35,22 +35,14 @@ Check ids (documented in docs/analysis.md):
   ``(cross..., intra)`` convention (intra = last mesh axis rides ICI).
 """
 
+import contextlib
 from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
 import jax
-from jax import core as jax_core
-
-try:  # location of Jaxpr/ClosedJaxpr classes is stable here, but be safe
-    from jax._src import core as _src_core
-except ImportError:  # pragma: no cover
-    _src_core = jax_core
-
-try:
-    from jax._src import source_info_util as _source_info
-except ImportError:  # pragma: no cover
-    _source_info = None
+from jax._src import core as _src_core
+from jax._src import source_info_util as _source_info
 
 from .findings import Finding, Severity
 
@@ -76,14 +68,10 @@ class CollectiveCall(NamedTuple):
 
 
 def _loc(eqn) -> Tuple[str, int]:
-    if _source_info is not None:
-        try:
-            frame = _source_info.user_frame(eqn.source_info)
-            if frame is not None:
-                return frame.file_name, frame.start_line
-        except Exception:
-            pass
-    return "<unknown>", 0
+    frame = _source_info.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return "<unknown>", 0
+    return frame.file_name, frame.start_line
 
 
 def _axis_names(eqn) -> Tuple[str, ...]:
@@ -370,8 +358,13 @@ def analyze_step(fn, *args,
     names for steps whose collectives are NOT wrapped in an in-trace
     ``shard_map`` (axis names are then checked against ``mesh.axis_names``).
     """
+    # A concrete mesh is made ambient for the whole trace: a step that
+    # would enter set_mesh itself cannot do so under make_jaxpr.
+    ambient = jax.sharding.set_mesh(mesh) \
+        if isinstance(mesh, jax.sharding.Mesh) else contextlib.nullcontext()
     try:
-        closed = _closed_jaxpr_of(fn, *args, **kwargs)
+        with ambient:
+            closed = _closed_jaxpr_of(fn, *args, **kwargs)
     except NameError as e:
         # jax raises at trace time for axis names bound nowhere at all
         # ("unbound axis name: X") — fold it into the same finding the

@@ -108,10 +108,6 @@ def init(devices: Optional[Sequence[jax.Device]] = None,
                         float(os.environ["HOROVOD_START_TIMEOUT"]))
                 except (TypeError, ValueError):
                     pass
-            # Multi-process CPU meshes need gloo collectives; older jax
-            # defaults them off (compat.py has the full story).
-            from .. import compat
-            compat.enable_multiprocess_cpu_collectives()
             jax.distributed.initialize(
                 coordinator_address=coord, num_processes=nproc,
                 process_id=pid, **kw)

@@ -585,9 +585,9 @@ class StepMonitor:
         if dt > 0:
             from ..tools import perf as _perf
             flops = _perf.registered_step_flops(what)
-            if flops:
-                _telemetry.set_gauge("hvd_step_mfu_proxy",
-                                     _perf.mfu_proxy(flops, dt), what=what)
+            proxy = _perf.mfu_proxy(flops, dt) if flops else None
+            if proxy is not None:
+                _telemetry.set_gauge("hvd_step_mfu_proxy", proxy, what=what)
         _telemetry.record_event("step_end", what=what, step=n,
                                 seconds=round(dt, 6))
 

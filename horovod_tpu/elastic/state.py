@@ -352,8 +352,7 @@ class _LeafRef:
     """Placeholder leaf inside a pickled pytree *skeleton*: an index into
     the manifest's leaf-blob list. Pickling the skeleton (the original
     containers with ``_LeafRef`` leaves) instead of a ``PyTreeDef`` keeps
-    the on-disk format independent of jax's treedef pickling across the
-    versions compat.py bridges."""
+    the on-disk format independent of jax's treedef pickling."""
 
     __slots__ = ("index",)
 

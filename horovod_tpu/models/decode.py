@@ -545,7 +545,7 @@ def _shard_mapped(cfg, mesh, axis, body, n_pools, n_extra, n_outs):
     """Wrap ``body`` in shard_map lazily — the param PartitionSpec tree
     needs the concrete params structure, so construction happens on first
     call (and jit caches the result by tracing, not by wrapper identity)."""
-    from jax import shard_map               # backfilled by horovod_tpu.compat
+    from jax import shard_map
     pool_s = kv_pool_spec(axis)
 
     def wrapped(params, *args):

@@ -189,10 +189,8 @@ def build_sparse_training(model, cfg, mesh, rules, params, *,
     import optax
     from jax.experimental.layout import Format, Layout
     from jax.sharding import NamedSharding, PartitionSpec as P
-    try:  # UNSPECIFIED = "let XLA choose" (None would mean "replicate")
-        from jax._src.sharding_impls import UNSPECIFIED as _u
-    except ImportError:  # pragma: no cover - older/newer jax fallback
-        _u = None
+    # UNSPECIFIED = "let XLA choose" (None would mean "replicate")
+    from jax._src.sharding_impls import UNSPECIFIED as _u
 
     dense_params = {k: v for k, v in params.items()
                     if k != "embedding_tables"}

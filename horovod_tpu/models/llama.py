@@ -186,10 +186,7 @@ def _seq_parallel_attention(q, k, v, impl: str, scale: float):
     mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or "sp" not in mesh.axis_names or mesh.shape["sp"] == 1:
         return None
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel import ring_attention, ulysses_attention

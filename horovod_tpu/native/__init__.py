@@ -7,9 +7,10 @@ prefetch/memcpy machinery (the fusion buffer's MEMCPY_IN role) applied to
 the TPU's real host bottleneck: the input pipeline. See
 ``src/hvd_runtime.cc``.
 
-Everything degrades gracefully: if no C++ toolchain is present,
-:func:`available` is False and :class:`RecordPipeline` transparently uses
-the pure-numpy fallback with identical semantics (the tests run both).
+If no C++ compiler is present at all, :func:`available` is False and
+:class:`RecordPipeline` uses the pure-numpy path with identical semantics
+(the tests run both). A compiler that is present and fails to build the
+library is an error at load (``build.py``), not a quiet downgrade.
 """
 
 from __future__ import annotations
@@ -31,12 +32,13 @@ def _load() -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib is not None or _tried:
             return _lib
-        _tried = True
         if os.environ.get("HOROVOD_DISABLE_NATIVE", "").lower() in (
                 "1", "true", "yes", "on"):
+            _tried = True
             return None
         from .build import build
-        path = build()
+        path = build()  # raises when a compiler is present and fails
+        _tried = True
         if path is None:
             return None
         lib = ctypes.CDLL(path)

@@ -33,8 +33,11 @@ def lib_path() -> str:
     return os.path.join(_BUILD_DIR, f"libhvd_runtime_{_source_hash()}.so")
 
 
-def build(quiet: bool = True) -> Optional[str]:
-    """Compile (if needed) and return the .so path; None if no toolchain."""
+def build() -> Optional[str]:
+    """Compile (if needed) and return the .so path. None only when the
+    machine has no C++ compiler at all; a compiler that is present and
+    fails raises — a quietly slower numpy path is not a fallback anyone
+    asked for."""
     out = lib_path()
     if os.path.exists(out):
         return out
@@ -43,20 +46,17 @@ def build(quiet: bool = True) -> Optional[str]:
     if cxx is None:
         return None
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    tmp = out + ".tmp.so"
+    # per-process temp name: workers of one job may build concurrently
+    tmp = f"{out}.{os.getpid()}.tmp.so"
     cmd = [cxx, *CXX_FLAGS, _SRC, "-o", tmp]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True,
                               timeout=300)
-    except (OSError, subprocess.TimeoutExpired):
-        return None
+    except (OSError, subprocess.TimeoutExpired) as e:
+        raise RuntimeError(
+            f"native build could not run:\n{' '.join(cmd)}\n{e}") from e
     if proc.returncode != 0:
-        if not quiet:
-            raise RuntimeError(
-                f"native build failed:\n{' '.join(cmd)}\n{proc.stderr}")
-        from ..core.logging import get_logger
-        get_logger().warning("native build failed (falling back to pure "
-                             "python): %s", proc.stderr.strip()[:500])
-        return None
+        raise RuntimeError(
+            f"native build failed:\n{' '.join(cmd)}\n{proc.stderr}")
     os.replace(tmp, out)
     return out

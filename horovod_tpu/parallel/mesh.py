@@ -47,14 +47,9 @@ def create_mesh(axis_sizes: Dict[str, int],
             f"mesh axes {dict(zip(names, sizes))} require {total} devices, "
             f"have {len(devices)}")
     from jax.experimental import mesh_utils
-    try:
-        arr = mesh_utils.create_device_mesh(
-            sizes, devices=devices,
-            allow_split_physical_axes=allow_split_physical_axes)
-    except TypeError:
-        # Older jax without allow_split_physical_axes; topology-aware
-        # placement still applies.
-        arr = mesh_utils.create_device_mesh(sizes, devices=devices)
+    arr = mesh_utils.create_device_mesh(
+        sizes, devices=devices,
+        allow_split_physical_axes=allow_split_physical_axes)
     return Mesh(arr, tuple(names))
 
 

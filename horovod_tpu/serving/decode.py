@@ -317,10 +317,8 @@ class DecodeEngine:
             # around the page gathers (the r4 DLRM trap).
             from jax.experimental.layout import Format, Layout
             from jax.sharding import NamedSharding, PartitionSpec as P
-            try:  # UNSPECIFIED = "let XLA choose" (None would replicate)
-                from jax._src.sharding_impls import UNSPECIFIED as _u
-            except ImportError:  # pragma: no cover - jax version drift
-                _u = None
+            # UNSPECIFIED = "let XLA choose" (None would replicate)
+            from jax._src.sharding_impls import UNSPECIFIED as _u
             pool_nd = NamedSharding(mesh, MD.kv_pool_spec(tp_axis))
             pool_fmt = Format(Layout((0, 1, 2, 3, 4)), pool_nd)
             self._kp = jax.device_put(self._kp, pool_nd)
@@ -386,11 +384,12 @@ class DecodeEngine:
 
     def _current(self):
         """(params, seq) from the registry, install_params, or the
-        constructor params — one RCU read, no lock on the step path."""
-        if self.registry is not None:
-            cur = self.registry.current()
-            if cur is None:
-                return None, None
+        constructor params — one RCU read, no lock on the step path. A
+        registry that has published nothing yet does not hide weights the
+        engine was built with (``InferenceServer`` hands every engine its
+        registry)."""
+        cur = self.registry.current() if self.registry is not None else None
+        if cur is not None:
             return cur.payload, cur.manifest_seq
         if getattr(self, "_installed", None) is not None:
             return self._installed, self._installed_seq

@@ -260,8 +260,6 @@ SOAK_WORKER = """
 import json
 import os
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import numpy as np
 import horovod_tpu as hvd
 from horovod_tpu import elastic
@@ -311,8 +309,6 @@ print(json.dumps({"final_step": state.step, "size": hvd.size(),
 SOAK_REPLICA = """
 import os
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import numpy as np
 from horovod_tpu.checkpoint.store import BlobStore
 from horovod_tpu.elastic.service import CoordinatorClient

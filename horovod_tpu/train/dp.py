@@ -21,12 +21,9 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from ..core import context_api as _ctx
 from ..core import sentinel as _sentinel
@@ -48,18 +45,35 @@ class TrainState(NamedTuple):
 
 def create_train_state(model, rng, sample_input,
                        optimizer: optax.GradientTransformation,
-                       broadcast: bool = True) -> TrainState:
+                       broadcast: bool = True, mesh=None) -> TrainState:
     """Init variables + optimizer state; broadcast from rank-0's process so
-    all hosts agree (reference: ``hvd.broadcast_parameters`` at startup)."""
-    variables = model.init(rng, sample_input, train=False)
+    all hosts agree (reference: ``hvd.broadcast_parameters`` at startup).
+
+    The state is returned replicated over ``mesh`` (default: the context
+    mesh) — pass the same mesh you give ``make_train_step``."""
+    # One compiled program: eager flax init dispatches (and compiles) every
+    # initializer and forward op on its own, which on an accelerator is
+    # most of the wait before the first step.
+    variables = jax.jit(lambda r, x: model.init(r, x, train=False))(
+        rng, sample_input)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
     if broadcast:
         params = broadcast_parameters(params)
         batch_stats = broadcast_parameters(batch_stats)
     opt_state = optimizer.init(params)
-    return TrainState(jnp.zeros((), jnp.int32), params, opt_state,
-                      batch_stats)
+    state = TrainState(jnp.zeros((), jnp.int32), params, opt_state,
+                       batch_stats)
+    if mesh is None and _ctx.is_initialized():
+        mesh = _ctx.mesh()
+    if mesh is not None:
+        # Committed to the mesh, like every state the step returns. An
+        # uncommitted state is a different input TYPE to jit than the
+        # mesh-committed one that comes back, so the second call traced,
+        # lowered and compiled the whole step again (one v5e, cold:
+        # +27 s ResNet-50, +52 s BERT-Large — PERF.md, PR 21).
+        state = jax.device_put(state, NamedSharding(mesh, P()))
+    return state
 
 
 def make_train_step(model, optimizer: optax.GradientTransformation,

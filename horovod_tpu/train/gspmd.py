@@ -218,6 +218,10 @@ def _build_gspmd_step(model, mesh, rules, *, optimizer=None, pair=None,
             _flops_hook.append(True)
             _maybe_register_step_flops(lower, "gspmd_train_step",
                                        scan_steps or 1, (state, tokens), {})
+        if jax.sharding.get_abstract_mesh() == mesh.abstract_mesh:
+            # already ambient (a caller's own set_mesh, or hvd-analyze
+            # tracing this step: jax forbids set_mesh inside a trace)
+            return inner(state, tokens)
         with jax.sharding.set_mesh(mesh):
             return inner(state, tokens)
 

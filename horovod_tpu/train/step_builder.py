@@ -44,10 +44,7 @@ import numpy as np
 import optax
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from ..core import sentinel as _sentinel
 from ..core import telemetry as _telemetry

@@ -25,7 +25,7 @@ class BuildWithNative(build_py):
         sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
         try:
             from horovod_tpu.native import build as native_build
-            lib = native_build.build(quiet=True)
+            lib = native_build.build()
             if lib:
                 print(f"built native runtime: {lib}")
             else:

@@ -14,11 +14,8 @@ This module only BUILDS traceable functions (args are
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-import horovod_tpu  # noqa: F401  (installs the shard_map compat shim)
-from jax import shard_map  # noqa: E402  (needs the shim on old jax)
 
 
 def _mesh():

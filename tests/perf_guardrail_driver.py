@@ -30,9 +30,6 @@ from profiling_common import compiled_step_flops, ensure_cpu_op_events  # noqa: 
 ensure_cpu_op_events()
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import optax  # noqa: E402

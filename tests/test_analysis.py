@@ -206,7 +206,6 @@ def test_sweep_collectives_barrier_clean():
 LINT_CASES = [
     ("bad_xla_flags.py", "lint-xla-flags", "error"),
     ("bad_torch_seed.py", "lint-torch-seed", "warning"),
-    ("bad_platform_pin.py", "lint-late-platform-pin", "warning"),
     ("bad_slope_cadence.py", "lint-slope-cadence", "warning"),
     ("bad_silent_rpc.py", "lint-silent-rpc", "warning"),
     ("bad_unguarded_apply.py", "jax-unguarded-apply", "warning"),

@@ -1,7 +1,7 @@
 """Bench-parity regression tests (VERDICT r5 Weak #1 → ISSUE 17).
 
-BENCH_r05's ``vs_baseline`` 0.9631 fell outside the stated ±0.02 band.
-The bisect suspicion was that the r5 train.py deferral change
+A driver reading of ``vs_baseline`` 0.9631 once fell outside the stated
+±0.02 band. The bisect suspicion was that the r5 train.py deferral change
 (``make_gspmd_deferred_train_step``) taxed ``make_train_step``. The
 graph-level facts that rule that out permanently are now declared in
 the contract registry (``horovod_tpu/analysis/contracts.py``) and
@@ -10,8 +10,7 @@ driven thin from here:
 1. ``bench-arms-parity``: bench.py's two arms (hvd DistributedOptimizer
    step vs plain step) compile to programs with byte-identical — and on
    the bench's 1-device mesh, EMPTY — collective-op sets; any measured
-   ratio shift is NOISE, not graph tax (see docs/benchmarks.md
-   "Parity band").
+   ratio shift is NOISE, not graph tax.
 2. ``gspmd-deferred-every1``: the deferred factory at ``every=1`` emits
    collective HLO signature-identical to the standard GSPMD step it
    wraps — the deferral is graph-level inert at k=1.
@@ -26,7 +25,6 @@ from __future__ import annotations
 
 import pytest
 
-import horovod_tpu  # noqa: F401  (compat shims before any jax use)
 from horovod_tpu.analysis import contracts
 
 

@@ -593,7 +593,6 @@ def test_load_persisted_world_legacy_single_frame_fallback(tmp_path):
 _TORN_WORKER = textwrap.dedent("""
     import os, sys
     import jax
-    jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import horovod_tpu
     from horovod_tpu import elastic

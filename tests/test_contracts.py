@@ -18,7 +18,6 @@ import os
 import subprocess
 import sys
 
-import horovod_tpu  # noqa: F401  (compat shims before any jax use)
 from horovod_tpu.analysis import analyze_rank_divergence, contracts
 from horovod_tpu.analysis.__main__ import main as analysis_main
 from horovod_tpu.analysis.hlo import HloCollective, LayoutMove

@@ -78,8 +78,6 @@ ARBITER_VICTIM = """
 import json
 import os
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 from horovod_tpu.elastic.arbiter import ArbiterPolicy, FleetArbiter
 from horovod_tpu.elastic.service import CoordinatorService
 
@@ -214,8 +212,6 @@ def test_coordinator_sigkill_mid_rebalance_replays_same_fleet(tmp_path):
 REPLICA_WORKER = """
 import os
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import numpy as np
 from horovod_tpu.checkpoint.store import BlobStore
 from horovod_tpu.elastic.service import CoordinatorClient

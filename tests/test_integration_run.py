@@ -14,8 +14,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = """
 import json
 import os
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import horovod_tpu as hvd
 hvd.init()
 print(json.dumps({
@@ -75,8 +73,6 @@ def test_hvdrun_output_filename_redirects(tmp_path):
 
 MP_WORKER = """
 import json
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import jax
 import numpy as np
 import horovod_tpu as hvd
@@ -171,8 +167,6 @@ def test_hvdrun_two_process_collectives(tmp_path):
 
 MP3_WORKER = """
 import json
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import torch
 import horovod_tpu as hvd
 from horovod_tpu import torch as thvd
@@ -218,8 +212,6 @@ HIER_WORKER = """
 import json
 import os
 os.environ["HOROVOD_HIERARCHICAL_ALLREDUCE"] = "1"
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import jax
 import numpy as np
 import horovod_tpu as hvd
@@ -320,8 +312,6 @@ def test_hvdrun_elastic_relaunches_failed_generation(tmp_path):
 WATCHDOG_WORKER = """
 import os
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import horovod_tpu as hvd
 hvd.init()
 from horovod_tpu.core.exceptions import HorovodInternalError
@@ -382,8 +372,6 @@ def test_watchdog_unblocks_survivor_of_silent_peer(tmp_path):
 CHAOS_WORKER = """
 import json
 import os
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import horovod_tpu as hvd
 from horovod_tpu import elastic
 from horovod_tpu.optimizer import allgather_object
@@ -455,8 +443,6 @@ def test_elastic_sigkill_mid_collective_shrinks_and_resumes(tmp_path):
 JIT_CHAOS_WORKER = """
 import json
 import os
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import jax
 import numpy as np
 import horovod_tpu as hvd
@@ -610,8 +596,6 @@ GROW_WORKER = """
 import json
 import os
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import horovod_tpu as hvd
 from horovod_tpu import elastic
 from horovod_tpu.optimizer import allgather_object
@@ -694,8 +678,6 @@ RESUME_MESH_WORKER = """
 import json
 import os
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import horovod_tpu as hvd
 from horovod_tpu import elastic
 from horovod_tpu.elastic.state import last_resume_stats
@@ -846,8 +828,6 @@ def test_hvdrun_timeline_flag_reaches_worker(tmp_path):
 
 TF_WORKER = """
 import json
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import numpy as np
 import horovod_tpu as hvdj
 hvdj.init()   # brings up jax.distributed from the launcher's env
@@ -950,8 +930,6 @@ import time
 # Python signal handler either), leaving only the push and the driver's
 # 5s SIGKILL escalation — and the push wins by seconds.
 signal.signal(signal.SIGTERM, signal.SIG_IGN)
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import jax
 import numpy as np
 import horovod_tpu as hvd
@@ -1155,8 +1133,6 @@ def test_flaky_control_plane_during_elastic_resize(tmp_path):
 
 BADSIG_WORKER = """
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import horovod_tpu as hvd
 from horovod_tpu import elastic
 
@@ -1206,8 +1182,6 @@ def test_tampered_coordinator_reply_detected_in_real_run(tmp_path):
 
 LOST_WORKER = """
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import horovod_tpu as hvd
 from horovod_tpu import elastic
 
@@ -1284,8 +1258,6 @@ import time
 # fate-sharing SIGTERM, whose default handler would die without dumping.
 # A rank wedged inside the compiled runtime couldn't run a handler either.
 signal.signal(signal.SIGTERM, signal.SIG_IGN)
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import horovod_tpu as hvd
 from horovod_tpu import elastic
 from horovod_tpu.core import watchdog
@@ -1380,8 +1352,6 @@ def test_chaos_kill_produces_cross_rank_incident_report(tmp_path):
 SENTINEL_NAN_WORKER = """
 import json
 import numpy as np
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import jax
 import flax.linen as nn
 import optax
@@ -1485,8 +1455,6 @@ SENTINEL_DESYNC_WORKER = """
 import json
 import os
 import numpy as np
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import jax
 import jax.numpy as jnp
 import flax.linen as nn
@@ -1649,8 +1617,6 @@ PREEMPT_CHAOS_WORKER = """
 import json
 import os
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import horovod_tpu as hvd
 from horovod_tpu import elastic
 from horovod_tpu.optimizer import allgather_object

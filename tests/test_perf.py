@@ -177,7 +177,7 @@ def test_attribute_logdir_record_schema(tmp_path):
     assert "mfu" not in rec
 
 
-def test_categorize_budget_taxonomy():
+def test_categorize_budget_categories():
     cases = {"%dot.12 = f32[8,8] dot(...)": "matmul/conv",
              "convolution.3": "matmul/conv",
              "gather.1": "gather/scatter",
@@ -302,9 +302,8 @@ def _headline_rec(value, band=None, **extra):
 
 
 def test_headline_vs_baseline_rails_against_parity_not_best():
-    # railed against parity (ideal 1.0), NOT best-ever: the r05-style
-    # 0.9631 after a 0.9999 passes — cross-session noise, not regression
-    # (band derivation: BASELINE.md §"Headline vs_baseline noise band")
+    # railed against parity (ideal 1.0), NOT best-ever: a 0.9631 after a
+    # 0.9999 passes — inside the band, not a regression
     ok, msgs = perf.ratchet_check([_headline_rec(0.9999),
                                    _headline_rec(0.9631)])
     assert ok
@@ -501,14 +500,11 @@ def test_cli_subprocess_entry_point(tmp_path):
 
 # --- FLOPs registry + MFU proxy ----------------------------------------------
 
-def test_mfu_proxy_math_and_fallbacks(monkeypatch):
+def test_mfu_proxy_math_and_unknown_peak():
     assert perf.mfu_proxy(1e12, 1.0, peak=2e12) == pytest.approx(0.5)
-    # unknown peak: HOROVOD_PEAK_FLOPS env wins, else 1e12 (reads as
-    # achieved TFLOP/s)
-    monkeypatch.setenv("HOROVOD_PEAK_FLOPS", "5e11")
-    assert perf.mfu_proxy(1e12, 1.0, peak=float("nan")) == pytest.approx(2.0)
-    monkeypatch.delenv("HOROVOD_PEAK_FLOPS")
-    assert perf.mfu_proxy(1e12, 1.0, peak=float("nan")) == pytest.approx(1.0)
+    # unknown peak: no number at all — never an invented one
+    assert perf.mfu_proxy(1e12, 1.0, peak=float("nan")) is None
+    assert perf.mfu_proxy(1e12, 1.0) is None   # the CPU mesh has no peak
 
 
 def test_register_step_flops_rejects_garbage():
@@ -546,7 +542,8 @@ def test_step_span_sets_wall_and_data_wait_gauges():
 
 
 def test_monitored_call_publishes_mfu_proxy_gauge(monkeypatch):
-    monkeypatch.setenv("HOROVOD_PEAK_FLOPS", "1e12")
+    # a known chip: the CPU mesh itself has no peak, hence no gauge
+    monkeypatch.setitem(perf._peak_cache, "default", 197e12)
     mon = watchdog.monitor()
     perf.register_step_flops(2e9, what="perf_mfu")
     assert mon.monitored_call(lambda: 7, what="perf_mfu") == 7
@@ -559,6 +556,12 @@ def test_monitored_call_publishes_mfu_proxy_gauge(monkeypatch):
     assert mon.monitored_call(lambda: 8, what="perf_noflops") == 8
     assert reg.gauge_value("hvd_step_mfu_proxy",
                            what="perf_noflops") is None
+    # unknown device kind -> no proxy gauge either
+    monkeypatch.setitem(perf._peak_cache, "default", float("nan"))
+    perf.register_step_flops(2e9, what="perf_nopeak")
+    assert mon.monitored_call(lambda: 9, what="perf_nopeak") == 9
+    assert reg.gauge_value("hvd_step_mfu_proxy",
+                           what="perf_nopeak") is None
 
 
 # --- coordinator /metrics fleet rollup ---------------------------------------
@@ -650,7 +653,7 @@ def test_perf_gauges_overhead_within_bound():
         model = Wide()
         dopt = distributed(optax.sgd(0.1))
         state = create_train_state(model, jax.random.PRNGKey(0),
-                                   images[:1], dopt)
+                                   images[:1], dopt, mesh=mesh1)
         step = make_train_step(model, dopt, _xent, mesh=mesh1,
                                axis_name=hvd.RANK_AXIS, sentinel=False)
         box = {"state": state}

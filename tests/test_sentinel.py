@@ -105,7 +105,7 @@ def test_health_vector_gathers_per_rank_rows(mesh8):
     per-rank fingerprint lane that exposes replica divergence."""
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.compat import shard_map
+    from jax import shard_map
 
     def f(x):
         # x: per-rank shard; use it as both grads and "params" so each
@@ -599,7 +599,7 @@ def test_sentinel_overhead_within_noise():
         model = Wide()
         dopt = distributed(optax.sgd(0.1))
         state = create_train_state(model, jax.random.PRNGKey(0),
-                                   images[:1], dopt)
+                                   images[:1], dopt, mesh=mesh1)
         step = make_train_step(model, dopt, _xent, mesh=mesh1,
                                axis_name=hvd.RANK_AXIS, sentinel=sentinel)
         box = {"state": state}

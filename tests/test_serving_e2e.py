@@ -37,8 +37,6 @@ E2E_WORKER = """
 import json
 import os
 import time
-from horovod_tpu.platform import honor_jax_platforms_env
-honor_jax_platforms_env()
 import numpy as np
 import horovod_tpu as hvd
 from horovod_tpu import elastic, serving

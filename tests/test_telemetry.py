@@ -396,7 +396,7 @@ def test_telemetry_overhead_within_bound():
         model = Wide()
         dopt = distributed(optax.sgd(0.1))
         state = create_train_state(model, jax.random.PRNGKey(0),
-                                   images[:1], dopt)
+                                   images[:1], dopt, mesh=mesh1)
         step = make_train_step(model, dopt, _xent, mesh=mesh1,
                                axis_name=hvd.RANK_AXIS, sentinel=False)
         box = {"state": state}

@@ -23,7 +23,6 @@ family per pytest process.
 
 import pytest
 
-import horovod_tpu  # noqa: F401  (compat shims before any jax use)
 from horovod_tpu.analysis import contracts
 from wire_accounting import collective_wire_costs
 
