@@ -37,6 +37,7 @@ from jax.sharding import Mesh
 from .config import Config
 from .exceptions import NotInitializedError
 from .logging import get_logger
+from . import telemetry as _telemetry
 from .process_sets import ProcessSet, ProcessSetTable
 
 #: Name of the mesh axis that plays the role of the Horovod rank axis.
@@ -89,6 +90,7 @@ def init(devices: Optional[Sequence[jax.Device]] = None,
     with _lock:
         if _context is not None:
             return _context
+        _telemetry.install_compile_listener()
         coord = coordinator_address or os.environ.get("HOROVOD_COORDINATOR_ADDR")
         # NOTE: jax.distributed.initialize must run before ANY call that
         # initialises the XLA backend (incl. jax.process_count/jax.devices),

@@ -14,6 +14,11 @@ dies.  This module is the TPU rebuild's single telemetry surface:
   heartbeats, elastic generation changes, checkpoint commit/restore,
   coordinator RPC retries) that dumps atomically to
   ``flight_<rank>.jsonl`` on abnormal exit;
+* :func:`span` — the one span helper: a bounded in-memory log of
+  ``(name, seq, t0_ns, t1_ns, thread_id)`` on the singleton, one
+  ``hvd_span_seconds{name=}`` histogram, and a ``TraceAnnotation`` so the
+  span sits in a running profiler session on the device trace's clock
+  (docs/telemetry.md lists every span the library emits);
 * Prometheus text rendering (served by the coordinator at
   ``GET /metrics``) and a compact cumulative-delta export pushed to the
   coordinator piggybacked on the existing poll cadence;
@@ -29,12 +34,16 @@ or ``np.asarray`` on traced values inside a step loop — hvd-analyze's
 
 from __future__ import annotations
 
+import bisect
 import json
 import os
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
+
+import jax.monitoring
+from jax.profiler import TraceAnnotation
 
 from .logging import get_logger
 
@@ -43,6 +52,7 @@ RING_ENV = "HOROVOD_TELEMETRY_RING"
 FLIGHT_DIR_ENV = "HOROVOD_FLIGHT_DIR"
 
 DEFAULT_RING = 256
+SPAN_LOG = 16384
 MAX_SERIES_PER_METRIC = 64
 DEFAULT_BUCKETS = (0.001, 0.005, 0.02, 0.1, 0.5, 2.0, 10.0, 60.0)
 
@@ -122,7 +132,12 @@ class Registry:
         return True
 
     def inc(self, name: str, value: float = 1.0, **labels: Any) -> None:
-        sid = _series_id(name, labels)
+        self.inc_series(name, _series_id(name, labels), value)
+
+    def inc_series(self, name: str, sid: str, value: float = 1.0) -> None:
+        """``inc`` for a caller that keeps its series id (``series_id``): on
+        the step loop's thread, where Python runs once a step and
+        cache-cold, rendering the id costs several times the update."""
         with self._lock:
             if not self._admit(self._counters, name, sid):
                 return
@@ -137,10 +152,14 @@ class Registry:
             self._gauges[sid] = float(value)
             self._dirty.add(("g", sid))
 
-    def observe(self, name: str, value: float,
+    def observe(self, name: str, value: float, /,
                 buckets: Optional[Tuple[float, ...]] = None,
                 **labels: Any) -> None:
-        sid = _series_id(name, labels)
+        self.observe_series(name, _series_id(name, labels), value, buckets)
+
+    def observe_series(self, name: str, sid: str, value: float,
+                       buckets: Optional[Tuple[float, ...]] = None) -> None:
+        """``observe`` for a caller that keeps its series id."""
         with self._lock:
             bounds = self._hist_bounds.get(name)
             if bounds is None:
@@ -152,10 +171,7 @@ class Registry:
             if counts is None:
                 counts = [0] * (len(bounds) + 1)
                 self._hist_counts[sid] = counts
-            i = 0
-            while i < len(bounds) and value > bounds[i]:
-                i += 1
-            counts[i] += 1
+            counts[bisect.bisect_left(bounds, value)] += 1
             self._hist_sum[sid] = self._hist_sum.get(sid, 0.0) + value
             self._hist_n[sid] = self._hist_n.get(sid, 0) + 1
             self._dirty.add(("h", sid))
@@ -314,6 +330,8 @@ class Telemetry:
         self.rank = _env_rank() if rank is None else int(rank)
         self.registry = Registry()
         self.ring = FlightRecorder(ring_size)
+        # (name, seq, t0_ns, t1_ns, thread_id); append is atomic, no lock
+        self.span_log: deque = deque(maxlen=SPAN_LOG)
         self._dump_lock = threading.Lock()
 
 
@@ -360,6 +378,17 @@ def inc(name: str, value: float = 1.0, **labels: Any) -> None:
         t.registry.inc(name, value, **labels)
 
 
+def series_id(name: str, /, **labels: Any) -> str:
+    """The id of one labelled series, for ``inc_series``."""
+    return _series_id(name, labels)
+
+
+def inc_series(name: str, sid: str, value: float = 1.0) -> None:
+    t = active()
+    if t.enabled:
+        t.registry.inc_series(name, sid, value)
+
+
 def set_gauge(name: str, value: float, **labels: Any) -> None:
     t = active()
     if t.enabled:
@@ -376,6 +405,133 @@ def record_event(kind: str, **fields: Any) -> None:
     t = active()
     if t.enabled:
         t.ring.record(kind, **fields)
+
+
+# -- spans ----------------------------------------------------------------
+
+class _Span:
+    """One open span: ``time.perf_counter_ns`` on entry and on exit, then
+    the log and the histogram."""
+
+    __slots__ = ("_t", "_annotation", "name", "seq", "t0")
+
+    def __init__(self, t: Telemetry, name: str, seq: Optional[int]):
+        self._t = t
+        self._annotation = TraceAnnotation("hvd:" + name)
+        self.name = name
+        self.seq = seq
+
+    def __enter__(self) -> "_Span":
+        self._annotation.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
+        _log_span(self._t, self.name, self.seq, self.t0, t1,
+                  threading.get_ident())
+
+
+class _NoSpan:
+    """What ``span()`` returns with telemetry off: reads no clock."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
+_span_series: Dict[str, str] = {}  # span name -> its histogram's series id
+
+
+def _observe_span(t: Telemetry, name: str, seconds: float) -> None:
+    sid = _span_series.get(name)
+    if sid is None:
+        sid = _span_series[name] = series_id("hvd_span_seconds", name=name)
+    t.registry.observe_series("hvd_span_seconds", sid, seconds)
+
+
+def _log_span(t: Telemetry, name: str, seq: Optional[int], t0: int, t1: int,
+              thread: int) -> None:
+    t.span_log.append((name, seq, t0, t1, thread))
+    _observe_span(t, name, (t1 - t0) / 1e9)
+
+
+def span(name: str, seq: Optional[int] = None):
+    """Context manager round one piece of a step's life. ``seq`` is the
+    ordinal of the batch or step it belongs to: what the spans of one step
+    share. Parentage is by containment on one thread (a layer's self time
+    is its span less what its children cover)."""
+    t = active()
+    return _Span(t, name, seq) if t.enabled else _NO_SPAN
+
+
+def record_span(name: str, seq: Optional[int], t0_ns: int, t1_ns: int,
+                thread: Optional[int] = None) -> None:
+    """Log a span whose two clock reads (``time.perf_counter_ns``) were
+    taken elsewhere, on ``thread``: what ``span()`` does on exit, for a
+    caller that times now and accounts later, or is told a duration."""
+    t = active()
+    if t.enabled:
+        _log_span(t, name, seq, t0_ns, t1_ns,
+                  threading.get_ident() if thread is None else thread)
+
+
+def spans(name: Optional[str] = None) -> List[Tuple]:
+    """The span log, oldest first, as
+    ``(name, seq, t0_ns, t1_ns, thread_id)``; ``name`` filters."""
+    log = list(active().span_log)
+    return log if name is None else [s for s in log if s[0] == name]
+
+
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    # includes a persistent-cache load
+    "/jax/core/compile/backend_compile_duration": "compile.backend",
+}
+# JAX reports a trace for every jitted helper traced inside another trace
+# (8,000 in BERT-Large's first step, 0.2 s together, nearly all nested in
+# the step's own): under this they go to the histogram and not to the log,
+# which they would otherwise fill.
+MIN_TRACE_SPAN_S = 1e-3
+_compile_listener_installed = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_: Any) -> None:
+    name = _COMPILE_SPANS.get(event)
+    t = active()
+    if name is None or not t.enabled:
+        return
+    if name == "compile.trace" and duration_secs < MIN_TRACE_SPAN_S:
+        _observe_span(t, name, duration_secs)
+        return
+    t1 = time.perf_counter_ns()
+    _log_span(t, name, None, t1 - int(duration_secs * 1e9), t1,
+              threading.get_ident())
+    if name == "compile.backend":
+        t.registry.inc("hvd_compiles_total")
+        t.ring.record("compile", seconds=round(duration_secs, 6),
+                      hvd_last_step=t.registry.gauge_value("hvd_last_step"))
+
+
+def install_compile_listener() -> None:
+    """Turn JAX's own compile-duration events into ``compile.*`` spans
+    (``t1`` = arrival, ``t0`` = ``t1 - duration``). JAX keeps listeners
+    for the life of the process, so this registers once."""
+    global _compile_listener_installed
+    with _lock:
+        if _compile_listener_installed:
+            return
+        _compile_listener_installed = True
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def export_delta() -> Optional[Dict[str, Dict[str, float]]]:

@@ -150,10 +150,6 @@ class StepMonitor:
         self._client = None        # CoordinatorClient, lazy
         self._client_missing = False
         self._telemetry_pushed = 0.0  # last piggybacked metrics push
-        # Monotonic time the previous monitored step finished: the gap to
-        # the next step_begin is the host-side data wait
-        # (hvd_step_data_wait_seconds) — input pipeline + python glue.
-        self._last_step_end: Optional[float] = None
 
     # -- configuration (re-read per step: tests and drivers set env late) --
 
@@ -537,7 +533,7 @@ class StepMonitor:
             with self._lock:
                 started = self._inflight_since = time.monotonic()
                 self._inflight_what = what
-            self._note_step_begin(what, started)
+            _telemetry.record_event("step_begin", what=what)
             if self.peer_watch_available():
                 self._ensure_watcher()
             try:
@@ -553,19 +549,6 @@ class StepMonitor:
 
     # -- the monitored call -------------------------------------------------
 
-    def _note_step_begin(self, what: str, now: float) -> None:
-        """Step-entry telemetry: the gap since the previous step's end is
-        the host-side data wait (input pipeline, python glue between
-        steps) — exported as the ``hvd_step_data_wait_seconds`` gauge of
-        the ISSUE 11 perf-attribution plane. Host clocks only, never a
-        device fetch."""
-        with self._lock:
-            last_end = self._last_step_end
-        if last_end is not None:
-            _telemetry.set_gauge("hvd_step_data_wait_seconds",
-                                 max(now - last_end, 0.0), what=what)
-        _telemetry.record_event("step_begin", what=what)
-
     def _note_step_done(self, what: str, started: Optional[float]) -> None:
         """Per-step telemetry: counters/histogram plus a ring event. All
         inputs are host scalars the monitor already holds — never a
@@ -577,7 +560,6 @@ class StepMonitor:
         dt = (end - started) if started is not None else 0.0
         with self._lock:
             n = self._steps_completed
-            self._last_step_end = end
         _telemetry.inc("hvd_steps_total", what=what)
         _telemetry.observe("hvd_step_seconds", dt, what=what)
         _telemetry.set_gauge("hvd_last_step", n)
@@ -650,7 +632,7 @@ class StepMonitor:
             # resize re-earn this via reset_for_recovery).
             first_of_signature = self._completed_by_what.get(what, 0) == 0
         scale = self.compile_mult if first_of_signature else 1.0
-        self._note_step_begin(what, begun)
+        _telemetry.record_event("step_begin", what=what)
         try:
             if not self.armed():
                 out = fn()

@@ -22,13 +22,18 @@ three host-side pieces a training loop needs, TPU-shaped:
 
 from __future__ import annotations
 
+import collections
 import queue
 import threading
+import time
 from typing import Any, Callable, Iterable, Iterator, Optional
 
+import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .core import context_api as _ctx
+from .core import telemetry as _telemetry
 
 
 def shard_batch(batch: Any, mesh=None, axis: Optional[str] = None):
@@ -40,7 +45,6 @@ def shard_batch(batch: Any, mesh=None, axis: Optional[str] = None):
     leading dim is ``local_batch * process_count``. Single-process: one
     ``device_put`` with the sharded layout.
     """
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = mesh if mesh is not None else _ctx.mesh()
@@ -55,8 +59,25 @@ def shard_batch(batch: Any, mesh=None, axis: Optional[str] = None):
         lambda x: jax.device_put(np.asarray(x), sharding), batch)
 
 
+def _account_waits(waits: "collections.deque") -> None:
+    """Record the hand-outs the consumer has timed and not yet accounted:
+    the ``input.wait`` span, the data-wait gauge (the wait measured where it
+    happens) and the ready share's two counters."""
+    while True:
+        try:
+            seq, t0, t1, ready, thread = waits.popleft()
+        except IndexError:  # empty, or the other drainer took the last
+            return
+        _telemetry.record_span("input.wait", seq, t0, t1, thread)
+        _telemetry.set_gauge("hvd_step_data_wait_seconds", (t1 - t0) / 1e9)
+        _telemetry.inc("hvd_input_batches_total")
+        if ready:
+            _telemetry.inc("hvd_input_ready_total")
+
+
 def _prefetch_worker(it: Iterator, transfer: Callable, q: "queue.Queue",
-                     stop: threading.Event, done: object) -> None:
+                     stop: threading.Event, done: object,
+                     waits: "collections.deque") -> None:
     def put(item) -> bool:
         while not stop.is_set():
             try:
@@ -66,10 +87,27 @@ def _prefetch_worker(it: Iterator, transfer: Callable, q: "queue.Queue",
                 continue
         return False
 
+    # The worker's busy time a batch is input.produce (the source: a
+    # Dataset's gather) plus input.transfer (shard_batch: the host's share
+    # of the copy, device_put returns before the DMA ends); both carry the
+    # batch's ordinal, which input.wait carries when it is handed out. The
+    # consumer only times its waits; this thread does their bookkeeping.
     try:
-        for batch in it:
-            if stop.is_set() or not put(transfer(batch)):
+        seq = 0
+        while True:
+            _account_waits(waits)
+            with _telemetry.span("input.produce", seq):
+                batch = next(it, done)
+            if batch is done:
+                break
+            _telemetry.inc("hvd_input_bytes_total", sum(
+                getattr(x, "nbytes", 0)
+                for x in jax.tree_util.tree_leaves(batch)))
+            with _telemetry.span("input.transfer", seq):
+                batch = transfer(batch)
+            if stop.is_set() or not put(batch):
                 return
+            seq += 1
         put(done)
     except BaseException as e:  # re-raised on the consumer side
         put(e)
@@ -101,9 +139,14 @@ class Prefetcher:
         self._q: "queue.Queue" = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self._dead = False
+        self._handed = 0  # ordinal of the next batch handed out
+        # (seq, t0_ns, t1_ns, was ready, thread) of hand-outs not yet
+        # accounted; the worker drains it, close() what is left
+        self._waits: "collections.deque" = collections.deque()
         self._thread = threading.Thread(
             target=_prefetch_worker,
-            args=(iter(it), transfer, self._q, self._stop, self._DONE),
+            args=(iter(it), transfer, self._q, self._stop, self._DONE,
+                  self._waits),
             daemon=True)
         self._thread.start()
 
@@ -113,13 +156,33 @@ class Prefetcher:
     def __next__(self):
         if self._dead:
             raise StopIteration
-        item = self._q.get()
-        if item is self._DONE:
-            self._dead = True
-            raise StopIteration
-        if isinstance(item, BaseException):
+        timed = _telemetry.enabled()
+        if timed:
+            # Two clock reads and an append, then straight back to the
+            # caller: this is the loop's thread, where Python run once a
+            # step, cache-cold, costs ten times its timeit figure (the
+            # span, gauge and counters here cost 0.05 ms a step on the
+            # v5e's host, and 0.6 ms when they sat between get() and the
+            # return; PERF.md section 6). The worker does the accounting.
+            t0 = time.perf_counter_ns()
+            with TraceAnnotation("hvd:input.wait"):
+                try:
+                    item, ready = self._q.get_nowait(), True
+                except queue.Empty:
+                    item, ready = self._q.get(), False
+            t1 = time.perf_counter_ns()
+        else:
+            item = self._q.get()
+        if item is self._DONE or isinstance(item, BaseException):
             self._dead = True  # next call: StopIteration, not a hang
+            _account_waits(self._waits)  # the worker has gone
+            if item is self._DONE:
+                raise StopIteration
             raise item
+        if timed:
+            self._waits.append((self._handed, t0, t1, ready,
+                                threading.get_ident()))
+        self._handed += 1
         return item
 
     def __enter__(self) -> "Prefetcher":
@@ -158,6 +221,7 @@ class Prefetcher:
         if t.is_alive() and t is not threading.current_thread():
             t.join(timeout=0.5)
         drain()
+        _account_waits(self._waits)
 
 
 class Dataset:
@@ -175,8 +239,6 @@ class Dataset:
                  seed: int = 0, drop_last: bool = True,
                  rank: Optional[int] = None,
                  num_replicas: Optional[int] = None):
-        import jax
-
         leaves = _leaves(arrays)
         if not leaves:
             raise ValueError("empty dataset pytree")
@@ -233,12 +295,10 @@ class Dataset:
 
 
 def _leaves(tree):
-    import jax
     return [np.asarray(x) for x in jax.tree_util.tree_leaves(tree)]
 
 
 def _map_leaves(fn, tree):
-    import jax
     return jax.tree_util.tree_map(lambda a: fn(np.asarray(a)), tree)
 
 

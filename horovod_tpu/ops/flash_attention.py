@@ -49,6 +49,12 @@ NEG_INF = -1e30  # finite mask value: exp() underflows cleanly, no NaN algebra
 
 _LANE = 128
 
+# What a compiled program and a profile's ``XLA Ops`` events call the kernels
+# (XLA names the custom call after ``pallas_call``'s ``name``): per-kernel
+# readers find them by these, whatever Python function encloses the call.
+KERNEL_FWD = "hvd_flash_fwd"
+KERNEL_BWD = ("hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")
+
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
@@ -172,6 +178,7 @@ def _fa_pallas(q, k, v, bias3, *, causal, scale, block_q, block_k,
             pltpu.VMEM((block_q, _LANE), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_FWD,
     )(*operands)
 
 
@@ -427,6 +434,7 @@ def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
         out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name=KERNEL_BWD[0],
     )(*operands)[0]
 
     # dK/dV pass iterates q INNERMOST: swap the grid index meaning (i = k
@@ -462,6 +470,7 @@ def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_BWD[1],
     )(*operands)
     return dq, dk, dv
 

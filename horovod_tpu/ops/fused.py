@@ -94,6 +94,7 @@ def fused_norms_dot(a, b):
         out_shape=jax.ShapeDtypeStruct((3,), jnp.float32),
         scratch_shapes=[pltpu.SMEM((3,), jnp.float32)],
         interpret=jax.default_backend() != "tpu",
+        name="hvd_adasum_norms_dot",
     )(at, bt)
     return out[0], out[1], out[2]
 
@@ -130,5 +131,6 @@ def fused_combine(a, b, eps: float = 0.0):
         out_specs=pl.BlockSpec((_BLOCK_ROWS, _LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(at.shape, a.dtype),
         interpret=jax.default_backend() != "tpu",
+        name="hvd_adasum_combine",
     )(at, bt, coef)
     return out.reshape(-1)[:a.size].reshape(a.shape)

@@ -72,8 +72,9 @@ def distributed(inner: optax.GradientTransformation, *,
 
         def update(grads, state, params=None, **extra):
             g = reduce_grads(grads)
-            updates, inner_state = inner.update(g, state.inner_state, params,
-                                                **extra)
+            with jax.named_scope("hvd_optimizer"):
+                updates, inner_state = inner.update(
+                    g, state.inner_state, params, **extra)
             return updates, DistributedState(inner_state, (),
                                              state.counter + 1)
 
@@ -94,7 +95,9 @@ def distributed(inner: optax.GradientTransformation, *,
             scale = 1.0 / k if average_aggregated_gradients else 1.0
             g = jax.tree_util.tree_map(lambda a: a * scale, acc_)
             g = reduce_grads(g)
-            updates, new_inner = inner.update(g, inner_state, params, **extra)
+            with jax.named_scope("hvd_optimizer"):
+                updates, new_inner = inner.update(g, inner_state, params,
+                                                  **extra)
             zeros = jax.tree_util.tree_map(jnp.zeros_like, acc_)
             return updates, new_inner, zeros
 

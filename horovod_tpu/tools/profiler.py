@@ -35,8 +35,3 @@ def annotate(name: str):
     Usable as decorator or context manager around host code issuing work."""
     return jax.profiler.TraceAnnotation(name)
 
-
-def step_marker(step: int):
-    """Mark a training step boundary (shows as StepTraceAnnotation rows in
-    TensorBoard's trace viewer)."""
-    return jax.profiler.StepTraceAnnotation("train_step", step_num=step)

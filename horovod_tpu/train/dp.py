@@ -27,13 +27,12 @@ from jax import shard_map as _shard_map
 
 from ..core import context_api as _ctx
 from ..core import sentinel as _sentinel
-from ..core import telemetry as _telemetry
 from ..core.watchdog import monitored_step
 from ..collectives import ops as _ops
 from ..collectives.ops import effective_axis_size, force_axis_size1
 from ..optimizer import broadcast_parameters
-from .step_builder import (_maybe_register_step_flops, accumulate_gradients,
-                           build_program_set, fold_scan, make_dispatch)
+from .step_builder import (accumulate_gradients, build_program_set,
+                           fold_scan, instrument_dispatch, make_dispatch)
 
 
 class TrainState(NamedTuple):
@@ -154,15 +153,16 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
             def run_grads(params, stats, b, y):
                 variables = {"params": params}
                 use_stats = len(jax.tree_util.tree_leaves(stats)) > 0
-                if use_stats:
-                    variables["batch_stats"] = stats
-                    out, mutated = model.apply(variables, b, train=True,
-                                               mutable=["batch_stats"])
-                    new_stats = mutated["batch_stats"]
-                else:
-                    out = model.apply(variables, b, train=True)
-                    new_stats = stats
-                return loss_fn(out, y), new_stats
+                with jax.named_scope("hvd_forward"):
+                    if use_stats:
+                        variables["batch_stats"] = stats
+                        out, mutated = model.apply(variables, b, train=True,
+                                                   mutable=["batch_stats"])
+                        new_stats = mutated["batch_stats"]
+                    else:
+                        out = model.apply(variables, b, train=True)
+                        new_stats = stats
+                    return loss_fn(out, y), new_stats
 
             vg = jax.value_and_grad(run_grads, has_aux=True)
             if accum_steps is not None and accum_steps > 1:
@@ -183,7 +183,8 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
             if apply_update:
                 updates, opt_state = opt.update(grads, state.opt_state,
                                                 state.params)
-                params = optax.apply_updates(state.params, updates)
+                with jax.named_scope("hvd_optimizer"):
+                    params = optax.apply_updates(state.params, updates)
                 if multi:
                     # TrainState is declared replicated (out_specs P()); if
                     # the model's BatchNorm does not itself sync
@@ -256,33 +257,8 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
     dispatch = make_dispatch(programs, sentinel=sentinel,
                              scan_steps=scan_steps)
 
-    _flops_hook = []  # once-latch for the opt-in cost-analysis hook
-
-    def marked(*args, **kwargs):
-        if not _flops_hook:
-            _flops_hook.append(True)
-            _maybe_register_step_flops(jitted.lower, "train_step",
-                                       scan_steps or 1, args, kwargs)
-        # Per-step host-side timeline record (the reference's MARK_CYCLES):
-        # dispatch span + cycle marker; device phases live in the
-        # jax.profiler xplane (tools/profiler.py merges both views). The
-        # timeline is read PER CALL (a runtime check, like the reference's)
-        # so start_timeline/stop_timeline work in any order relative to
-        # building the step, and a closed timeline is never written to.
-        # Registry counter, not a device read: the dispatch is async and
-        # the loss is still a future here — step timing/loss reads belong
-        # to the watchdog span and the Keras callback, which see values
-        # the host already fetched.
-        _telemetry.inc("hvd_dispatches_total", what="train_step")
-        tl = _ctx.context().timeline if _ctx.is_initialized() else None
-        if tl is None or getattr(tl, "_closed", False):
-            return dispatch(*args, **kwargs)
-        tl.activity_start("TRAIN_STEP", "DISPATCH")
-        out = dispatch(*args, **kwargs)
-        tl.activity_end("TRAIN_STEP", "DISPATCH")
-        tl.mark_cycle()
-        return out
-
+    marked = instrument_dispatch(dispatch, what="train_step",
+                                 lower=jitted.lower, steps=scan_steps or 1)
     marked.lower = jitted.lower  # keep AOT introspection available
     if sentinel is not None:
         marked.lower_probe = programs["probe"].lower

@@ -28,8 +28,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..core import sentinel as _sentinel
 from ..core.watchdog import monitored_step
-from .step_builder import (_maybe_register_step_flops, accumulate_gradients,
-                           build_program_set, fold_scan, make_dispatch)
+from .step_builder import (accumulate_gradients, build_program_set,
+                           fold_scan, instrument_dispatch, make_dispatch)
 
 
 class GSPMDTrainState(NamedTuple):
@@ -159,10 +159,11 @@ def _build_gspmd_step(model, mesh, rules, *, optimizer=None, pair=None,
                                                       token_sharding)
 
             def run_grads(params, toks):
-                with nn_partitioning.axis_rules(rules):
+                with nn_partitioning.axis_rules(rules), \
+                        jax.named_scope("hvd_forward"):
                     logits, mods = model.apply({"params": params}, toks,
                                                mutable=["losses"])
-                loss = loss_fn(logits, toks)
+                    loss = loss_fn(logits, toks)
                 if aux_weight and "losses" in mods:
                     aux = sum(jnp.sum(v) for v in
                               jax.tree_util.tree_leaves(mods["losses"]))
@@ -184,7 +185,8 @@ def _build_gspmd_step(model, mesh, rules, *, optimizer=None, pair=None,
             if apply_update:
                 updates, opt_state = opt.update(grads, state.opt_state,
                                                 state.params)
-                params = optax.apply_updates(state.params, updates)
+                with jax.named_scope("hvd_optimizer"):
+                    params = optax.apply_updates(state.params, updates)
                 if sentinel is not None:
                     ok = health[:, 0].min() >= 1.0
 
@@ -211,13 +213,7 @@ def _build_gspmd_step(model, mesh, rules, *, optimizer=None, pair=None,
                           every=pair.every if pair is not None else 1,
                           scan_steps=scan_steps)
 
-    _flops_hook = []  # once-latch for the opt-in cost-analysis hook
-
-    def run(state, tokens):
-        if not _flops_hook:
-            _flops_hook.append(True)
-            _maybe_register_step_flops(lower, "gspmd_train_step",
-                                       scan_steps or 1, (state, tokens), {})
+    def in_mesh(state, tokens):
         if jax.sharding.get_abstract_mesh() == mesh.abstract_mesh:
             # already ambient (a caller's own set_mesh, or hvd-analyze
             # tracing this step: jax forbids set_mesh inside a trace)
@@ -235,6 +231,8 @@ def _build_gspmd_step(model, mesh, rules, *, optimizer=None, pair=None,
         return lower
 
     lower = _mesh_lower(programs["apply"])
+    run = instrument_dispatch(in_mesh, what="gspmd_train_step", lower=lower,
+                              steps=scan_steps or 1)
     run.lower = lower
     if sentinel is not None:
         run.lower_probe = _mesh_lower(programs["probe"])

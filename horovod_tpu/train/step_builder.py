@@ -36,16 +36,19 @@ express (see docs/train_step.md for the full feature lattice):
 from __future__ import annotations
 
 import os
+import time
 from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from jax import shard_map as _shard_map
 
+from ..core import context_api as _ctx
 from ..core import sentinel as _sentinel
 from ..core import telemetry as _telemetry
 from ..core.watchdog import monitored_step
@@ -194,6 +197,53 @@ def make_dispatch(programs: Dict[str, Any], *, sentinel=None,
         return new_state, loss
 
     return dispatch
+
+
+def instrument_dispatch(dispatch: Callable, *, what: str, lower: Callable,
+                        steps: int = 1) -> Callable:
+    """The host-side boundary of every step kind (DP, pipeline, GSPMD):
+    one ``step.dispatch`` span round the call of ``dispatch`` with the
+    call's ordinal as ``seq``, ``hvd_dispatches_total``, and the
+    reference's MARK_CYCLES timeline record (``<WHAT>/DISPATCH`` activity
+    + cycle marker; device phases live in the jax.profiler xplane).
+
+    The timeline is read PER CALL so start_timeline/stop_timeline work in
+    any order relative to building the step, and a closed timeline is
+    never written to. Nothing here reads the device: the dispatch is async
+    and the loss is still a future — step timing belongs to the watchdog
+    span, which sees values the host already fetched. The first call also
+    runs the opt-in cost-analysis hook (``lower`` is the apply program's)."""
+    calls = [0]
+    tensor = what.upper()
+    counted = _telemetry.series_id("hvd_dispatches_total", what=what)
+
+    def run(*args, **kwargs):
+        seq = calls[0]
+        calls[0] = seq + 1
+        if seq == 0:
+            _maybe_register_step_flops(lower, what, steps, args, kwargs)
+        _telemetry.inc_series("hvd_dispatches_total", counted)
+        tl = _ctx.context().timeline if _ctx.is_initialized() else None
+        if tl is not None and getattr(tl, "_closed", False):
+            tl = None
+        if tl is not None:
+            tl.activity_start(tensor, "DISPATCH")
+        # The clock is read here and not by a ``span()`` object: on the
+        # loop's thread every Python frame is run cache-cold and costs ten
+        # times its timeit figure (PERF.md section 6, PR 25).
+        timed = _telemetry.enabled()
+        t0 = time.perf_counter_ns() if timed else 0
+        with TraceAnnotation("hvd:step.dispatch"):
+            out = dispatch(*args, **kwargs)
+        if timed:
+            _telemetry.record_span("step.dispatch", seq, t0,
+                                   time.perf_counter_ns())
+        if tl is not None:
+            tl.activity_end(tensor, "DISPATCH")
+            tl.mark_cycle()
+        return out
+
+    return run
 
 
 # ---------------------------------------------------------- scan folding
@@ -351,7 +401,8 @@ def make_pipeline_train_step(stage_fn: Callable, loss_fn: Callable,
             opt_state = unstack(state.opt_state)
             if apply_update:
                 updates, opt_state = opt.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                with jax.named_scope("hvd_optimizer"):
+                    params = optax.apply_updates(params, updates)
             return (PipelineTrainState(state.step + 1, restack(params),
                                        restack(opt_state)), loss)
 
@@ -368,17 +419,8 @@ def make_pipeline_train_step(stage_fn: Callable, loss_fn: Callable,
                                  pair=pair)
     dispatch = make_dispatch(programs,
                              every=pair.every if pair is not None else 1)
-    _flops_hook = []  # once-latch for the opt-in cost-analysis hook
-
-    def run(state, x_microbatches, targets):
-        if not _flops_hook:
-            _flops_hook.append(True)
-            _maybe_register_step_flops(
-                programs["apply"].lower, "pipeline_train_step", 1,
-                (state, x_microbatches, targets), {})
-        _telemetry.inc("hvd_dispatches_total", what="pipeline_train_step")
-        return dispatch(state, x_microbatches, targets)
-
+    run = instrument_dispatch(dispatch, what="pipeline_train_step",
+                              lower=programs["apply"].lower)
     run.lower = programs["apply"].lower
     if pair is not None:
         run.lower_apply = programs["apply"].lower
