@@ -23,11 +23,24 @@ Two properties matter for the distributed design:
 The backward pass recomputes probability tiles from the saved
 (q, k, v, o, m, l) — the standard flash trade of FLOPs for HBM (SURVEY.md §7
 lists remat as the stock TPU memory lever). Two implementations exist:
-dedicated blockwise Pallas kernels (FlashAttention-2 split: a dQ pass and a
-dK/dV pass) used on the common ``return_residuals=False`` model path, and a
-materialised-softmax jnp recompute vjp kept for ``return_residuals=True``,
-where the (m, l) outputs carry real cotangents from ring-attention partial
-merging that the kernels do not model.
+dedicated Pallas kernels on the common ``return_residuals=False`` model
+path, and a materialised-softmax jnp recompute vjp kept for
+``return_residuals=True``, where the (m, l) outputs carry real cotangents
+from ring-attention partial merging that the kernels do not model.
+
+The kernel backward adapts to the shape it is traced with
+(``_fa_bwd_pallas``, no option selects it). With several tiles it is the
+FlashAttention-2 split, a dQ pass (``hvd_flash_bwd_dq``) that accumulates
+over key tiles and a dK/dV pass (``hvd_flash_bwd_dkv``) that accumulates
+over query tiles, each recomputing the probability tile. When the padded
+sequence is ONE tile in both directions (``Tq <= block_q`` and
+``Tk <= block_k``: up to 512 tokens at the default blocks) there is nothing
+to accumulate across, so one fused kernel (``hvd_flash_bwd``) on grid
+``(batch*heads,)`` recomputes the tile once and writes dQ, dK and dV from
+it: 5 matmuls and one round of the T² elementwise work where the two passes
+spend 7 and two. The trace-time counter
+``hvd_flash_bwd_kernels_total{kind="fused"|"two_pass"}`` says which a
+program got.
 
 On non-TPU backends the kernel runs in Pallas interpreter mode, which is how
 the CPU test mesh exercises it (the reference's CPU+Gloo fake-backend trick,
@@ -45,6 +58,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core import telemetry as _telemetry
+
 NEG_INF = -1e30  # finite mask value: exp() underflows cleanly, no NaN algebra
 
 _LANE = 128
@@ -53,7 +68,8 @@ _LANE = 128
 # (XLA names the custom call after ``pallas_call``'s ``name``): per-kernel
 # readers find them by these, whatever Python function encloses the call.
 KERNEL_FWD = "hvd_flash_fwd"
-KERNEL_BWD = ("hvd_flash_bwd_dq", "hvd_flash_bwd_dkv")
+KERNEL_BWD = ("hvd_flash_bwd_dq", "hvd_flash_bwd_dkv", "hvd_flash_bwd")
+_BWD_DQ, _BWD_DKV, _BWD_FUSED = KERNEL_BWD
 
 
 def _use_interpret() -> bool:
@@ -319,8 +335,10 @@ def _recompute_p_ds(q, k, v, do, m, l, dsum, bias_tile, *, scale, causal,
     if causal:
         q_pos = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    l = jnp.where(l == 0.0, 1.0, l)
-    p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m)) / l
+    # 1/l once a row ([bq, 1]), multiplied in: a divide of the whole tile
+    # costs several VPU passes over [bq, bk] for the same quotient.
+    inv_l = 1.0 / jnp.where(l == 0.0, 1.0, l)
+    p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m)) * inv_l
     dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                          preferred_element_type=jnp.float32)
     ds = p * (dp - dsum)  # dsum: rowsum(dO*O), the FA2 correction term
@@ -400,14 +418,82 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, d_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, d_ref,
+                         *refs, scale, causal, bq, bk, has_bias):
+    """Whole backward of one (batch*head) whose sequence is ONE tile in both
+    directions: nothing accumulates across grid steps, so ``p`` and ``ds``
+    are computed once and dQ, dK, dV leave straight from them."""
+    if has_bias:
+        bias_ref, dq_ref, dk_ref, dv_ref = refs
+    else:
+        dq_ref, dk_ref, dv_ref = refs
+    q = q_ref[0]
+    k = k_ref[0]
+    do = do_ref[0]
+    p, ds = _recompute_p_ds(
+        q, k, v_ref[0], do, m_ref[0], l_ref[0], d_ref[0],
+        bias_ref[0] if has_bias else None, scale=scale, causal=causal,
+        bq=bq, bk=bk, iq=0, ik=0)
+    dv_ref[0] = lax.dot_general(
+        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
+    ds = ds.astype(q.dtype)
+    dq_ref[0] = (lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale).astype(dq_ref.dtype)
+    dk_ref[0] = (lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale).astype(dk_ref.dtype)
+
+
+def _fa_bwd_fused(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
+                  interpret):
+    """The single-tile backward: one pallas_call on grid (BH,), every
+    operand's whole (batch*head) row as its block."""
+    BH, Tq, D = q.shape
+    Tk = k.shape[1]
+    row = lambda b: (b, 0, 0)
+    q_rows = pl.BlockSpec((1, Tq, D), row)       # q, do, dq
+    k_rows = pl.BlockSpec((1, Tk, D), row)       # k, v, dk, dv
+    q_stat = pl.BlockSpec((1, Tq, 1), row)       # m, l, dsum
+    operands = [q, k, v, do, m3, l3, dsum]
+    in_specs = [q_rows, k_rows, k_rows, q_rows, q_stat, q_stat, q_stat]
+    if bias3 is not None:
+        operands.append(bias3)
+        in_specs.append(pl.BlockSpec((1, 1, Tk), row))
+    return tuple(pl.pallas_call(
+        functools.partial(_fa_bwd_fused_kernel, scale=scale, causal=causal,
+                          bq=Tq, bk=Tk, has_bias=bias3 is not None),
+        grid=(BH,),
+        in_specs=in_specs,
+        out_specs=[q_rows, k_rows, k_rows],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, D), v.dtype),
+        ],
+        interpret=interpret,
+        name=_BWD_FUSED,
+    )(*operands))
+
+
 @functools.partial(jax.jit, static_argnames=(
     "causal", "scale", "block_q", "block_k", "interpret"))
 def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
                    block_q, block_k, interpret):
-    """Backward pallas_calls on PADDED folded shapes → (dq, dk, dv)."""
+    """Backward pallas_calls on PADDED folded shapes → (dq, dk, dv): the
+    fused kernel when the sequence is one tile in both directions, the dQ
+    and dK/dV passes otherwise."""
     BH, Tq, D = q.shape
     Tk = k.shape[1]
     nq, nk = Tq // block_q, Tk // block_k
+    fused = nq == 1 and nk == 1
+    # Trace-time: fires once per traced program, never per step.
+    _telemetry.inc("hvd_flash_bwd_kernels_total",
+                   kind="fused" if fused else "two_pass")
+    if fused:
+        return _fa_bwd_fused(q, k, v, do, m3, l3, dsum, bias3, causal=causal,
+                             scale=scale, interpret=interpret)
 
     base_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # q
@@ -434,7 +520,7 @@ def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
         out_shape=[jax.ShapeDtypeStruct((BH, Tq, D), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
-        name=KERNEL_BWD[0],
+        name=_BWD_DQ,
     )(*operands)[0]
 
     # dK/dV pass iterates q INNERMOST: swap the grid index meaning (i = k
@@ -470,7 +556,7 @@ def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
-        name=KERNEL_BWD[1],
+        name=_BWD_DKV,
     )(*operands)
     return dq, dk, dv
 

@@ -258,6 +258,104 @@ def test_flash_bwd_kernel_matches_reference(causal):
                                    rtol=2e-4, atol=2e-4)
 
 
+def pallas_names(fn, *args):
+    """Names of the ``pallas_call``s in ``fn``'s program, in program order
+    (tests/test_spans.py uses this too)."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"] if "name" in eqn.params
+                             else eqn.params["name_and_src_info"].name)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+def _bwd_kernels(loss, *args):
+    return sorted(n for n in pallas_names(
+        jax.grad(loss, argnums=(0, 1, 2)), *args) if "bwd" in n)
+
+
+_F32, _BF16 = (jnp.float32, 2e-4), (jnp.bfloat16, 5e-2)
+
+
+@pytest.mark.parametrize("causal,Tq,Tk,masked,dtype_tol", [
+    (True, 64, 64, False, _F32),
+    (False, 64, 64, False, _F32),
+    (False, 64, 64, True, _F32),
+    (True, 64, 64, True, _F32),
+    (False, 48, 80, False, _F32),       # cross attention, Tq != Tk
+    (True, 100, 100, False, _F32),      # padded to 104 inside the one tile
+    (False, 100, 84, True, _F32),       # both dims padded, and a mask
+    (True, 64, 64, False, _BF16),
+    (False, 100, 100, True, _BF16),
+], ids=["causal", "full", "full_masked", "causal_masked", "cross",
+        "causal_padded", "cross_padded_masked", "causal_bf16",
+        "padded_masked_bf16"])
+def test_flash_bwd_fused_kernel_matches_reference(causal, Tq, Tk, masked,
+                                                  dtype_tol):
+    """A sequence that is ONE tile in both directions (the default 512
+    blocks clamp to it) takes the fused single-pass backward: same gradients
+    as the materialised-softmax vjp."""
+    dtype, tol = dtype_tol
+    B, H, D = 2, 2, 32
+    q = _rand((B, Tq, H, D), 40, dtype)
+    k = _rand((B, Tk, H, D), 41, dtype)
+    v = _rand((B, Tk, H, D), 42, dtype)
+    mask = bias = None
+    if masked:
+        mask = jnp.arange(Tk)[None, :] < jnp.array([Tk - 24, Tk])[:, None]
+        bias = jnp.where(mask, 0.0, -1e30)
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=causal, kv_mask=mask)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    def ref_loss(q, k, v):
+        o, _, _ = _reference_partial(q, k, v, bias, causal=causal,
+                                     scale=D ** -0.5)
+        return (o.astype(jnp.float32) ** 2).sum()
+
+    assert _bwd_kernels(loss, q, k, v) == ["hvd_flash_bwd"]
+    g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g, gr):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_bwd_fused_agrees_with_two_pass(causal):
+    """Same inputs through the fused kernel (one tile) and through the dQ and
+    dK/dV passes (forced by 32-token blocks): one algorithm, two grids."""
+    B, H, D, Tq, Tk = 2, 2, 32, 100, 84
+    q = _rand((B, Tq, H, D), 43)
+    k = _rand((B, Tk, H, D), 44)
+    v = _rand((B, Tk, H, D), 45)
+    mask = jnp.arange(Tk)[None, :] < jnp.array([60, Tk])[:, None]
+
+    def loss(q, k, v, **blocks):
+        return (flash_attention(q, k, v, causal=causal, kv_mask=mask,
+                                **blocks) ** 2).sum()
+
+    def tiled(q, k, v):
+        return loss(q, k, v, block_q=32, block_k=32)
+
+    assert _bwd_kernels(loss, q, k, v) == ["hvd_flash_bwd"]
+    assert _bwd_kernels(tiled, q, k, v) == ["hvd_flash_bwd_dkv",
+                                            "hvd_flash_bwd_dq"]
+    g1 = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    g2 = jax.grad(tiled, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-5, atol=5e-5)
+
+
 def test_flash_residual_path_still_differentiable():
     """return_residuals=True keeps the recompute vjp (m/l cotangents from
     merge_partials must flow)."""
@@ -291,10 +389,13 @@ def test_flash_residual_path_still_differentiable():
                                    rtol=2e-4, atol=2e-4)
 
 
-def test_flash_partitioned_under_gspmd_mesh(monkeypatch):
+@pytest.mark.parametrize("blocks", [{"block_q": 32, "block_k": 32}, {}],
+                         ids=["two_pass", "fused"])
+def test_flash_partitioned_under_gspmd_mesh(monkeypatch, blocks):
     """flash_attention inside jit with dp x tp sharded operands: the
     custom_partitioning rule shards batch*head and replicates seq/depth, so
-    the kernel runs per-shard and matches the unsharded result."""
+    the kernel runs per-shard and matches the unsharded result, whichever
+    backward the shape selects."""
     monkeypatch.setenv("HOROVOD_FLASH_PARTITION", "1")
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -308,8 +409,7 @@ def test_flash_partitioned_under_gspmd_mesh(monkeypatch):
     qs, ks, vs = (jax.device_put(x, sh) for x in (q, k, v))
 
     def loss(q, k, v):
-        return (flash_attention(q, k, v, causal=True, block_q=32,
-                                block_k=32) ** 2).sum()
+        return (flash_attention(q, k, v, causal=True, **blocks) ** 2).sum()
 
     f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
     val_s, grads_s = f(qs, ks, vs)

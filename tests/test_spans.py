@@ -25,6 +25,7 @@ from horovod_tpu.train import (create_gspmd_train_state,
                                create_train_state, make_gspmd_train_step,
                                make_pipeline_train_step, make_train_step,
                                next_token_loss)
+from test_ops import pallas_names
 
 
 @pytest.fixture(autouse=True)
@@ -417,36 +418,34 @@ def test_named_scopes_are_metadata_only(monkeypatch):
     assert [a.tobytes() for a in named] == [b.tobytes() for b in plain]
 
 
-def _pallas_names(fn, *args):
-    names = []
-
-    def walk(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
-                names.append(eqn.params["name"] if "name" in eqn.params
-                             else eqn.params["name_and_src_info"].name)
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub)
-    walk(jax.make_jaxpr(fn)(*args).jaxpr)
-    return names
-
-
-def test_flash_kernels_carry_their_names():
-    from horovod_tpu.ops.flash_attention import flash_attention
-    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+@pytest.mark.parametrize("blocks,bwd_names,kind", [
+    ({}, ["hvd_flash_bwd"], "fused"),
+    ({"block_q": 64, "block_k": 64},
+     ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"], "two_pass"),
+], ids=["single_tile", "multi_tile"])
+def test_flash_kernels_carry_their_names(blocks, bwd_names, kind):
+    from horovod_tpu.ops.flash_attention import (KERNEL_BWD, KERNEL_FWD,
+                                                 flash_attention)
+    # three heads: a shape no other test traces, so the jitted backward is
+    # traced here (its counter fires per trace, and jit caches traces)
+    q = jnp.ones((1, 128, 3, 64), jnp.float32)
 
     def loss(q, k, v):
-        return flash_attention(q, k, v, causal=False).sum()
+        return flash_attention(q, k, v, causal=False, **blocks).sum()
 
-    assert _pallas_names(loss, q, q, q) == ["hvd_flash_fwd"]
-    assert sorted(_pallas_names(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)) \
-        == ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq", "hvd_flash_fwd"]
+    assert pallas_names(loss, q, q, q) == ["hvd_flash_fwd"]
+    names = sorted(pallas_names(jax.grad(loss, argnums=(0, 1, 2)), q, q, q))
+    assert names == bwd_names + ["hvd_flash_fwd"]
+    assert set(names) <= {KERNEL_FWD, *KERNEL_BWD}
+    counted = {k: T.active().registry.counter_value(
+        "hvd_flash_bwd_kernels_total", kind=k) for k in ("fused", "two_pass")}
+    assert counted == {"fused": 0.0, "two_pass": 0.0, kind: 1.0}
 
 
 def test_adasum_kernels_carry_their_names():
     from horovod_tpu.ops import fused
     a = jnp.ones((4096,), jnp.float32)
-    assert _pallas_names(fused.fused_norms_dot, a, a) == \
+    assert pallas_names(fused.fused_norms_dot, a, a) == \
         ["hvd_adasum_norms_dot"]
-    assert _pallas_names(fused.fused_combine, a, a) == \
+    assert pallas_names(fused.fused_combine, a, a) == \
         ["hvd_adasum_norms_dot", "hvd_adasum_combine"]
