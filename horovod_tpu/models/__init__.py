@@ -10,7 +10,8 @@ __all__ = [
 def __getattr__(name):
     import importlib
     lazy = {"bert": ".bert", "llama": ".llama", "mixtral": ".mixtral",
-            "dlrm": ".dlrm", "decode": ".decode"}
+            "dlrm": ".dlrm", "decode": ".decode",
+            "latent_moe": ".latent_moe"}
     for mod, path in lazy.items():
         if name == mod:
             try:

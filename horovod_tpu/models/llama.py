@@ -143,11 +143,17 @@ _REMAT_POLICIES = {
 }
 
 
-def _remat(cls, policy_name: str):
+def _remat(cls, policy_name: str, prevent_cse: bool = False):
+    """``prevent_cse=False`` is right under ``nn.scan`` (the loop keeps XLA
+    from merging the recomputation with the forward pass). Unrolled layers
+    that count on the memory pass True: without the barrier XLA's CSE folds
+    every recomputed value back into the one the forward pass made, and all
+    of it stays live (latent_moe.py; 16.7 GB against 16 at its benchmark
+    shape whatever the policy)."""
     if policy_name not in _REMAT_POLICIES:
         raise ValueError(f"remat_policy {policy_name!r} not in "
                          f"{sorted(_REMAT_POLICIES)}")
-    return nn.remat(cls, prevent_cse=False,
+    return nn.remat(cls, prevent_cse=prevent_cse,
                     policy=_REMAT_POLICIES[policy_name])
 
 

@@ -8,6 +8,10 @@ of HBM entirely — each (q-block, k-block) tile is produced in VMEM, folded
 into running (max, denominator, accumulator) state, and discarded. Scores hit
 the MXU as [block_q, D] x [D, block_k] matmuls in fp32.
 
+``v`` may have a depth of its own (``Dv``; latent attention: 192-deep
+queries and keys, 128-deep values): o, dO and dV then have ``v``'s depth and
+dQ, dK the query's, in every kernel below.
+
 Two properties matter for the distributed design:
 
 - **Residuals** (``return_residuals=True``): the kernel can return the
@@ -81,7 +85,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, nk,
     """One (batch*head, q-block, k-block) grid step.
 
     Scratch (persists across the innermost k-block grid dim):
-      acc [bq, D] f32 — unnormalised output accumulator
+      acc [bq, Dv] f32 — unnormalised output accumulator
       m_s [bq, 128] f32 — running row max (broadcast over lanes)
       l_s [bq, 128] f32 — running denominator (broadcast over lanes)
     """
@@ -157,9 +161,10 @@ def _pad_axis(x, axis, mult):
 def _fa_pallas(q, k, v, bias3, *, causal, scale, block_q, block_k,
                interpret):
     """Forward pallas_call on PADDED folded shapes. q [BH, Tq, D],
-    k/v [BH, Tk, D], bias3 None or [BH, 1, Tk]; Tq/Tk block multiples."""
+    k [BH, Tk, D], v [BH, Tk, Dv], bias3 None or [BH, 1, Tk]; Tq/Tk block
+    multiples. The output has v's depth."""
     BH, Tq, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[2]
     nq, nk = Tq // block_q, Tk // block_k
     kern = functools.partial(_fa_kernel, scale=scale, causal=causal,
                              bq=block_q, bk=block_k, nk=nk,
@@ -167,7 +172,7 @@ def _fa_pallas(q, k, v, bias3, *, causal, scale, block_q, block_k,
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),
     ]
     operands = [q, k, v]
     if bias3 is not None:
@@ -179,17 +184,17 @@ def _fa_pallas(q, k, v, bias3, *, causal, scale, block_q, block_k,
         grid=(BH, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, Tq, Dv), q.dtype),
             jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
             jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q, Dv), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
             pltpu.VMEM((block_q, _LANE), jnp.float32),
         ],
@@ -245,10 +250,10 @@ def _sharded_wrapper(kind: str, has_bias: bool):
     n_arrays = (3 if kind == "fwd" else 7) + int(has_bias)
     bias_term = ", b s k" if has_bias else ""
     if kind == "fwd":
-        rule = f"b q d, b k d, b k d{bias_term} -> b q d, b q s, b q s"
+        rule = f"b q d, b k d, b k e{bias_term} -> b q e, b q s, b q s"
     else:
-        rule = (f"b q d, b k d, b k d, b q d, b q s, b q s, b q s"
-                f"{bias_term} -> b q d, b k d, b k d")
+        rule = (f"b q d, b k d, b k e, b q e, b q s, b q s, b q s"
+                f"{bias_term} -> b q d, b k d, b k e")
 
     def run(arrays, causal, scale, block_q, block_k, interpret):
         bias3 = arrays[-1] if has_bias else None
@@ -295,7 +300,7 @@ def _sharded_wrapper(kind: str, has_bias: bool):
         partition=partition,
         infer_sharding_from_operands=infer,
         sharding_rule=rule,
-        need_replication_factors=("q", "d", "k", "s"))
+        need_replication_factors=("q", "d", "k", "e", "s"))
     return wrapped
 
 
@@ -303,9 +308,9 @@ def _sharded_wrapper(kind: str, has_bias: bool):
     "causal", "scale", "block_q", "block_k", "interpret", "partition"))
 def _fa_call(q, k, v, bias=None, *, causal, scale, block_q, block_k,
              interpret, partition):
-    """q [BH, Tq, D], k/v [BH, Tk, D], optional additive score bias
-    [BH, Tk] → (o [BH, Tq, D], m, l [BH, Tq])."""
-    BH, Tq0, D = q.shape
+    """q [BH, Tq, D], k [BH, Tk, D], v [BH, Tk, Dv], optional additive
+    score bias [BH, Tk] → (o [BH, Tq, Dv], m, l [BH, Tq])."""
+    BH = q.shape[0]
     q, Tq0 = _pad_axis(q, 1, block_q)
     k, Tk0 = _pad_axis(k, 1, block_k)
     v, _ = _pad_axis(v, 1, block_k)
@@ -451,13 +456,15 @@ def _fa_bwd_fused(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
     """The single-tile backward: one pallas_call on grid (BH,), every
     operand's whole (batch*head) row as its block."""
     BH, Tq, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[2]
     row = lambda b: (b, 0, 0)
-    q_rows = pl.BlockSpec((1, Tq, D), row)       # q, do, dq
-    k_rows = pl.BlockSpec((1, Tk, D), row)       # k, v, dk, dv
+    q_rows = pl.BlockSpec((1, Tq, D), row)       # q, dq
+    k_rows = pl.BlockSpec((1, Tk, D), row)       # k, dk
+    v_rows = pl.BlockSpec((1, Tk, Dv), row)      # v, dv
+    do_rows = pl.BlockSpec((1, Tq, Dv), row)     # do
     q_stat = pl.BlockSpec((1, Tq, 1), row)       # m, l, dsum
     operands = [q, k, v, do, m3, l3, dsum]
-    in_specs = [q_rows, k_rows, k_rows, q_rows, q_stat, q_stat, q_stat]
+    in_specs = [q_rows, k_rows, v_rows, do_rows, q_stat, q_stat, q_stat]
     if bias3 is not None:
         operands.append(bias3)
         in_specs.append(pl.BlockSpec((1, 1, Tk), row))
@@ -466,11 +473,11 @@ def _fa_bwd_fused(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
                           bq=Tq, bk=Tk, has_bias=bias3 is not None),
         grid=(BH,),
         in_specs=in_specs,
-        out_specs=[q_rows, k_rows, k_rows],
+        out_specs=[q_rows, k_rows, v_rows],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
             jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, Tk, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, Dv), v.dtype),
         ],
         interpret=interpret,
         name=_BWD_FUSED,
@@ -485,7 +492,7 @@ def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
     fused kernel when the sequence is one tile in both directions, the dQ
     and dK/dV passes otherwise."""
     BH, Tq, D = q.shape
-    Tk = k.shape[1]
+    Tk, Dv = k.shape[1], v.shape[2]
     nq, nk = Tq // block_q, Tk // block_k
     fused = nq == 1 and nk == 1
     # Trace-time: fires once per traced program, never per step.
@@ -498,8 +505,8 @@ def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
     base_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # q
         pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # k
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),   # v
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # do
+        pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, j, 0)),  # v
+        pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, i, 0)),  # do
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),   # m
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),   # l
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),   # dsum
@@ -528,8 +535,8 @@ def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
     kv_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0)),   # q
         pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0)),   # k
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0)),   # v
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0)),   # do
+        pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, i, 0)),  # v
+        pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, j, 0)),  # do
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),   # m
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),   # l
         pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),   # dsum
@@ -545,15 +552,15 @@ def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
         in_specs=kv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, Tk, D), v.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, Dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         interpret=interpret,
         name=_BWD_DKV,
@@ -564,7 +571,7 @@ def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
 def _fa_bwd_call(q, k, v, do, o, m, l, bias=None, *, causal, scale,
                  block_q, block_k, interpret, partition):
     """Folded-[BH] backward. Returns (dq, dk, dv) in the input dtypes."""
-    BH, Tq0, D = q.shape
+    BH, Tq0, _ = q.shape
     dsum = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                    keepdims=True)  # [BH, Tq, 1] — the FA2 rowsum(dO*O) term
     q, _ = _pad_axis(q, 1, block_q)
@@ -593,8 +600,8 @@ def _reference_partial(q, k, v, bias=None, *, causal, scale):
     """Blockless jnp oracle with the same (o, m, l) partial semantics.
 
     Used as the recompute path of the backward pass and by the test suite.
-    q [B, Tq, H, D]; k/v [B, Tk, H, D]; optional additive score bias
-    [B, Tk]; returns o [B,Tq,H,D], m/l [B,H,Tq].
+    q [B, Tq, H, D]; k [B, Tk, H, D]; v [B, Tk, H, Dv]; optional additive
+    score bias [B, Tk]; returns o [B,Tq,H,Dv], m/l [B,H,Tq].
     """
     s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
@@ -612,8 +619,8 @@ def _reference_partial(q, k, v, bias=None, *, causal, scale):
     return o.astype(q.dtype), m, l
 
 
-def _fold(x, B, H, D):
-    return x.transpose(0, 2, 1, 3).reshape(B * H, -1, D)
+def _fold(x, B, H):
+    return x.transpose(0, 2, 1, 3).reshape(B * H, -1, x.shape[-1])
 
 
 def _fold_bias(bias, B, H, Tk):
@@ -625,15 +632,15 @@ def _fa_fwd_impl(q, k, v, bias, causal, scale, block_q, block_k):
     """Plain (non-vjp) forward shared by both custom_vjp cores."""
     from jax.ad_checkpoint import checkpoint_name
     interpret = _use_interpret()
-    B, Tq, H, D = q.shape
+    B, Tq, H, _ = q.shape
     Tk = k.shape[1]
     fbias = None if bias is None else _fold_bias(bias, B, H, Tk)
-    o, m, l = _fa_call(_fold(q, B, H, D), _fold(k, B, H, D),
-                       _fold(v, B, H, D), fbias, causal=causal,
+    o, m, l = _fa_call(_fold(q, B, H), _fold(k, B, H),
+                       _fold(v, B, H), fbias, causal=causal,
                        scale=scale, block_q=block_q, block_k=block_k,
                        interpret=interpret,
                        partition=_partition_enabled())
-    o = o.reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
+    o = o.reshape(B, H, Tq, -1).transpose(0, 2, 1, 3)
     # Named so a remat policy can SAVE the kernel's outputs — they are
     # exactly the custom-vjp residuals (o, m, l), so a policy that keeps
     # them (models' "dots_attn") skips the whole fwd-kernel re-run inside
@@ -688,17 +695,17 @@ def _fa_fwd_nores(q, k, v, bias, causal, scale, block_q, block_k):
 
 def _fa_bwd_nores(causal, scale, block_q, block_k, res, do):
     q, k, v, bias, o, m, l = res
-    B, Tq, H, D = q.shape
+    B, Tq, H, _ = q.shape
     Tk = k.shape[1]
     fbias = None if bias is None else _fold_bias(bias, B, H, Tk)
     fm = m.reshape(B * H, Tq)
     fl = l.reshape(B * H, Tq)
     dq, dk, dv = _fa_bwd_call(
-        _fold(q, B, H, D), _fold(k, B, H, D), _fold(v, B, H, D),
-        _fold(do, B, H, D), _fold(o, B, H, D), fm, fl, fbias,
+        _fold(q, B, H), _fold(k, B, H), _fold(v, B, H),
+        _fold(do, B, H), _fold(o, B, H), fm, fl, fbias,
         causal=causal, scale=scale, block_q=block_q, block_k=block_k,
         interpret=_use_interpret(), partition=_partition_enabled())
-    unfold = lambda x, T: x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    unfold = lambda x, T: x.reshape(B, H, T, -1).transpose(0, 2, 1, 3)
     dbias = None if bias is None else jnp.zeros_like(bias)
     return unfold(dq, Tq), unfold(dk, Tk), unfold(dv, Tk), dbias
 
@@ -711,7 +718,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None,
                     block_q: int = 512, block_k: int = 512,
                     return_residuals: bool = False):
-    """Blockwise (flash) attention on [B, T, H, D] tensors.
+    """Blockwise (flash) attention on q, k [B, T, H, D] and v [B, Tk, H, Dv].
+
+    ``v`` may have another depth than ``q`` and ``k`` (latent attention:
+    192-deep queries and keys, 128-deep values); the output has ``v``'s
+    depth and the default ``scale`` is ``1/sqrt(D)`` of the query's.
 
     ``kv_mask`` is an optional [B, Tk] bool array marking real (attendable)
     keys — the BERT-style padding mask; masked keys never win the softmax.

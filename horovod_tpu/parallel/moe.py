@@ -16,6 +16,11 @@ the full path, built here the TPU way:
 - the combine applies router probabilities on the way back.
 
 All functions run inside ``shard_map`` over the ep axis.
+
+A second, DROPLESS form (``dropless_share`` and the functions above it, end
+of this file) serves sigmoid-routed layers with many small experts: no
+capacity, a selection bias that no gradient trains, and a rank that is told
+which experts it holds and computes their part of the layer's sum only.
 """
 
 from __future__ import annotations
@@ -25,6 +30,8 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..core import telemetry as _telemetry
 
 
 class RouterOutput(NamedTuple):
@@ -287,3 +294,184 @@ def routed_experts(x, router_logits, expert_fn: Callable, *,
         out = expert_alltoall_back(out, axis_name)           # [E, C, D]
     y = sorted_combine(out, r, T)
     return y, r.aux_loss
+
+
+# --------------------------------------------------------------------------
+# Dropless routing for ONE SHARE of the experts (sigmoid scores, a selection
+# bias that no gradient trains, nothing dropped). The router keeps every
+# expert's score and every token's k choices; this rank holds experts
+# ``[first, first + held)`` and computes their part of the layer's sum. What
+# the absent experts would add is left out: on more ranks it arrives through
+# the ``ep`` exchange, which this single-rank form runs without.
+
+class HeldRouting(NamedTuple):
+    """Plan for the (token, choice) pairs that land on held experts, sorted
+    by expert. ``k*T`` rows is the static bound (every pair could land
+    here); ``group_sizes`` says how many are filled, and the grouped
+    products and every consumer follow that. Both directions of the mapping
+    are kept so that dispatch and combine, forward and backward, are row
+    gathers (see :class:`SortedRouting` for why not scatters)."""
+    row_token: jnp.ndarray    # [k*T] int32: source token of each sorted row
+    row_entry: jnp.ndarray    # [k*T] int32: its (token*k + choice) pair
+    dest: jnp.ndarray         # [T, k] int32: row of each pair (if ``on``)
+    on: jnp.ndarray           # [T, k] bool: the pair's expert is held here
+    valid: jnp.ndarray        # [k*T] bool: the row is filled
+    group_sizes: jnp.ndarray  # [held] int32: rows of each held expert
+
+
+def sigmoid_topk_gates(logits, bias, top_k: int, scaling: float):
+    """DeepSeek-V3's ``noaux_tc`` selection without groups: float32 sigmoid
+    scores, the ``top_k`` of ``score + bias`` chosen, gates the chosen
+    scores renormalised over the choice and scaled. The bias picks and does
+    not weigh, and carries no gradient. Returns ``(choice [T, k] int32,
+    gates [T, k] f32)``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, choice = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+    picked = jnp.take_along_axis(scores, choice, axis=-1)
+    gates = scaling * picked / picked.sum(-1, keepdims=True)
+    return choice.astype(jnp.int32), gates
+
+
+def held_plan(choice, first: int, held: int) -> HeldRouting:
+    """Sort the pairs whose expert is in ``[first, first + held)`` by
+    expert; every other pair sorts behind them and fills no row."""
+    T, k = choice.shape
+    local = choice - first
+    on = (local >= 0) & (local < held)
+    key = jnp.where(on, local, held).reshape(-1)            # [k*T]
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    group_sizes = jnp.sum(
+        jax.nn.one_hot(key, held + 1, dtype=jnp.int32), axis=0)[:held]
+    rows = jnp.arange(k * T, dtype=jnp.int32)
+    # pair -> row: a k*T int permutation, nothing row-sized
+    dest = jnp.zeros_like(order).at[order].set(rows).reshape(T, k)
+    return HeldRouting(order // k, order, dest, on,
+                       rows < group_sizes.sum(), group_sizes)
+
+
+@jax.custom_vjp
+def _held_rows(x, row_token, dest, on):
+    """rows[p] = x[row_token[p]]: a gather. Rows past the filled ones hold
+    copies nobody reads."""
+    return x[row_token]
+
+
+def _held_rows_fwd(x, row_token, dest, on):
+    return x[row_token], (row_token, dest, on)
+
+
+def _held_rows_bwd(res, drows):
+    # dx[t] = sum over t's pairs that are held of drows[dest[t, c]]: the
+    # mirror of the combine's forward, also a gather.
+    row_token, dest, on = res
+    picked = jnp.where(on[..., None], drows[dest], 0)
+    dx = picked.astype(jnp.float32).sum(1).astype(drows.dtype)
+    return dx, _zero_tan(row_token), _zero_tan(dest), _zero_tan(on)
+
+
+_held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
+
+
+@jax.custom_vjp
+def _held_sum(out, gates, row_entry, row_token, dest, on, valid):
+    """y[t] = sum over t's held pairs of gates[t, c] * out[dest[t, c]], in
+    float32."""
+    picked = jnp.where(on[..., None], out[dest], 0).astype(jnp.float32)
+    return (picked * gates[..., None]).sum(1)
+
+
+def _held_sum_fwd(out, gates, row_entry, row_token, dest, on, valid):
+    return _held_sum(out, gates, row_entry, row_token, dest, on, valid), \
+        (out, gates, row_entry, row_token, dest, on, valid)
+
+
+def _held_sum_bwd(res, dy):
+    out, gates, row_entry, row_token, dest, on, valid = res
+    gate_row = jnp.where(valid, gates.reshape(-1)[row_entry], 0)
+    # gathered in the rows' own type: a float32 [k*T, D] copy of dy is the
+    # layer's largest buffer otherwise
+    dout = (dy.astype(out.dtype)[row_token]
+            * gate_row[:, None]).astype(out.dtype)
+    picked = jnp.where(on[..., None], out[dest], 0).astype(jnp.float32)
+    dgates = (picked * dy[:, None, :]).sum(-1)
+    return (dout, dgates, _zero_tan(row_entry), _zero_tan(row_token),
+            _zero_tan(dest), _zero_tan(on), _zero_tan(valid))
+
+
+_held_sum.defvjp(_held_sum_fwd, _held_sum_bwd)
+
+
+def held_dispatch(x, plan: HeldRouting):
+    """[T, D] tokens -> [k*T, D] rows grouped by held expert."""
+    return _held_rows(x, plan.row_token, plan.dest, plan.on)
+
+
+def held_combine(out, gates, plan: HeldRouting):
+    """[k*T, D] expert outputs -> [T, D] float32 gate-weighted sum over each
+    token's held pairs (zero for a token with none)."""
+    return _held_sum(out, gates, plan.row_entry, plan.row_token, plan.dest,
+                     plan.on, plan.valid)
+
+
+def grouped_swiglu(rows, w1, w3, w2, plan: HeldRouting):
+    """SwiGLU of every held expert on its own rows: three grouped products
+    (``jax.lax.ragged_dot``) over ``plan.group_sizes``. ``w1``/``w3``
+    [held, D, M], ``w2`` [held, M, D], already in the compute type. What a
+    grouped product leaves in the rows past the filled ones is undefined,
+    so they are zeroed before anything else reads them."""
+    dot = _partial(lax.ragged_dot, group_sizes=plan.group_sizes,
+                   preferred_element_type=rows.dtype)
+    filled = plan.valid[:, None]
+    h = jnp.where(filled, jax.nn.silu(dot(rows, w1)) * dot(rows, w3), 0)
+    return jnp.where(filled, dot(h.astype(rows.dtype), w2), 0)
+
+
+def dropless_share(x, router_logits, bias, w1, w3, w2, *, first: int,
+                   top_k: int, scaling: float):
+    """The routed part of an expert layer that this rank's experts give:
+    ``sum over chosen experts i in [first, first + held) of g_i * E_i(x)``.
+    No capacity and no dropped pair, whatever the imbalance.
+
+    x [T, D]; router_logits [T, E] over ALL experts; bias [E]; w1, w3
+    [held, D, M], w2 [held, M, D]. Returns ``(y [T, D] float32, counts [E]
+    float32: tokens of this batch that chose each expert, rows [held]
+    float32: rows each held expert computed)``."""
+    held = w1.shape[0]
+    _telemetry.inc("hvd_moe_layers_total", held=held,
+                   routed=router_logits.shape[-1], top_k=top_k)
+    with jax.named_scope("hvd_moe_route"):
+        choice, gates = sigmoid_topk_gates(router_logits, bias, top_k,
+                                           scaling)
+        plan = held_plan(choice, first, held)
+        counts = jnp.sum(jax.nn.one_hot(
+            choice.reshape(-1), router_logits.shape[-1], dtype=jnp.float32),
+            axis=0)
+    with jax.named_scope("hvd_moe_dispatch"):
+        rows = held_dispatch(x, plan)
+    with jax.named_scope("hvd_moe_experts"):
+        out = grouped_swiglu(rows, w1, w3, w2, plan)
+    with jax.named_scope("hvd_moe_combine"):
+        y = held_combine(out, gates, plan)
+    return y, counts, plan.group_sizes.astype(jnp.float32)
+
+
+def balance_bias(bias, counts, rate: float):
+    """The selection bias after a step (arXiv:2412.19437 section 2.1.2):
+    up by ``rate`` for an expert that got fewer tokens than the mean of the
+    GLOBAL batch's ``counts``, down for one that got more."""
+    return bias + rate * jnp.sign(counts.mean() - counts)
+
+
+def share_load(counts, rows, *, first: int):
+    """Host-side reading of one layer's router state after a step: the rows
+    each held expert received (max and mean over the global batch), the
+    share of (token, choice) pairs that landed on held experts, and the
+    pairs dropped, which is 0 by construction and asserted."""
+    import numpy as np
+    counts, rows = np.asarray(counts, np.float64), np.asarray(rows, np.float64)
+    held = counts[first:first + len(rows)]
+    dropped = int(held.sum() - rows.sum())
+    assert dropped == 0, f"{dropped} routed pairs computed no row"
+    return {"rows_max": float(held.max()), "rows_mean": float(held.mean()),
+            "held_share": float(held.sum() / max(counts.sum(), 1.0)),
+            "dropped": dropped}

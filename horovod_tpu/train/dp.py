@@ -39,7 +39,10 @@ class TrainState(NamedTuple):
     step: Any
     params: Any
     opt_state: Any
-    batch_stats: Any  # {} for models without BatchNorm
+    # Model state that no gradient trains and the step carries along:
+    # BatchNorm's running statistics, a router's selection bias and expert
+    # counts (the flax collection "batch_stats"). {} for models with none.
+    batch_stats: Any
 
 
 def create_train_state(model, rng, sample_input,

@@ -1,0 +1,75 @@
+"""Flash attention with a value depth of its own (latent attention: q and k
+192 deep, v and o 128): forward and the three backward kernels (fused one
+tile, dQ pass, dK/dV pass) in interpret mode against ``_reference_partial``."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+# ``horovod_tpu.ops`` re-exports the function under the module's name.
+fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+from test_ops import _bwd_kernels  # noqa: E402
+
+
+def _qkv(seed, B, T, H, D, Dv):
+    kq, kk, kv, kd = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(kq, (B, T, H, D), jnp.float32),
+            jax.random.normal(kk, (B, T, H, D), jnp.float32),
+            jax.random.normal(kv, (B, T, H, Dv), jnp.float32),
+            jax.random.normal(kd, (B, T, H, Dv), jnp.float32))
+
+
+def _reference(q, k, v, causal):
+    scale = 1.0 / q.shape[-1] ** 0.5
+    return fa._reference_partial(q, k, v, causal=causal, scale=scale)[0]
+
+
+# (T, block): one tile -> the fused backward; several -> the two passes.
+@pytest.mark.parametrize("T,block,kernels", [
+    (32, 32, ["hvd_flash_bwd"]),
+    (64, 16, ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"]),
+    (40, 16, ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"]),     # padded to 48
+], ids=["one_tile", "four_tiles", "padded_tiles"])
+@pytest.mark.parametrize("D,Dv", [(24, 16), (16, 24)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_forward_and_backward_at_another_value_depth(T, block, kernels, D,
+                                                     Dv, causal):
+    q, k, v, do = _qkv(T + D, 2, T, 2, D, Dv)
+
+    def flash(q, k, v):
+        return fa.flash_attention(q, k, v, causal=causal, block_q=block,
+                                  block_k=block)
+
+    assert _bwd_kernels(lambda *a: flash(*a).sum(), q, k, v) == kernels
+    out, vjp = jax.vjp(flash, q, k, v)
+    ref, ref_vjp = jax.vjp(lambda *a: _reference(*a, causal), q, k, v)
+    assert out.shape == (2, T, 2, Dv)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    for got, want, name in zip(vjp(do), ref_vjp(do), "qkv"):
+        assert got.shape == want.shape, name
+        np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5,
+                                   err_msg="d" + name)
+
+
+def test_partitioned_under_a_gspmd_mesh_at_another_value_depth(monkeypatch):
+    """The ``custom_partitioning`` wrappers give v, o, dO and dV their own
+    depth factor: batch*head sharded, both depths replicated."""
+    monkeypatch.setenv("HOROVOD_FLASH_PARTITION", "1")
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    q, k, v, _ = _qkv(7, 4, 64, 4, 24, 16)
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "tp"))
+    sharded = [jax.device_put(x, NamedSharding(
+        mesh, P("dp", None, "tp", None))) for x in (q, k, v)]
+
+    def loss(q, k, v):
+        return (fa.flash_attention(q, k, v, block_q=32, block_k=32) ** 2
+                ).sum()
+
+    f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    (value, grads), (want, want_grads) = f(*sharded), f(q, k, v)
+    np.testing.assert_allclose(value, want, rtol=1e-4)
+    for got, ref in zip(grads, want_grads):
+        np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
