@@ -20,7 +20,8 @@ All functions run inside ``shard_map`` over the ep axis.
 A second, DROPLESS form (``dropless_share`` and the functions above it, end
 of this file) serves sigmoid-routed layers with many small experts: no
 capacity, a selection bias that no gradient trains, and a rank that is told
-which experts it holds and computes their part of the layer's sum only.
+which experts it holds and computes their part of the layer's sum only,
+over the rows that the step filled and not over the plan's bound.
 """
 
 from __future__ import annotations
@@ -307,8 +308,9 @@ def routed_experts(x, router_logits, expert_fn: Callable, *,
 class HeldRouting(NamedTuple):
     """Plan for the (token, choice) pairs that land on held experts, sorted
     by expert. ``k*T`` rows is the static bound (every pair could land
-    here); ``group_sizes`` says how many are filled, and the grouped
-    products and every consumer follow that. Both directions of the mapping
+    here); ``group_sizes`` says how many are filled, at the front, and
+    :func:`dropless_share` works over windows of the plan that hold them
+    (:func:`_window`). Both directions of the mapping
     are kept so that dispatch and combine, forward and backward, are row
     gathers (see :class:`SortedRouting` for why not scatters)."""
     row_token: jnp.ndarray    # [k*T] int32: source token of each sorted row
@@ -426,32 +428,142 @@ def grouped_swiglu(rows, w1, w3, w2, plan: HeldRouting):
     return jnp.where(filled, dot(h.astype(rows.dtype), w2), 0)
 
 
-def dropless_share(x, router_logits, bias, w1, w3, w2, *, first: int,
-                   top_k: int, scaling: float):
-    """The routed part of an expert layer that this rank's experts give:
-    ``sum over chosen experts i in [first, first + held) of g_i * E_i(x)``.
-    No capacity and no dropped pair, whatever the imbalance.
+def plan_rows(pairs: int, held: int, routed: int) -> int:
+    """Rows of the smaller plan: twice what ``held`` of ``routed`` experts
+    get of ``pairs`` (token, choice) pairs in expectation, rounded up to the
+    grouped product's row tile, and never more than the whole bound."""
+    tile = 512 if jax.default_backend() == "tpu" else 8
+    return min(pairs, -(-2 * pairs * held // (routed * tile)) * tile)
 
-    x [T, D]; router_logits [T, E] over ALL experts; bias [E]; w1, w3
-    [held, D, M], w2 [held, M, D]. Returns ``(y [T, D] float32, counts [E]
-    float32: tokens of this batch that chose each expert, rows [held]
-    float32: rows each held expert computed)``."""
-    held = w1.shape[0]
-    _telemetry.inc("hvd_moe_layers_total", held=held,
-                   routed=router_logits.shape[-1], top_k=top_k)
-    with jax.named_scope("hvd_moe_route"):
-        choice, gates = sigmoid_topk_gates(router_logits, bias, top_k,
-                                           scaling)
-        plan = held_plan(choice, first, held)
-        counts = jnp.sum(jax.nn.one_hot(
-            choice.reshape(-1), router_logits.shape[-1], dtype=jnp.float32),
-            axis=0)
+
+def _window(plan: HeldRouting, start, n: int) -> HeldRouting:
+    """The plan over rows ``[start, start + n)`` alone: each held expert's
+    rows among them, and the pairs whose row lies there. A pair that is not
+    ``on`` may point anywhere; what it reads is discarded."""
+    ends = jnp.cumsum(plan.group_sizes)
+    cut = lambda edge: jnp.clip(edge, start, start + n)
+    dest = plan.dest - start
+    return HeldRouting(
+        *(lax.dynamic_slice_in_dim(rows, start, n)
+          for rows in (plan.row_token, plan.row_entry)),
+        jnp.clip(dest, 0, n - 1), plan.on & (dest >= 0) & (dest < n),
+        lax.dynamic_slice_in_dim(plan.valid, start, n),
+        cut(ends) - cut(ends - plan.group_sizes))
+
+
+def _routed_rows(x, gates, w1, w3, w2, plan: HeldRouting):
+    """Dispatch, experts and combine over the plan's rows: [T, D] float32."""
     with jax.named_scope("hvd_moe_dispatch"):
         rows = held_dispatch(x, plan)
     with jax.named_scope("hvd_moe_experts"):
         out = grouped_swiglu(rows, w1, w3, w2, plan)
     with jax.named_scope("hvd_moe_combine"):
-        y = held_combine(out, gates, plan)
+        return held_combine(out, gates, plan)
+
+
+@_partial(jax.jit, static_argnums=(0,))
+def _routed_window(n: int, start, plan: HeldRouting, x, gates, w1, w3, w2):
+    """:func:`_routed_rows` over rows ``[start, start + n)`` of the plan.
+    Jitted so that both branches, forward and backward, of every layer of
+    one shape are traced and lowered once."""
+    return _routed_rows(x, gates, w1, w3, w2, _window(plan, start, n))
+
+
+def _first_window_or_every(window, like, plan: HeldRouting, small: int,
+                           *operands):
+    """``window(start, plan, *operands)`` over the first ``small`` rows when
+    the filled ones fit there; when they do not, summed in float32 over as
+    many windows of ``small`` rows as are filled, so that no array of the
+    program has the bound's rows. ``like`` has the result's shapes and
+    types."""
+    def first(plan, *operands):
+        return window(jnp.int32(0), plan, *operands)
+
+    def every(plan, *operands):
+        pad = -plan.valid.shape[0] % small      # whole windows
+        if pad:
+            plan = plan._replace(**{
+                name: jnp.pad(getattr(plan, name), (0, pad))
+                for name in ("row_token", "row_entry", "valid")})
+        total = lax.fori_loop(
+            0, (plan.group_sizes.sum() + small - 1) // small,
+            lambda w, total: jax.tree_util.tree_map(
+                lambda t, part: t + part.astype(t.dtype), total,
+                window(w * small, plan, *operands)),
+            jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, jnp.float32), like))
+        return jax.tree_util.tree_map(lambda t, a: t.astype(a.dtype),
+                                      total, like)
+
+    return lax.cond(plan.group_sizes.sum() <= small, first, every,
+                    plan, *operands)
+
+
+@_partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _routed_two_plans(x, gates, w1, w3, w2, plan: HeldRouting, small: int):
+    """:func:`_routed_rows` over the smaller plan or over the bound by
+    windows (:func:`_first_window_or_every`). The choice lies outside the
+    residual path: differentiating through a ``lax.cond`` makes the
+    residuals of BOTH branches its outputs, so the forward returns ``y``
+    alone, saves its inputs, and the backward recomputes and transposes
+    inside the branch it takes."""
+    return _first_window_or_every(
+        _partial(_routed_window, small),
+        jax.ShapeDtypeStruct(x.shape, jnp.float32), plan, small,
+        x, gates, w1, w3, w2)
+
+
+def _routed_two_plans_fwd(x, gates, w1, w3, w2, plan, small):
+    return _routed_two_plans(x, gates, w1, w3, w2, plan, small), \
+        (x, gates, w1, w3, w2, plan)
+
+
+def _routed_two_plans_bwd(small, res, dy):
+    *args, plan = res
+
+    def pull(start, plan, dy, *args):
+        return jax.vjp(_partial(_routed_window, small, start, plan),
+                       *args)[1](dy)
+
+    # The barrier keeps XLA from moving the optimizer's float32 copy and
+    # square of each weight gradient into both branches, where they become
+    # outputs that are all live at once (0.28 GB more of the step's memory
+    # at 16 experts of 2048 x 768).
+    grads = lax.optimization_barrier(_first_window_or_every(
+        pull, tuple(args), plan, small, dy, *args))
+    return (*grads, jax.tree_util.tree_map(_zero_tan, plan))
+
+
+_routed_two_plans.defvjp(_routed_two_plans_fwd, _routed_two_plans_bwd)
+
+
+def dropless_share(x, router_logits, bias, w1, w3, w2, *, first: int,
+                   top_k: int, scaling: float):
+    """The routed part of an expert layer that this rank's experts give:
+    ``sum over chosen experts i in [first, first + held) of g_i * E_i(x)``.
+    No capacity and no dropped pair, whatever the imbalance: the plan's
+    bound is every pair, and the rows are worked over a smaller plan
+    (:func:`plan_rows`) only in a step whose filled rows fit it.
+
+    x [T, D]; router_logits [T, E] over ALL experts; bias [E]; w1, w3
+    [held, D, M], w2 [held, M, D]. Returns ``(y [T, D] float32, counts [E]
+    float32: tokens of this batch that chose each expert, rows [held]
+    float32: rows each held expert computed)``."""
+    held, routed = w1.shape[0], router_logits.shape[-1]
+    pairs = top_k * x.shape[0]
+    small = plan_rows(pairs, held, routed)
+    _telemetry.inc("hvd_moe_layers_total", held=held, routed=routed,
+                   top_k=top_k, plan_rows=small)
+    with jax.named_scope("hvd_moe_route"):
+        choice, gates = sigmoid_topk_gates(router_logits, bias, top_k,
+                                           scaling)
+        plan = held_plan(choice, first, held)
+        counts = jnp.sum(jax.nn.one_hot(
+            choice.reshape(-1), routed, dtype=jnp.float32), axis=0)
+    if small == pairs:
+        y = _routed_rows(x, gates, w1, w3, w2, plan)
+    else:
+        y = _routed_two_plans(x, gates, w1, w3, w2, plan, small)
     return y, counts, plan.group_sizes.astype(jnp.float32)
 
 
@@ -465,13 +577,16 @@ def balance_bias(bias, counts, rate: float):
 def share_load(counts, rows, *, first: int):
     """Host-side reading of one layer's router state after a step: the rows
     each held expert received (max and mean over the global batch), the
-    share of (token, choice) pairs that landed on held experts, and the
-    pairs dropped, which is 0 by construction and asserted."""
+    share of (token, choice) pairs that landed on held experts, the rows of
+    the smaller plan (:func:`plan_rows`) and whether the filled ones fit it,
+    and the pairs dropped, which is 0 by construction and asserted."""
     import numpy as np
     counts, rows = np.asarray(counts, np.float64), np.asarray(rows, np.float64)
     held = counts[first:first + len(rows)]
     dropped = int(held.sum() - rows.sum())
     assert dropped == 0, f"{dropped} routed pairs computed no row"
+    small = plan_rows(int(counts.sum()), len(rows), len(counts))
     return {"rows_max": float(held.max()), "rows_mean": float(held.mean()),
             "held_share": float(held.sum() / max(counts.sum(), 1.0)),
+            "plan_rows": small, "small_plan": bool(rows.sum() <= small),
             "dropped": dropped}

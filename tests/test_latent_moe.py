@@ -177,10 +177,130 @@ def test_nothing_is_dropped_when_one_held_expert_gets_most_rows():
     np.testing.assert_array_equal(rows, counts[first:first + held])
     assert moe.share_load(counts, rows, first=first) == pytest.approx({
         "rows_max": float(T), "rows_mean": float(rows.mean()),
-        "held_share": float(rows.sum() / (T * k)), "dropped": 0})
+        "held_share": float(rows.sum() / (T * k)), "plan_rows": 144,
+        "small_plan": bool(rows.sum() <= 144), "dropped": 0})
     np.testing.assert_allclose(y, want, atol=1e-5, rtol=1e-5)
     for g, w in zip(grads, want_grads):
         np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4)
+
+
+# the two-plan cases: 1,024 tokens x 4 choices of 16 experts are 4,096 pairs,
+# and the smaller plan 2 * 4096 * 2 / 16 = 1,024 rows when 2 experts are held
+PLAN_T, PLAN_K, PLAN_E, PLAN_D, PLAN_M, PLAN_FIRST = 1024, 4, 16, 16, 8, 6
+
+
+def _plan_case(held, filled=None, biased=0, T=PLAN_T):
+    """Inputs of ``dropless_share`` for ``T`` tokens. ``filled`` pins the
+    number of held pairs: the held experts' logits are -6 (never among the
+    top of 16) but for that many (token, held expert) pairs at +6 (always
+    among them). ``biased`` held experts are chosen by every token."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 5)
+    x = jax.random.normal(keys[0], (T, PLAN_D))
+    w1, w3 = (jax.random.normal(kk, (held, PLAN_D, PLAN_M)) * 0.3
+              for kk in keys[1:3])
+    w2 = jax.random.normal(keys[3], (held, PLAN_M, PLAN_D)) * 0.3
+    logits = jax.random.normal(keys[4], (T, PLAN_E))
+    if filled is not None:
+        mine = np.full(T * held, -6.0, np.float32)
+        mine[:filled] = 6.0
+        logits = logits.at[:, PLAN_FIRST:PLAN_FIRST + held].set(
+            mine.reshape(held, T).T)
+    bias = jnp.zeros(PLAN_E).at[PLAN_FIRST:PLAN_FIRST + biased].set(10.0)
+    return (x, logits, w1, w3, w2), bias
+
+
+def _share_with_grads(bias, top_k=PLAN_K):
+    def share(x, logits, w1, w3, w2):
+        y, counts, rows = moe.dropless_share(
+            x, logits, bias, w1, w3, w2, first=PLAN_FIRST, top_k=top_k,
+            scaling=2.5)
+        return (y ** 2).sum(), (y, counts, rows)
+    return jax.value_and_grad(share, argnums=(0, 1, 2, 3, 4), has_aux=True)
+
+
+def _subjaxprs(jaxpr):
+    """``jaxpr`` and every jaxpr nested in its equations' parameters."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _subjaxprs(sub)
+
+
+def _eqns(jaxpr, primitive):
+    return [eqn for sub in _subjaxprs(jaxpr) for eqn in sub.eqns
+            if eqn.primitive.name == primitive]
+
+
+@pytest.mark.parametrize("T, k, held, filled, biased, small, fits", [
+    pytest.param(1024, 4, 2, None, 0, 1024, True, id="small_plan"),
+    pytest.param(1024, 4, 2, None, 1, 1024, False,
+                 id="every_token_on_a_held_expert"),
+    pytest.param(1024, 4, 2, 1024, 0, 1024, True, id="filled_is_plan_rows"),
+    pytest.param(1024, 4, 2, 1025, 0, 1024, False, id="one_row_over"),
+    # 200 pairs, all on held experts, in four windows of 56: the last padded
+    pytest.param(100, 2, 2, None, 2, 56, False,
+                 id="every_window_and_a_ragged_last"),
+    pytest.param(1024, 4, 8, None, 0, 4096, True, id="no_second_plan"),
+])
+def test_both_plans_give_the_masked_loops_layer_and_drop_nothing(
+        T, k, held, filled, biased, small, fits):
+    args, bias = _plan_case(held, filled, biased, T)
+    assert moe.plan_rows(k * T, held, PLAN_E) == small
+
+    def loop(x, logits, w1, w3, w2):
+        y, counts = _masked_loop(x, logits, bias, w1, w3, w2, PLAN_FIRST,
+                                 k, 2.5)
+        return (y ** 2).sum(), (y, counts)
+
+    share = _share_with_grads(bias, k)
+    (_, (y, counts, rows)), grads = share(*args)
+    (_, (want, want_counts)), want_grads = jax.value_and_grad(
+        loop, argnums=(0, 1, 2, 3, 4), has_aux=True)(*args)
+    if filled is not None:
+        assert float(rows.sum()) == filled
+    if biased == held:
+        assert float(rows.sum()) == k * T
+    assert (float(rows.sum()) <= small) == fits
+    np.testing.assert_array_equal(counts, want_counts)
+    np.testing.assert_array_equal(rows,
+                                  counts[PLAN_FIRST:PLAN_FIRST + held])
+    load = moe.share_load(counts, rows, first=PLAN_FIRST)
+    assert load["dropped"] == 0
+    assert (load["plan_rows"], load["small_plan"]) == (small, fits)
+    np.testing.assert_allclose(y, want, atol=1e-5, rtol=1e-5)
+    assert float(np.abs(y).max()) > 0
+    for g, w in zip(grads, want_grads):
+        np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4)
+    # one conditional forward and one backward, or today's program
+    assert len(_eqns(jax.make_jaxpr(share)(*args).jaxpr, "cond")) \
+        == (2 if small < k * T else 0)
+
+
+def test_no_branch_holds_an_array_of_the_bounds_rows():
+    """Forward and backward, the ``filled <= plan_rows`` branch works on
+    ``[1024, .]`` rows with no loop, the other on windows of as many, and
+    nothing in either has the bound's 4,096; and the forward conditional
+    returns ``y`` alone, so differentiation has made no residual of either
+    branch its output."""
+    args, bias = _plan_case(2)
+    forward, backward = sorted(
+        _eqns(jax.make_jaxpr(_share_with_grads(bias))(*args).jaxpr, "cond"),
+        key=lambda cond: len(cond.outvars))
+    assert [v.aval.shape for v in forward.outvars] == [(PLAN_T, PLAN_D)]
+    assert [v.aval.shape for v in backward.outvars] == [
+        args[0].shape, (PLAN_T, PLAN_K), *(a.shape for a in args[2:])]
+    for cond in (forward, backward):
+        every, first = cond.params["branches"]      # index = the predicate
+        for branch in (every, first):
+            rows = {v.aval.shape[0] for sub in _subjaxprs(branch.jaxpr)
+                    for eqn in sub.eqns for v in (*eqn.invars, *eqn.outvars)
+                    if len(getattr(v.aval, "shape", ())) >= 2}
+            assert 1024 in rows and PLAN_T * PLAN_K not in rows
+        assert len(_eqns(every.jaxpr, "while")) == 1
+        assert not _eqns(first.jaxpr, "while")
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -255,7 +375,7 @@ def test_the_layers_carry_their_scopes_and_the_counter_fires_once_a_trace():
     dopt = distributed(optax.sgd(0.1))
     model = LatentMoE(c)
     state = create_train_state(model, jax.random.PRNGKey(0), tokens[:1], dopt)
-    labelled = dict(held=2, routed=8, top_k=3)
+    labelled = dict(held=2, routed=8, top_k=3, plan_rows=24)
     before = T.active().registry.counter_value("hvd_moe_layers_total",
                                                **labelled)
     step = make_train_step(model, dopt, next_tokens_loss(0.3))
@@ -263,8 +383,13 @@ def test_the_layers_carry_their_scopes_and_the_counter_fires_once_a_trace():
     for scope in ("hvd_mla", "hvd_moe_route", "hvd_moe_dispatch",
                   "hvd_moe_experts", "hvd_moe_shared", "hvd_moe_combine",
                   "hvd_mtp"):
+        # the routed part's gathers lie in functions that every layer
+        # shares, and start their names at the scope
         assert f"hvd_forward/LatentMoE/{scope}" in text \
-            or f"/{scope}" in text, scope
+            or f"/{scope}" in text or f'"{scope}/' in text, scope
     # trace-time: one a traced expert layer (two in the trunk, the module's)
     assert T.active().registry.counter_value(
         "hvd_moe_layers_total", **labelled) - before == 3.0
+    # the two plans' conditionals hold no collective and carry no state
+    from horovod_tpu.analysis import analyze_step
+    assert analyze_step(step, state, tokens, labels) == []
