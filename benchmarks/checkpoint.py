@@ -42,7 +42,7 @@ its digest via the writer's identity cache. ``dedup_bytes_ratio`` =
 total bytes actually written / (commits x first-commit bytes) — a
 frame-per-commit checkpointer scores 1.0, the CAS must score well under.
 
-Prints ONE JSON line (bench.py schema): ``checkpoint_commit_stall``
+Prints one JSON line: ``checkpoint_commit_stall``
 ratio (async/sync, median of interleaved samples) with the wall slopes,
 dedup ratio and cold ``load_latest`` resume latency as extra fields.
 

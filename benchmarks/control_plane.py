@@ -32,11 +32,10 @@ journal compaction preserves ``version``/``failure_seq`` (and the rest
 of the state) byte-for-byte against an uncompacted replay, through a
 simulated crash.
 
-Emits ONE JSON line (bench.py convention) and appends it — stamped with
+Emits one JSON line and appends it — stamped with
 date + git SHA — to ``benchmarks/control_plane_history.jsonl`` unless
 ``HOROVOD_CONTROL_PLANE_NO_HISTORY`` is set. ``--check`` validates the
-newest history record (presence + ranges) the way
-tests/test_scaling_guardrail.py pins the dp8 series; ``--smoke N`` runs
+newest history record (presence + ranges); ``--smoke N`` runs
 one delta round at N workers for the chaos-tier budget test.
 """
 
@@ -66,8 +65,8 @@ HISTORY_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                             "control_plane_history.jsonl")
 NO_HISTORY_ENV = "HOROVOD_CONTROL_PLANE_NO_HISTORY"
 
-#: --check rails (mirrors tests/test_scaling_guardrail.py's HARD band
-#: philosophy: fail only on movement no stated noise explains).
+#: --check rails (HARD bands: fail only on movement no stated noise
+#: explains).
 MIN_BYTES_RATIO = 5.0        # acceptance: >=5x fewer bytes per change
 MAX_SUBLINEAR_FRACTION = 0.75  # delta req/s growth <= 75% of world growth
 MAX_RENDEZVOUS_S = 30.0

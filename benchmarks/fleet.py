@@ -40,7 +40,7 @@ What one committed record (``benchmarks/fleet_history.jsonl``) holds:
   crash-replay substrate tests/test_fleet_chaos.py SIGKILLs), and the
   serving min/max the trace actually visited.
 
-Emits ONE JSON line (bench.py convention) and appends it — stamped
+Emits one JSON line and appends it — stamped
 with date + git SHA — unless ``HOROVOD_FLEET_NO_HISTORY`` is set.
 ``--check`` validates the newest committed record against the rails;
 ``--smoke`` runs a shrunk trace for the chaos tier.

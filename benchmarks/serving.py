@@ -88,7 +88,7 @@ steady-state recompiles required in every arm; the per-arm ratios
 land in perf_history as ``kind: "spec_decode"`` records ratcheted by
 ``tools.perf check``.
 
-Emits ONE JSON line (bench.py convention) and appends it — stamped with
+Emits one JSON line and appends it — stamped with
 date + git SHA — to ``benchmarks/serving_history.jsonl`` unless
 ``HOROVOD_SERVING_NO_HISTORY`` is set. ``--check`` validates the newest
 history record the way tests/test_control_plane_guardrail.py pins the
@@ -1242,8 +1242,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         dec = rec.get("decode") or {}
         if isinstance(dec.get("speedup_vs_full"), (int, float)):
             # Ratchet the decode win in perf_history too, so
-            # `tools.perf check` rails it per (model, arm) like the
-            # remat-sweep ratios (respects HOROVOD_PERF_NO_HISTORY).
+            # `tools.perf check` rails it per (model, arm)
+            # (respects HOROVOD_PERF_NO_HISTORY).
             from horovod_tpu.tools import perf as perf_tools
             perf_tools.append_history({
                 "kind": "perf_ratio",

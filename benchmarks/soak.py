@@ -11,7 +11,7 @@ fresh process, and no orphaned processes survive.
 
 The schedule is a pure function of ``--seed`` (same seed, same
 schedule — a red soak is re-runnable byte for byte; pinned by
-tests/test_soak.py). Emits ONE JSON line (bench.py convention) and
+tests/test_soak.py). Emits one JSON line and
 appends it — stamped with date + git SHA — to
 ``benchmarks/soak_history.jsonl`` unless ``HOROVOD_SOAK_NO_HISTORY`` is
 set. ``--check`` validates the newest committed record against the

@@ -184,9 +184,10 @@ def _require_mosaic(facts, at_least, what):
 
 
 def _slope_step_ms(model, dopt, loss_fn, state, batch, ks, rounds):
-    """bench.py's timing method, for ROADMAP S0 to compare against the
-    per-step block_until_ready number: seconds per step as the slope
-    between a short and a long device-side ``lax.scan`` of the step."""
+    """Slope timing, beside the per-step block_until_ready number: seconds
+    per step as the slope between a short and a long device-side
+    ``lax.scan`` of the step. A bring-up fact, not a benchmark metric
+    (ROADMAP D9: these timing lines are the remainder to remove)."""
     import jax
     import numpy as np
     from horovod_tpu.train import make_train_step

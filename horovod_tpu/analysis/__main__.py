@@ -42,8 +42,7 @@ from .jaxpr import analyze_step
 from .trap_lint import lint_paths
 
 REPO_SELF_LINT_TARGETS = (
-    "horovod_tpu", "tests", "benchmarks", "examples",
-    "bench.py", "__graft_entry__.py",
+    "horovod_tpu", "tests", "benchmarks", "examples", "__graft_entry__.py",
 )
 
 ANALYZE_HOOK = "HVD_ANALYZE"

@@ -10,7 +10,6 @@ the lowered stablehlo / optimized HLO:
 
 - fusion-threshold collective counts + donation (``dp-step-fusion``),
 - accumulation's single-allreduce discipline (``dp-step-accum``),
-- bench-arm graph parity (``bench-arms-parity``),
 - deferral inertness at ``every=1`` and probe DCE
   (``gspmd-deferred-every1`` / ``gspmd-deferred-programs``),
 - ppermute topology × payload × hop-count for the adasum butterfly,
@@ -22,8 +21,8 @@ the lowered stablehlo / optimized HLO:
 
 Builds are memoized per process and cache ONLY summaries and plain
 numbers (never live device arrays), so the thin pytest drivers
-(tests/test_wire_contracts.py, test_fusion.py, test_bench_parity.py,
-test_step_builder.py) and the full ``--contracts`` matrix share one
+(tests/test_wire_contracts.py, test_fusion.py, test_step_builder.py,
+test_contracts.py) and the full ``--contracts`` matrix share one
 build per family.  Violations surface as ``contract-<family>`` ERROR
 findings through the same :class:`~.findings.Finding` pipeline as the
 lint and jaxpr engines (``--json`` / ``--sarif`` included).
@@ -402,61 +401,6 @@ def _verify_dp_step_accum(b) -> List[str]:
         problems.append(
             "donate=False accumulation step unexpectedly aliases "
             "buffers — donation flag is not being honored")
-    return problems
-
-
-# ------------------------------------------------- family: bench parity
-
-def _build_bench_arms_parity():
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-    import horovod_tpu as hvd
-    from ..models import ResNetTiny
-    from ..optimizer import distributed
-    from ..train import create_train_state, make_train_step
-    from .hlo import summarize_optimized
-
-    _reinit()
-    rng = np.random.RandomState(0)
-    images = jnp.asarray(rng.randn(4, 32, 32, 3).astype(np.float32))
-    labels = jnp.asarray(rng.randint(0, 1000, size=(4,)))
-    mesh1 = jax.sharding.Mesh(np.asarray(jax.devices()[:1]),
-                              (hvd.RANK_AXIS,))
-
-    model = ResNetTiny(num_classes=1000, axis_name=hvd.RANK_AXIS,
-                       dtype=jnp.float32)
-    dopt = distributed(optax.sgd(0.1, momentum=0.9))
-    state = create_train_state(model, jax.random.PRNGKey(0), images[:1],
-                               dopt, mesh=mesh1)
-    step_hvd = make_train_step(model, dopt, _xent, scan_steps=4,
-                               mesh=mesh1, donate=False)
-
-    model_p = ResNetTiny(num_classes=1000, axis_name=None,
-                         dtype=jnp.float32)
-    popt = optax.sgd(0.1, momentum=0.9)
-    pstate = create_train_state(model_p, jax.random.PRNGKey(0),
-                                images[:1], popt, broadcast=False,
-                                mesh=mesh1)
-    step_plain = make_train_step(model_p, popt, _xent, scan_steps=4,
-                                 mesh=mesh1, donate=False)
-    return {
-        "hvd": summarize_optimized(
-            step_hvd.lower(state, images, labels).compile().as_text()),
-        "plain": summarize_optimized(
-            step_plain.lower(pstate, images, labels).compile().as_text()),
-    }
-
-
-def _verify_bench_arms_parity(b) -> List[str]:
-    problems = []
-    for arm in ("hvd", "plain"):
-        if b[arm].ops():
-            problems.append(
-                f"bench {arm} arm compiled with collectives on the "
-                f"1-device mesh: {b[arm].ops()} — force_axis_size1 must "
-                f"collapse everything to identity")
     return problems
 
 
@@ -852,11 +796,6 @@ def _register_builtin() -> None:
          "and donate=True survives the microbatch scan",
          "horovod_tpu/train/step_builder.py",
          _build_dp_step_accum, _verify_dp_step_accum),
-        ("bench-arms-parity",
-         "bench.py's hvd arm vs plain arm compile to identical (empty) "
-         "collective sets on the 1-device mesh",
-         "bench.py",
-         _build_bench_arms_parity, _verify_bench_arms_parity),
         ("gspmd-deferred-every1",
          "make_gspmd_deferred_train_step(every=1) emits collective HLO "
          "signature-identical to the standard GSPMD step",

@@ -90,8 +90,8 @@ CLAUDE.md "Environment traps"):
   leaf children — summing them double counts the step; and the "Async
   XLA Ops" line carries overlapped DMA *windows*, not occupancy — adding
   it to device-busy time invents throughput.  Route xplane parsing
-  through the vetted parsers (``benchmarks/xprof.py``,
-  ``horovod_tpu.tools.perf``), filter on ``UMBRELLA_PREFIXES``, or
+  through the vetted parsers (``horovod_tpu.tools.perf``,
+  ``chipbench/trace.py``), filter on ``UMBRELLA_PREFIXES``, or
   pragma a span-sum that is deliberately a wall/overlap figure.
 
 - ``lint-decode-host-sync`` (WARNING): a host loop that drives a decode
@@ -798,8 +798,8 @@ class _Lint(ast.NodeVisitor):
                     "events are scan/module umbrellas covering their "
                     "children (double counts the step), and 'Async XLA "
                     "Ops' spans are overlap windows, not occupancy — "
-                    "use the vetted parsers (benchmarks/xprof.py, "
-                    "tools/perf.py), filter on UMBRELLA_PREFIXES, or "
+                    "use the vetted parsers (horovod_tpu.tools.perf, "
+                    "chipbench/trace.py), filter on UMBRELLA_PREFIXES, or "
                     "pragma a deliberate wall/overlap sum")
 
         if name.endswith("slope_time_paired"):
@@ -1030,8 +1030,8 @@ class _Lint(ast.NodeVisitor):
                     "events are scan/module umbrellas covering their "
                     "children (double counts the step), and 'Async XLA "
                     "Ops' spans are overlap windows, not occupancy — "
-                    "use the vetted parsers (benchmarks/xprof.py, "
-                    "tools/perf.py), filter on UMBRELLA_PREFIXES, or "
+                    "use the vetted parsers (horovod_tpu.tools.perf, "
+                    "chipbench/trace.py), filter on UMBRELLA_PREFIXES, or "
                     "pragma a deliberate wall/overlap sum")
 
     def visit_For(self, node):

@@ -240,9 +240,9 @@ def render_prometheus(per_rank: Dict[Any, Dict[str, Dict[str, float]]]) -> str:
 
     Per-rank samples get a ``rank`` label injected; the fleet rollup is
     emitted with no ``rank`` label — counters SUMMED across ranks, gauges
-    AVERAGED (a sum of per-rank ``hvd_step_mfu_proxy``/wall gauges would
-    be meaningless; the across-rank mean is the fleet MFU-proxy the
-    coordinator dashboard wants — ISSUE 11).
+    AVERAGED (a sum of per-rank ``hvd_step_wall_seconds`` gauges would be
+    meaningless; the across-rank mean is the fleet's step wall the
+    coordinator dashboard wants).
     """
     lines: List[str] = []
     typed: set = set()

@@ -552,10 +552,7 @@ class StepMonitor:
     def _note_step_done(self, what: str, started: Optional[float]) -> None:
         """Per-step telemetry: counters/histogram plus a ring event. All
         inputs are host scalars the monitor already holds — never a
-        device fetch (lint-blocking-telemetry guards this invariant).
-        The MFU proxy divides cost-analysis FLOPs (registered once per
-        program via ``tools.perf.register_step_flops``) by the step wall
-        — a ratio of two host scalars, available live every step."""
+        device fetch (lint-blocking-telemetry guards this invariant)."""
         end = time.monotonic()
         dt = (end - started) if started is not None else 0.0
         with self._lock:
@@ -564,12 +561,6 @@ class StepMonitor:
         _telemetry.observe("hvd_step_seconds", dt, what=what)
         _telemetry.set_gauge("hvd_last_step", n)
         _telemetry.set_gauge("hvd_step_wall_seconds", dt, what=what)
-        if dt > 0:
-            from ..tools import perf as _perf
-            flops = _perf.registered_step_flops(what)
-            proxy = _perf.mfu_proxy(flops, dt) if flops else None
-            if proxy is not None:
-                _telemetry.set_gauge("hvd_step_mfu_proxy", proxy, what=what)
         _telemetry.record_event("step_end", what=what, step=n,
                                 seconds=round(dt, 6))
 

@@ -15,7 +15,7 @@ import jax
 # 8-head seq-512 config measured materialised 6.5% FASTER (half BERT's
 # heads = half the grid), while seq 1024 favors flash by 21% even at 8
 # heads. Models near the boundary with few heads should pass an explicit
-# use_flash (benchmarks/mixtral.py does).
+# use_flash.
 AUTO_MIN_SEQ = 512
 
 

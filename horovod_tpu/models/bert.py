@@ -135,9 +135,9 @@ class Bert(nn.Module):
                          name="mlm_norm")(x)
         # Deliberately f32xf32 (NOT the llama.py bf16-operand head): the
         # bf16+f32-accum variant measured 0.5% SLOWER interleaved at the
-        # bench config — XLA already decomposes this f32 matmul
-        # efficiently at [4096, 1024] x [1024, 30522] (docs/benchmarks.md,
-        # BERT profile section).
+        # BERT-Large shape — XLA already decomposes this f32 matmul
+        # efficiently at [4096, 1024] x [1024, 30522] (a builder's chip run
+        # before PR 1).
         logits = jnp.einsum("btd,vd->btv", x.astype(jnp.float32), emb)
         return logits
 

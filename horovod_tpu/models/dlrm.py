@@ -133,7 +133,8 @@ def sparse_adagrad_update(tables_flat, accum_flat, flat_idx, row_grads,
     lets the caller pin a row-major jit layout — XLA's entry-layout
     heuristic otherwise picks a gather-friendly transposed layout and
     inserts four whole-table transpose copies per step around the
-    scatters (~12 ms/step measured; see benchmarks/dlrm.py).
+    scatters (~12 ms/step measured on the chip before the pin; CLAUDE.md,
+    the entry-layout trap).
 
     tables_flat/accum_flat: [N, D]; flat_idx: [K] int; row_grads: [K, D]
     (d loss / d looked-up rows). Returns (tables, accum) updated.
@@ -169,9 +170,8 @@ def build_sparse_training(model, cfg, mesh, rules, params, *,
                           lr: float = 1e-2, eps: float = 1e-7,
                           acc0: float = 0.1):
     """Complete sparse-embedding training setup — ONE definition of the
-    flat tables, PINNED row-major jit layouts, and donation, shared by
-    `benchmarks/dlrm.py`, `benchmarks/profile_dlrm.py` and
-    `examples/train_dlrm.py` (two hand-maintained copies drifted twice
+    flat tables, PINNED row-major jit layouts, and donation, used by
+    `examples/train_dlrm.py` (hand-maintained copies drifted twice
     in r4 review; the layout pin is load-bearing: without it XLA's
     entry-layout heuristic transposes the whole tables around the row
     scatters, 4 × ~666MB copies/step at the criteo config).
@@ -222,7 +222,7 @@ def make_sparse_dlrm_step(model, cfg, opt_dense, *, lr: float,
     exist only for the [B, T, D] looked-up rows, never as dense [T, R, D]
     tables. Tables ride FLAT as [T*R, D] (see sparse_adagrad_update for
     the layout rationale; callers should pin a row-major layout on the
-    tables/accum jit params, as benchmarks/dlrm.py does). Returns
+    tables/accum jit params, as :func:`build_sparse_training` does). Returns
     ``step(dense_params, tables_flat, accum_flat, opt_state, d, s, y) ->
     (dense_params, tables_flat, accum_flat, opt_state, loss)``, jittable
     with all array args donated. On a multi-chip mesh pass the resolved

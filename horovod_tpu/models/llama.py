@@ -72,9 +72,9 @@ class LlamaConfig:
     # the scan's loop-carried [L,...] gradient stacks cost a
     # dynamic-update-slice write-back per weight per layer per step —
     # measured 12.6% of the Llama step, and +13% / +22% / +14.5%
-    # throughput from unrolling at the Llama / Mixtral / longctx bench
-    # configs (r5, docs/benchmarks.md). Prefer False for production
-    # training runs when the ~3x compile time is acceptable.
+    # throughput from unrolling at toy-width Llama / Mixtral / long-context
+    # configs (builders' chip runs before PR 1, no cell). Prefer False for
+    # production training runs when the ~3x compile time is acceptable.
     # "auto" (the default): unroll when n_layers is small enough to
     # compile fast (≤ SCAN_LAYERS_AUTO_THRESHOLD), scan above it —
     # small/test configs get the throughput win for free, big configs
@@ -155,20 +155,6 @@ def _remat(cls, policy_name: str, prevent_cse: bool = False):
                          f"{sorted(_REMAT_POLICIES)}")
     return nn.remat(cls, prevent_cse=prevent_cse,
                     policy=_REMAT_POLICIES[policy_name])
-
-
-def with_remat_policy(c: "LlamaConfig", policy: str) -> "LlamaConfig":
-    """``c`` with its remat arm set by ONE name — the vocabulary the
-    compute-tier sweep (benchmarks/remat_sweep.py) enumerates. ``"none"``
-    disables remat entirely (save every residual — the fastest arm
-    whenever the activations fit); any ``_REMAT_POLICIES`` key enables
-    remat under that checkpoint policy."""
-    if policy == "none":
-        return dataclasses.replace(c, remat=False)
-    if policy not in _REMAT_POLICIES:
-        raise ValueError(f"remat policy {policy!r} not in "
-                         f"{['none'] + sorted(_REMAT_POLICIES)}")
-    return dataclasses.replace(c, remat=True, remat_policy=policy)
 
 
 def _part(init, names):

@@ -76,8 +76,8 @@ class MoEMLP(nn.Module):
         capacity = max(1, int(c.capacity_factor * c.top_k * B * T / E))
         # Sort-based dispatch plan (r4): the one-hot [T,E,C] einsum
         # dispatch cost more device time than the expert matmuls at the
-        # bench config (profile_mixtral.py) — row gather/scatter moves
-        # O(k·T·D) bytes instead.
+        # toy-width config a builder traced before PR 1 — row
+        # gather/scatter moves O(k·T·D) bytes instead.
         r = topk_router_sorted(logits, E, capacity, c.top_k)
         self.sow("losses", "router_aux", r.aux_loss)
 

@@ -3,7 +3,8 @@
 An 8-expert top-2 MoE carries an 8x-overprovisioned expert bank whose
 AdamW pass is pure HBM traffic independent of batch: every step reads
 grad+param+m+v and writes param+m+v for mostly-inactive weights
-(profiled at 12.8% of the Mixtral step, docs/benchmarks.md). The three
+(12.8% of a Mixtral step in a builder's trace before PR 1; no cell of the
+benchmark runs it). The three
 standard levers, each expressible per-subtree so the dense params keep
 exact AdamW:
 
@@ -219,8 +220,7 @@ def partition(transforms: dict,
 
 def is_expert_param(path: str) -> bool:
     """The routed expert bank: ``moe/{w1,w2,w3}`` leaves (leading E dim);
-    router and norms are always-active (same selector as the MoE MFU
-    accounting in benchmarks/mixtral.py)."""
+    router and norms are always-active."""
     return "moe" in path and path.rsplit("/", 1)[-1] in ("w1", "w2", "w3")
 
 
@@ -230,7 +230,7 @@ def frozen_like(inner: optax.GradientTransformation):
     because the state is an unmodified donated jit input, XLA aliases its
     buffers to the output — zero HBM traffic — which ``lax.cond`` inside
     one program cannot do (measured: the cond form's pass-through copies
-    ate the entire saving, docs/benchmarks.md r5)."""
+    ate the entire saving; CLAUDE.md, the ``lax.cond`` trap)."""
 
     def update(updates, state, params=None):
         del params

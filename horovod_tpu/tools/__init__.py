@@ -2,8 +2,8 @@
 
 - :class:`Timeline` — host-side Chrome-trace writer (HOROVOD_TIMELINE).
 - :mod:`profiler` — device-side xplane traces (jax.profiler wrappers).
-- :mod:`perf` — step-time budgets from xplane traces, the per-model MFU
-  ratchet over ``benchmarks/perf_history.jsonl``, and regression diffs
+- :mod:`perf` — step-time budgets from xplane traces of one's own job,
+  the ratchet over a history of such records, and regression diffs
   (``python -m horovod_tpu.tools.perf`` — docs/profiling.md).
 - :class:`StallInspector` — step-progress watchdog (HOROVOD_STALL_CHECK_*).
 - :class:`MismatchDetector` — debug cross-process collective-signature

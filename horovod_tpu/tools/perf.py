@@ -4,9 +4,11 @@ Reference analog: the per-op ``horovod/common/timeline.cc`` record (where a
 step's time goes, op by op) paired with the autotuner's measurement loop
 (``horovod/common/autotuner`` — measure, persist, only accept configs that
 beat the incumbent). Here the measurement source is an ``xplane`` trace
-(``jax.profiler``) and the persistence is ``benchmarks/perf_history.jsonl``:
-each model's best measured MFU becomes a railed floor (``tools.perf check``)
-so perf wins compound instead of evaporating between bench rounds.
+(``jax.profiler``) and the persistence is a JSON-lines history
+(``$HOROVOD_PERF_HISTORY``, by default ``benchmarks/perf_history.jsonl``,
+which is written at run time and not committed): each model's best recorded
+MFU becomes a railed floor (``tools.perf check``). What decides a PR's speed
+is ``python3 -m chipbench.run`` and ``PERF_LEDGER.jsonl``, not this history.
 
 The canonical artifact is the **step-time budget**: device time decomposed
 into disjoint occupancy categories that sum to device wall within tolerance:
@@ -27,9 +29,9 @@ module *umbrellas* whose spans cover their children — counting them double
 counts the step; "Async XLA Ops" are overlapped DMA *windows*, not occupancy
 — they only feed the hidden-collective intersection.
 
-Module-level imports are stdlib-only on purpose: ``benchmarks/xprof.py``
-pulls the interval core from here lazily (before the jax backend is up) and
-``core/watchdog.py`` reads the registered-FLOPs table on its hot path.
+Module-level imports are stdlib-only on purpose: the CLI reads a history
+without jax or tensorflow; ``load_xspace`` and ``attribute_logdir`` import
+them when a trace is parsed.
 
 CLI::
 
@@ -50,7 +52,6 @@ import json
 import math
 import os
 import re
-import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 # --------------------------------------------------------------- env knobs
@@ -66,12 +67,6 @@ RATCHET_BAND_ENV = "HOROVOD_PERF_RATCHET_BAND"
 DEFAULT_RATCHET_BAND = 0.90
 #: Shape rail: budget categories must sum to device wall within this.
 SUM_TOLERANCE = 0.05
-#: Cross-session noise band of the headline ``vs_baseline`` ratio
-#: (bench.py's interleaved plain-vs-hvd paired slopes). A reading inside
-#: ``1 − band`` is noise; below ``1 − 2×band`` is a real breach. The
-#: readings it was set from were removed with PR 21 and the band has not
-#: been re-derived on the current chip machine (PERF.md, open questions).
-DEFAULT_HEADLINE_BAND = 0.04
 #: Absolute per-arm floors for ``kind: "spec_decode"`` records
 #: (benchmarks/serving.py spec segment, ISSUE 16): the repeat-heavy arm
 #: must keep the n-gram drafter paying off, and the adversarial
@@ -94,7 +89,7 @@ COLLECTIVE_RE = re.compile(
 
 #: Ordered, first-match-wins budget categories over the SHORT op name
 #: (lower-cased). gather/scatter precedes copy/transpose so dynamic-slice
-#: lands with the indexing traffic, matching benchmarks/xprof.py.
+#: lands with the indexing traffic.
 BUDGET_CATEGORIES: Tuple[Tuple[str, Any], ...] = (
     ("collective", COLLECTIVE_RE),
     ("gather/scatter", re.compile(r"gather|scatter|dynamic-slice|"
@@ -128,8 +123,6 @@ def categorize_budget(name: str) -> str:
 
 
 # ------------------------------------------------------- interval algebra
-# Shared with benchmarks/xprof.py (which imports these lazily so the
-# benchmarks stay importable before the jax backend is up).
 
 def merge_intervals(intervals: List) -> List:
     """Sorted union of (start, end) intervals."""
@@ -371,24 +364,7 @@ def print_budget(rec: Dict[str, Any]) -> None:
     print(json.dumps(rec))
 
 
-# ------------------------------------------------------------- FLOPs/MFU
-
-def step_flops(compiled, steps: int = 1) -> Optional[float]:
-    """Model FLOPs per step from a compiled executable's XLA cost
-    analysis — THE shared definition (mfu_probe, bench MFU lines, and the
-    live ``hvd_step_mfu_proxy`` gauge all route through here). ``steps``
-    divides out a ``scan_steps=k`` folded dispatch. None when the backend
-    exposes no cost analysis."""
-    try:
-        ca = compiled.cost_analysis()
-        ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-        flops = float(ca.get("flops", float("nan")))
-    except Exception:
-        return None
-    if not math.isfinite(flops) or flops <= 0:
-        return None
-    return flops / max(int(steps), 1)
-
+# ------------------------------------------------------------ device peak
 
 _PEAK_TABLE = (
     ("v6", 918e12), ("trillium", 918e12),
@@ -398,23 +374,17 @@ _PEAK_TABLE = (
     ("v3", 123e12),
     ("v2", 45e12),
 )
-_peak_cache: Dict[str, float] = {}
 
 
 def device_peak_flops(device=None) -> float:
     """Per-chip bf16 peak FLOP/s by device kind (public TPU spec sheet);
-    NaN when unknown (CPU, unrecognized kinds) — callers omit MFU then.
-    The default-device lookup is cached so the watchdog's per-step gauge
-    path never re-touches the backend."""
+    NaN when unknown (CPU, unrecognized kinds) — callers omit MFU then."""
     if device is None:
-        if "default" not in _peak_cache:
-            try:
-                import jax
-                kind = getattr(jax.devices()[0], "device_kind", "")
-            except Exception:
-                kind = ""
-            _peak_cache["default"] = _peak_for_kind(kind)
-        return _peak_cache["default"]
+        try:
+            import jax
+            device = jax.devices()[0]
+        except Exception:
+            return float("nan")
     return _peak_for_kind(getattr(device, "device_kind", ""))
 
 
@@ -424,48 +394,6 @@ def _peak_for_kind(kind: str) -> float:
         if key in kind:
             return val
     return float("nan")
-
-
-def mfu_proxy(flops_per_step: float, wall_s: float,
-              peak: Optional[float] = None) -> Optional[float]:
-    """``flops/step ÷ wall ÷ peak``, or None when the device kind has no
-    peak in ``_PEAK_TABLE`` (CPU meshes, unrecognized chips): a
-    utilization against an invented peak is not a utilization, so the
-    caller publishes no gauge."""
-    if peak is None:
-        peak = device_peak_flops()
-    if not math.isfinite(peak) or peak <= 0:
-        return None
-    return flops_per_step / max(wall_s, 1e-12) / peak
-
-
-# Registered FLOPs-per-step by step signature ("what"), read by the
-# watchdog's step-done path to derive hvd_step_mfu_proxy from host-side
-# wall time — never a device fetch.
-_flops_lock = threading.Lock()
-_registered_flops: Dict[str, float] = {}
-
-
-def register_step_flops(flops: Optional[float],
-                        what: str = "train_step") -> None:
-    """Publish a step signature's FLOPs/step for the live MFU-proxy gauge
-    (benches call this with :func:`step_flops`; train.py's opt-in
-    ``HOROVOD_STEP_COST_ANALYSIS`` hook does it automatically)."""
-    if flops is None or not math.isfinite(flops) or flops <= 0:
-        return
-    with _flops_lock:
-        _registered_flops[what] = float(flops)
-
-
-def registered_step_flops(what: str = "train_step") -> Optional[float]:
-    with _flops_lock:
-        return _registered_flops.get(what)
-
-
-def reset_registered_flops() -> None:
-    """Test hook: drop all registered step FLOPs."""
-    with _flops_lock:
-        _registered_flops.clear()
 
 
 # --------------------------------------------------------------- history
@@ -483,8 +411,8 @@ def history_path(path: Optional[str] = None) -> str:
 
 def append_history(record: Dict[str, Any],
                    path: Optional[str] = None) -> Optional[str]:
-    """Append one record (stamped with UTC date + git sha, like
-    ``scaling_history.jsonl``) to the perf history; returns the path, or
+    """Append one record (stamped with UTC date + git sha) to the perf
+    history; returns the path, or
     None when ``HOROVOD_PERF_NO_HISTORY`` suppressed the append (CI)."""
     if os.environ.get(NO_HISTORY_ENV, "").lower() in ("1", "true"):
         return None
@@ -537,8 +465,8 @@ def ratchet_check(history: List[Dict[str, Any]],
     the floor up; a drop below the band fails. A drop below best but
     inside the band is reported as a warning line (noise allowance).
 
-    ``kind: "perf_ratio"`` records (``benchmarks/remat_sweep.py`` and any
-    slope_time_paired A/B arm) are railed separately, per
+    ``kind: "perf_ratio"`` records (an interleaved A/B arm's step-time
+    ratio) are railed separately, per
     ``(model, arm)`` key: the latest interleaved step-time ratio must be
     no lower than ``band`` × the best ratio ever recorded for that arm —
     a measured compute-tier win (remat policy, scan mode, accumulation)
@@ -556,14 +484,6 @@ def ratchet_check(history: List[Dict[str, Any]],
     model, a known arm, numeric ratio, ``spec_k ≥ 2``, a ≥3-round
     noise band, positive plain/spec tokens_per_s and ZERO steady-state
     compiles. Also excluded from the MFU grouping.
-
-    ``kind: "headline_vs_baseline"`` records rail the bench.py headline
-    hvd-vs-plain ratio against its CROSS-SESSION noise band (the record's
-    own ``band`` field, else :data:`DEFAULT_HEADLINE_BAND`) rather than
-    against a best-ever floor — the ratio's ideal is 1.0, not monotone
-    growth, so ratcheting it would reward noise. The latest reading fails
-    below ``1 − 2×band`` (a real overhead regression) and warns below
-    ``1 − band``. Also excluded from the MFU grouping.
     Returns ``(ok, messages)``.
     """
     if band is None:
@@ -576,18 +496,8 @@ def ratchet_check(history: List[Dict[str, Any]],
                  List[Dict[str, Any]]] = collections.defaultdict(list)
     by_spec: Dict[Tuple[str, str],
                   List[Dict[str, Any]]] = collections.defaultdict(list)
-    headline: List[Dict[str, Any]] = []
     for rec in history:
         model = rec.get("model")
-        if rec.get("kind") == "headline_vs_baseline":
-            value = rec.get("value")
-            if not isinstance(value, (int, float)):
-                ok = False
-                msgs.append("FAIL shape [headline_vs_baseline]: record "
-                            f"needs a numeric value, got {rec}")
-                continue
-            headline.append(rec)
-            continue
         if rec.get("kind") == "perf_ratio":
             ratio = rec.get("ratio")
             if not model or not rec.get("arm") \
@@ -698,27 +608,6 @@ def ratchet_check(history: List[Dict[str, Any]],
         else:
             msgs.append(f"ok [spec_decode {model}/{arm}]: {latest:.4f} ≥ "
                         f"floor {floor_abs} (best {best:.4f})")
-    if headline:
-        rec = headline[-1]
-        value = rec["value"]
-        band_rec = rec.get("band")
-        if not isinstance(band_rec, (int, float)) or band_rec <= 0:
-            band_rec = DEFAULT_HEADLINE_BAND
-        label = rec.get("metric") or "headline"
-        if value < 1.0 - 2 * band_rec:
-            ok = False
-            msgs.append(f"FAIL headline [{label}]: vs_baseline "
-                        f"{value:.4f} < {1.0 - 2 * band_rec:.4f} "
-                        f"(1 − 2×band, band ±{band_rec:.2f} — a real "
-                        "overhead regression, not session noise)")
-        elif value < 1.0 - band_rec:
-            msgs.append(f"warn headline [{label}]: vs_baseline "
-                        f"{value:.4f} inside the noise tail "
-                        f"(1 − 2×band ≤ value < 1 − band, "
-                        f"band ±{band_rec:.2f}) — watch the next reading")
-        else:
-            msgs.append(f"ok headline [{label}]: vs_baseline "
-                        f"{value:.4f} within ±{band_rec:.2f} of parity")
     return ok, msgs
 
 

@@ -12,10 +12,9 @@ See docs/train_step.md for the feature lattice: which combinations
 produce which jitted programs, and why donation survives each.
 """
 
-from .step_builder import (STEP_COST_ANALYSIS_ENV, PipelineTrainState,
-                           accumulate_gradients, build_program_set,
-                           create_pipeline_train_state, export_decode_params,
-                           fold_scan, make_dispatch,
+from .step_builder import (PipelineTrainState, accumulate_gradients,
+                           build_program_set, create_pipeline_train_state,
+                           export_decode_params, fold_scan, make_dispatch,
                            make_pipeline_train_step)
 from .dp import TrainState, create_train_state, make_train_step
 from .gspmd import (GSPMDTrainState, create_gspmd_train_state,
@@ -23,7 +22,6 @@ from .gspmd import (GSPMDTrainState, create_gspmd_train_state,
                     make_gspmd_train_step, next_token_loss, rules_for_mesh)
 
 __all__ = [
-    "STEP_COST_ANALYSIS_ENV",
     "PipelineTrainState",
     "accumulate_gradients",
     "build_program_set",

@@ -93,8 +93,8 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
     (a ``horovod_tpu.optimizer.distributed`` transform).
 
     ``scan_steps=k`` wraps k consecutive steps in a device-side ``lax.scan``
-    over the same batch (one dispatch, one sync) — used by benchmarks to
-    measure pure device throughput without host dispatch in the loop.
+    over the same batch (one dispatch, one sync): device throughput
+    without host dispatch in the loop.
     Composes with ``sentinel``: the per-step health vectors stack to
     ``[k, n, 3]`` and the host ladder adjudicates every row.
 
@@ -260,8 +260,7 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
     dispatch = make_dispatch(programs, sentinel=sentinel,
                              scan_steps=scan_steps)
 
-    marked = instrument_dispatch(dispatch, what="train_step",
-                                 lower=jitted.lower, steps=scan_steps or 1)
+    marked = instrument_dispatch(dispatch, what="train_step")
     marked.lower = jitted.lower  # keep AOT introspection available
     if sentinel is not None:
         marked.lower_probe = programs["probe"].lower

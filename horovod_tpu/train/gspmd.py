@@ -224,22 +224,21 @@ def _build_gspmd_step(model, mesh, rules, *, optimizer=None, pair=None,
     def _mesh_lower(prog):
         def lower(state, tokens):
             # AOT introspection must trace under the SAME mesh the step
-            # executes with (tests/test_bench_parity.py compares the
+            # executes with (tests/test_contracts.py compares the
             # post-SPMD-partitioning collective HLO of two such lowerings).
             with jax.sharding.set_mesh(mesh):
                 return prog.lower(state, tokens)
         return lower
 
     lower = _mesh_lower(programs["apply"])
-    run = instrument_dispatch(in_mesh, what="gspmd_train_step", lower=lower,
-                              steps=scan_steps or 1)
+    run = instrument_dispatch(in_mesh, what="gspmd_train_step")
     run.lower = lower
     if sentinel is not None:
         run.lower_probe = _mesh_lower(programs["probe"])
         run.sentinel = sentinel
     if pair is not None:
         # Per-program AOT handles (the dispatcher itself has no single
-        # lowering): tests/test_bench_parity.py pins that at every=1 the
+        # lowering): tests/test_contracts.py pins that at every=1 the
         # apply program's collective HLO is byte-identical to the
         # standard step's.
         run.lower_apply = lower
@@ -288,8 +287,8 @@ def make_gspmd_deferred_train_step(model, pair, mesh, rules, **kw):
     their buffers (zero optimizer HBM for the bank) AND dead-code-
     eliminates the bank's dL/dW einsums (their only consumer was the
     skipped update) — which a ``lax.cond`` inside ONE program cannot
-    achieve (its pass-through copies measured the saving away —
-    docs/benchmarks.md r5). Both optimizers share a state structure;
+    achieve (its pass-through copies measured the saving away; CLAUDE.md,
+    the ``lax.cond`` trap). Both optimizers share a state structure;
     init with ``pair.apply``. Requires ``donate=True`` (the default)
     for the aliasing to exist.
 

@@ -13,8 +13,8 @@ express (see docs/train_step.md for the full feature lattice):
   params/opt_state alias straight through (zero optimizer HBM) AND lets
   XLA dead-code-eliminate the dW work whose only consumer was the skipped
   update. A ``lax.cond`` inside ONE program cannot do either — its
-  pass-through copies measured the entire saving away (docs/benchmarks.md
-  r5, +22% on Mixtral from the two-program form).
+  pass-through copies measured the entire saving away (CLAUDE.md, the
+  ``lax.cond`` trap: +22% on Mixtral from the two-program form).
 - **host dispatch** (:func:`make_dispatch`): the single host-side
   dispatcher over that program set — sentinel containment picks the probe,
   cadence deferral picks the skip program off-phase, everything else runs
@@ -35,7 +35,6 @@ express (see docs/train_step.md for the full feature lattice):
 
 from __future__ import annotations
 
-import os
 import time
 from typing import Any, Callable, Dict, NamedTuple, Optional
 
@@ -52,36 +51,6 @@ from ..core import context_api as _ctx
 from ..core import sentinel as _sentinel
 from ..core import telemetry as _telemetry
 from ..core.watchdog import monitored_step
-
-#: Opt-in: AOT-compile the step once on first call to read XLA
-#: cost-analysis FLOPs and feed the live ``hvd_step_mfu_proxy`` gauge.
-#: Off by default — the extra compile costs minutes on big models;
-#: benches register FLOPs explicitly via ``tools.perf``.
-STEP_COST_ANALYSIS_ENV = "HOROVOD_STEP_COST_ANALYSIS"
-
-
-def _maybe_register_step_flops(lower, what, steps, args, kwargs):
-    """First-call hook behind ``HOROVOD_STEP_COST_ANALYSIS``: compile the
-    step's AOT lowering, read cost-analysis FLOPs via the shared
-    ``tools.perf`` accounting, and register them so the watchdog's
-    ``_note_step_done`` can export the MFU proxy every step. Best-effort:
-    any failure (no cost analysis on this backend, donation/lowering
-    mismatch) is logged and skipped, never raised into the step."""
-    if os.environ.get(STEP_COST_ANALYSIS_ENV, "").lower() \
-            not in ("1", "true"):
-        return
-    from ..core.logging import get_logger
-    from ..tools import perf
-    try:
-        compiled = lower(*args, **kwargs).compile()
-        flops = perf.step_flops(compiled, steps=steps)
-    except Exception as e:  # noqa: BLE001 — observability must not kill
-        get_logger().debug("step cost analysis unavailable: %s", e)
-        return
-    if flops:
-        perf.register_step_flops(flops, what=what)
-        get_logger().info("registered %s cost-analysis FLOPs/step: %.3e",
-                          what, flops)
 
 
 # ------------------------------------------------------------ program set
@@ -199,8 +168,7 @@ def make_dispatch(programs: Dict[str, Any], *, sentinel=None,
     return dispatch
 
 
-def instrument_dispatch(dispatch: Callable, *, what: str, lower: Callable,
-                        steps: int = 1) -> Callable:
+def instrument_dispatch(dispatch: Callable, *, what: str) -> Callable:
     """The host-side boundary of every step kind (DP, pipeline, GSPMD):
     one ``step.dispatch`` span round the call of ``dispatch`` with the
     call's ordinal as ``seq``, ``hvd_dispatches_total``, and the
@@ -211,8 +179,7 @@ def instrument_dispatch(dispatch: Callable, *, what: str, lower: Callable,
     any order relative to building the step, and a closed timeline is
     never written to. Nothing here reads the device: the dispatch is async
     and the loss is still a future — step timing belongs to the watchdog
-    span, which sees values the host already fetched. The first call also
-    runs the opt-in cost-analysis hook (``lower`` is the apply program's)."""
+    span, which sees values the host already fetched."""
     calls = [0]
     tensor = what.upper()
     counted = _telemetry.series_id("hvd_dispatches_total", what=what)
@@ -220,8 +187,6 @@ def instrument_dispatch(dispatch: Callable, *, what: str, lower: Callable,
     def run(*args, **kwargs):
         seq = calls[0]
         calls[0] = seq + 1
-        if seq == 0:
-            _maybe_register_step_flops(lower, what, steps, args, kwargs)
         _telemetry.inc_series("hvd_dispatches_total", counted)
         tl = _ctx.context().timeline if _ctx.is_initialized() else None
         if tl is not None and getattr(tl, "_closed", False):
@@ -250,8 +215,8 @@ def instrument_dispatch(dispatch: Callable, *, what: str, lower: Callable,
 
 def fold_scan(inner: Callable, scan_steps: int, with_health: bool):
     """Fold k consecutive steps into one dispatch via ``lax.scan`` over
-    the same batch (one dispatch, one sync — benchmarks use this to
-    measure pure device throughput without host dispatch in the loop).
+    the same batch (one dispatch, one sync: device throughput without
+    host dispatch in the loop).
 
     With a sentinel engaged the per-step health vectors stack to
     ``[k, n, 3]`` so the host ladder still adjudicates every inner step;
@@ -419,8 +384,7 @@ def make_pipeline_train_step(stage_fn: Callable, loss_fn: Callable,
                                  pair=pair)
     dispatch = make_dispatch(programs,
                              every=pair.every if pair is not None else 1)
-    run = instrument_dispatch(dispatch, what="pipeline_train_step",
-                              lower=programs["apply"].lower)
+    run = instrument_dispatch(dispatch, what="pipeline_train_step")
     run.lower = programs["apply"].lower
     if pair is not None:
         run.lower_apply = programs["apply"].lower

@@ -274,7 +274,7 @@ def test_self_lint_sweeps_benchmarks_and_examples():
     sweep alongside the package, tests, and entry points."""
     from horovod_tpu.analysis.__main__ import REPO_SELF_LINT_TARGETS
     for target in ("horovod_tpu", "tests", "benchmarks", "examples",
-                   "bench.py", "__graft_entry__.py"):
+                   "__graft_entry__.py"):
         assert target in REPO_SELF_LINT_TARGETS, target
 
 

@@ -4,7 +4,7 @@ Three layers:
 
 1. the FULL ``--contracts --strict`` matrix runs clean on the repo
    as-is (builds are memoized module-wide, so the thin per-family
-   drivers in test_wire_contracts/test_fusion/test_bench_parity/
+   drivers here and in test_wire_contracts/test_fusion/
    test_step_builder reuse these builds instead of re-compiling);
 2. detection is proven by breaking one contract each way IN-PROCESS —
    drop a donation, emit a stray permute, unpin the DLRM entry layout,
@@ -18,16 +18,17 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 from horovod_tpu.analysis import analyze_rank_divergence, contracts
 from horovod_tpu.analysis.__main__ import main as analysis_main
 from horovod_tpu.analysis.hlo import HloCollective, LayoutMove
 
 ALL_FAMILIES = (
-    "dp-step-fusion", "dp-step-accum", "bench-arms-parity",
-    "gspmd-deferred-every1", "gspmd-deferred-programs",
-    "adasum-butterfly", "ring-attention", "pipeline-handoff",
-    "hierarchical-allreduce", "decode-tp", "verify-tp", "prefill-tp",
-    "decode-tp8", "verify-tp8", "dlrm-layout-pin",
+    "dp-step-fusion", "dp-step-accum", "gspmd-deferred-every1",
+    "gspmd-deferred-programs", "adasum-butterfly", "ring-attention",
+    "pipeline-handoff", "hierarchical-allreduce", "decode-tp", "verify-tp",
+    "prefill-tp", "decode-tp8", "verify-tp8", "dlrm-layout-pin",
 )
 
 
@@ -44,6 +45,16 @@ def test_full_matrix_strict_clean(capsys):
     out = capsys.readouterr().out
     assert rc == 0, out
     assert "hvd-analyze: clean" in out
+
+
+@pytest.mark.parametrize("family", ["gspmd-deferred-every1"])
+def test_family_contract_holds(family):
+    """The families no other test file drives thin. At ``every=1`` the
+    deferred factory emits collective HLO signature-identical to the
+    standard GSPMD step it wraps (compared post-SPMD-partitioning: GSPMD
+    inserts its collectives there, stablehlo alone would compare nothing)."""
+    findings = contracts.check_family(family)
+    assert not findings, "\n".join(f.format() for f in findings)
 
 
 # --------------------------------------------------- injected breaks

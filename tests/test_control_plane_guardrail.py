@@ -1,7 +1,6 @@
 """Pod-scale control-plane guardrails over benchmarks/control_plane.py.
 
-Same contract as tests/test_scaling_guardrail.py for the dp8 series: the
-COMMITTED history record (benchmarks/control_plane_history.jsonl) must
+The COMMITTED history record (benchmarks/control_plane_history.jsonl) must
 stay inside the rails — ≥5× fewer response bytes per membership change
 at ≥256 workers, sub-linear steady-state request growth, and a journal
 compaction rebuild that matches the uncompacted replay — so a regression

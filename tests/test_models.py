@@ -143,7 +143,7 @@ def test_scan_layers_auto_resolution():
 def test_llama_remat_policies_match_full():
     """The named-save policies (r4: "attn"/"dots_attn" keep the flash
     kernel's (o, m, l) residuals so the backward skips the fwd-kernel
-    re-run — benchmarks/llama_remat_ab.py measures the win) must be
+    re-run) must be
     numerically identical to "full" remat: same loss trajectory on the
     same init, flash forced on (interpret-mode Pallas on CPU)."""
     import dataclasses

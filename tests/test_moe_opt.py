@@ -1,7 +1,7 @@
 """MoE expert-bank optimizer levers (optimizer/moe_opt.py, VERDICT r4 #2):
 reduced-precision moments, factored/partitioned treatment, deferred
-expert updates. Numerics here; the HBM A/B evidence lives in
-benchmarks/mixtral_opt_ab.py + docs/benchmarks.md."""
+expert updates. Numerics only: no cell of the benchmark measures their
+memory traffic (ROADMAP D5)."""
 
 import jax
 import jax.numpy as jnp

@@ -38,10 +38,8 @@ def _fresh_perf(monkeypatch):
     monkeypatch.delenv(perf.HISTORY_ENV, raising=False)
     monkeypatch.delenv(perf.NO_HISTORY_ENV, raising=False)
     monkeypatch.delenv(perf.RATCHET_BAND_ENV, raising=False)
-    perf.reset_registered_flops()
     T.reset()
     yield
-    perf.reset_registered_flops()
     T.reset()
 
 
@@ -259,7 +257,7 @@ def _ratio_rec(model, arm, ratio):
 
 
 def test_perf_ratio_records_rail_per_arm():
-    # a measured A/B win (remat_sweep.py arm) becomes a per-(model, arm)
+    # a measured A/B win becomes a per-(model, arm)
     # floor: later records inside the band pass, a collapse fails
     hist = [_ratio_rec("llama_tiny", "remat_none_vs_full", 1.30),
             _ratio_rec("llama_tiny", "remat_none_vs_full", 1.28)]
@@ -291,47 +289,6 @@ def test_perf_ratio_records_excluded_from_mfu_grouping():
     ok, msgs = perf.ratchet_check(
         [{"kind": "perf_ratio", "model": "m", "ratio": "fast"}])
     assert not ok and any("FAIL shape [perf_ratio]" in m for m in msgs)
-
-
-def _headline_rec(value, band=None, **extra):
-    rec = {"kind": "headline_vs_baseline",
-           "metric": "resnet50_images_per_sec_per_chip", "value": value}
-    if band is not None:
-        rec["band"] = band
-    rec.update(extra)
-    return rec
-
-
-def test_headline_vs_baseline_rails_against_parity_not_best():
-    # railed against parity (ideal 1.0), NOT best-ever: a 0.9631 after a
-    # 0.9999 passes — inside the band, not a regression
-    ok, msgs = perf.ratchet_check([_headline_rec(0.9999),
-                                   _headline_rec(0.9631)])
-    assert ok
-    assert any("ok headline" in m for m in msgs)
-    # the noise tail warns: 1 − 2×band ≤ value < 1 − band
-    ok, msgs = perf.ratchet_check([_headline_rec(0.95)])
-    assert ok and any("warn headline" in m for m in msgs)
-    # below 1 − 2×band is a real overhead regression
-    ok, msgs = perf.ratchet_check([_headline_rec(0.91)])
-    assert not ok and any("FAIL headline" in m for m in msgs)
-
-
-def test_headline_vs_baseline_band_and_shape():
-    # the record's own band overrides the default
-    ok, msgs = perf.ratchet_check([_headline_rec(0.91, band=0.10)])
-    assert ok and any("ok headline" in m for m in msgs)
-    # only the LATEST reading is judged, and headline records never join
-    # the MFU grouping (they carry a model-free ratio, not a budget)
-    ok, msgs = perf.ratchet_check(
-        [_headline_rec(0.50), _headline_rec(0.99), _rec("m", mfu=0.5)])
-    assert ok
-    assert any("ok [m]: MFU" in m for m in msgs)
-    # a non-numeric value FAILs shape
-    ok, msgs = perf.ratchet_check(
-        [{"kind": "headline_vs_baseline", "value": "fast"}])
-    assert not ok and any("FAIL shape [headline_vs_baseline]" in m
-                          for m in msgs)
 
 
 def _spec_rec(arm, ratio, **over):
@@ -499,22 +456,7 @@ def test_cli_subprocess_entry_point(tmp_path):
     assert "FAIL ratchet" in out.stdout
 
 
-# --- FLOPs registry + MFU proxy ----------------------------------------------
-
-def test_mfu_proxy_math_and_unknown_peak():
-    assert perf.mfu_proxy(1e12, 1.0, peak=2e12) == pytest.approx(0.5)
-    # unknown peak: no number at all — never an invented one
-    assert perf.mfu_proxy(1e12, 1.0, peak=float("nan")) is None
-    assert perf.mfu_proxy(1e12, 1.0) is None   # the CPU mesh has no peak
-
-
-def test_register_step_flops_rejects_garbage():
-    for bad in (None, float("nan"), float("inf"), 0.0, -5.0):
-        perf.register_step_flops(bad, what="perf_garbage")
-    assert perf.registered_step_flops("perf_garbage") is None
-    perf.register_step_flops(3e9, what="perf_garbage")
-    assert perf.registered_step_flops("perf_garbage") == 3e9
-
+# --- device peak table ------------------------------------------------------
 
 def test_device_peak_flops_table():
     class _Dev:
@@ -552,29 +494,6 @@ def test_step_span_sets_wall_gauge_and_leaves_data_wait_to_the_prefetcher():
     assert wait is not None and 0.0 <= wait < 0.02
 
 
-def test_monitored_call_publishes_mfu_proxy_gauge(monkeypatch):
-    # a known chip: the CPU mesh itself has no peak, hence no gauge
-    monkeypatch.setitem(perf._peak_cache, "default", 197e12)
-    mon = watchdog.monitor()
-    perf.register_step_flops(2e9, what="perf_mfu")
-    assert mon.monitored_call(lambda: 7, what="perf_mfu") == 7
-    reg = T.active().registry
-    assert reg.gauge_value("hvd_step_wall_seconds",
-                           what="perf_mfu") is not None
-    proxy = reg.gauge_value("hvd_step_mfu_proxy", what="perf_mfu")
-    assert proxy is not None and proxy > 0.0
-    # no registered FLOPs for this signature -> no proxy gauge, no error
-    assert mon.monitored_call(lambda: 8, what="perf_noflops") == 8
-    assert reg.gauge_value("hvd_step_mfu_proxy",
-                           what="perf_noflops") is None
-    # unknown device kind -> no proxy gauge either
-    monkeypatch.setitem(perf._peak_cache, "default", float("nan"))
-    perf.register_step_flops(2e9, what="perf_nopeak")
-    assert mon.monitored_call(lambda: 9, what="perf_nopeak") == 9
-    assert reg.gauge_value("hvd_step_mfu_proxy",
-                           what="perf_nopeak") is None
-
-
 # --- coordinator /metrics fleet rollup ---------------------------------------
 
 def test_metrics_endpoint_serves_step_gauges_with_mean_rollup():
@@ -587,11 +506,11 @@ def test_metrics_endpoint_serves_step_gauges_with_mean_rollup():
         client = CoordinatorClient(f"127.0.0.1:{svc.port}", key)
         assert client.push_metrics(0, {"c": {}, "g": {
             'hvd_step_wall_seconds{what="t"}': 0.1,
-            'hvd_step_mfu_proxy{what="t"}': 0.4,
+            'hvd_last_step': 4.0,
             'hvd_step_data_wait_seconds{what="t"}': 0.01}})
         assert client.push_metrics(1, {"c": {}, "g": {
             'hvd_step_wall_seconds{what="t"}': 0.3,
-            'hvd_step_mfu_proxy{what="t"}': 0.6,
+            'hvd_last_step': 6.0,
             'hvd_step_data_wait_seconds{what="t"}': 0.03}})
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{svc.port}/metrics", timeout=10) as resp:
@@ -602,9 +521,9 @@ def test_metrics_endpoint_serves_step_gauges_with_mean_rollup():
     assert s['hvd_step_wall_seconds{rank="0",what="t"}'] == 0.1
     assert s['hvd_step_wall_seconds{rank="1",what="t"}'] == 0.3
     assert s['hvd_step_wall_seconds{what="t"}'] == pytest.approx(0.2)
-    assert s['hvd_step_mfu_proxy{what="t"}'] == pytest.approx(0.5)
+    assert s['hvd_last_step'] == pytest.approx(5.0)
     assert s['hvd_step_data_wait_seconds{what="t"}'] == pytest.approx(0.02)
-    assert parse_prometheus(text)["types"]["hvd_step_mfu_proxy"] == "gauge"
+    assert parse_prometheus(text)["types"]["hvd_last_step"] == "gauge"
 
 
 def test_render_rollup_averages_gauges_sums_counters():
@@ -623,8 +542,8 @@ def test_render_rollup_averages_gauges_sums_counters():
 
 @pytest.mark.slow
 def test_perf_gauges_overhead_within_bound():
-    """Full perf instrumentation (wall + MFU-proxy gauges, FLOPs registered,
-    and the step's life as spans: every batch comes through ``Prefetcher``,
+    """Full perf instrumentation (the wall gauges and the step's life as
+    spans: every batch comes through ``Prefetcher``,
     so a step carries ``input.produce``/``input.transfer`` on the worker and
     ``input.wait`` + ``step.dispatch``, the data-wait gauge and two counters
     on the loop's thread) vs telemetry-off A/B on the CPU mesh: median of
@@ -683,10 +602,8 @@ def test_perf_gauges_overhead_within_bound():
         def fn(k):
             if instrumented:
                 T.configure(enabled=True)
-                perf.register_step_flops(1e9, what="bench_step")
             else:
                 T.configure(enabled=False)
-                perf.reset_registered_flops()
             for _ in range(k):
                 x, y = next(feed)
                 with mon.step_span("bench_step"):

@@ -300,8 +300,7 @@ def test_dispatch_with_telemetry_off_reads_no_clock(monkeypatch):
     def no_clock():
         raise AssertionError("a disabled dispatch boundary read the clock")
     monkeypatch.setattr(step_builder.time, "perf_counter_ns", no_clock)
-    run = step_builder.instrument_dispatch(lambda *a: a, what="unit_step",
-                                           lower=None)
+    run = step_builder.instrument_dispatch(lambda *a: a, what="unit_step")
     assert run(1, 2) == (1, 2)
     assert T.spans() == [] and T.active().registry.export()["c"] == {}
 
