@@ -33,18 +33,32 @@ path, and a materialised-softmax jnp recompute vjp kept for
 from ring-attention partial merging that the kernels do not model.
 
 The kernel backward adapts to the shape it is traced with
-(``_fa_bwd_pallas``, no option selects it). With several tiles it is the
-FlashAttention-2 split, a dQ pass (``hvd_flash_bwd_dq``) that accumulates
-over key tiles and a dK/dV pass (``hvd_flash_bwd_dkv``) that accumulates
-over query tiles, each recomputing the probability tile. When the padded
-sequence is ONE tile in both directions (``Tq <= block_q`` and
-``Tk <= block_k``: up to 512 tokens at the default blocks) there is nothing
-to accumulate across, so one fused kernel (``hvd_flash_bwd``) on grid
-``(batch*heads,)`` recomputes the tile once and writes dQ, dK and dV from
-it: 5 matmuls and one round of the T² elementwise work where the two passes
-spend 7 and two. The trace-time counter
-``hvd_flash_bwd_kernels_total{kind="fused"|"two_pass"}`` says which a
-program got.
+(``_fa_bwd_pallas``, no option selects it) and has three forms. Each
+recomputes the probability tile ``p`` and the score cotangent ``ds`` from
+the saved statistics; they differ in how often.
+
+- ONE tile in both directions (``Tq <= block_q`` and ``Tk <= block_k``: up
+  to 512 tokens at the default blocks): nothing accumulates across tiles,
+  so one fused kernel (``hvd_flash_bwd``) on grid ``(batch*heads,)``
+  recomputes the tile once and writes dQ, dK and dV from it, with no
+  scratch and no ``pl.when``.
+- Several tiles, and one (batch*head)'s dQ row fits VMEM beside the tiles
+  (``_tiled_bwd_vmem_bytes <= _VMEM_BYTES``: 16.5 MiB of 64 at 4,096 tokens
+  and 192/128 depth, up to 57,344 tokens at 128-deep heads): one fused
+  tiled kernel (``hvd_flash_bwd_tiled``) on grid ``(batch*heads, nk, nq)``,
+  query tiles innermost. A visible tile computes ``p`` and ``ds`` ONCE and
+  issues three dots from them: dV and dK accumulate over the inner axis,
+  dQ over the outer one into a float32 scratch that holds the whole
+  ``[Tq, D]`` row, so every sum keeps the order the two passes give it.
+- Rows too long for that: the FlashAttention-2 split, a dQ pass
+  (``hvd_flash_bwd_dq``) that accumulates over key tiles and a dK/dV pass
+  (``hvd_flash_bwd_dkv``) that accumulates over query tiles, each
+  recomputing the tile: 7 matmuls and two rounds of the T² elementwise
+  work where the fused forms spend 5 and one.
+
+The trace-time counter
+``hvd_flash_bwd_kernels_total{kind="fused"|"fused_tiled"|"two_pass"}`` says
+which a program got.
 
 On non-TPU backends the kernel runs in Pallas interpreter mode, which is how
 the CPU test mesh exercises it (the reference's CPU+Gloo fake-backend trick,
@@ -72,8 +86,16 @@ _LANE = 128
 # (XLA names the custom call after ``pallas_call``'s ``name``): per-kernel
 # readers find them by these, whatever Python function encloses the call.
 KERNEL_FWD = "hvd_flash_fwd"
-KERNEL_BWD = ("hvd_flash_bwd_dq", "hvd_flash_bwd_dkv", "hvd_flash_bwd")
-_BWD_DQ, _BWD_DKV, _BWD_FUSED = KERNEL_BWD
+KERNEL_BWD = ("hvd_flash_bwd_dq", "hvd_flash_bwd_dkv", "hvd_flash_bwd",
+              "hvd_flash_bwd_tiled")
+_BWD_DQ, _BWD_DKV, _BWD_FUSED, _BWD_TILED = KERNEL_BWD
+
+# VMEM one kernel may hold: half of a TPU core's 128 MiB (v5e, v6e), the
+# other half left to what XLA keeps in flight round the call. The several-tile
+# fused backward runs while ``_tiled_bwd_vmem_bytes`` stays within it. A call
+# that needs more than Mosaic's default scoped limit asks for its sum.
+_VMEM_BYTES = 64 * 1024 * 1024
+_VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024
 
 
 def _use_interpret() -> bool:
@@ -451,6 +473,145 @@ def _fa_bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, d_ref,
         preferred_element_type=jnp.float32) * scale).astype(dk_ref.dtype)
 
 
+def _fa_bwd_tiled_kernel(q_ref, k_ref, v_ref, do_ref, m_ref, l_ref, d_ref,
+                         *refs, scale, causal, bq, bk, nq, nk, has_bias):
+    """Whole backward of a sequence of several tiles: grid (BH, k-block,
+    q-block), q innermost as in the dK/dV pass, and ``p`` and ``ds`` computed
+    ONCE a visible tile. dK and dV accumulate over the inner axis as there;
+    dQ accumulates over the OUTER axis into ``dq_acc``, a float32 scratch
+    that holds this (batch*head)'s whole [Tq, D] row, so each query tile
+    meets its key tiles in ascending order as in the dQ pass. ``dq_ref`` is
+    that whole row too (its block index moves with the batch*head alone): it
+    is written tile by tile during the last key block and leaves VMEM once
+    a (batch*head)."""
+    if has_bias:
+        bias_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    else:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = refs
+    ikb = pl.program_id(1)
+    iqb = pl.program_id(2)
+    rows = pl.ds(pl.multiple_of(iqb * bq, bq), bq)
+
+    @pl.when(iqb == 0)
+    def _init_kv():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    @pl.when(ikb == 0)
+    def _init_q():
+        dq_acc[rows, :] = jnp.zeros((bq, dq_acc.shape[1]), dq_acc.dtype)
+
+    visible = ((iqb + 1) * bq - 1 >= ikb * bk) if causal else (iqb >= 0)
+
+    @pl.when(visible)
+    def _compute():
+        q = q_ref[0]
+        k = k_ref[0]
+        do = do_ref[0]
+        p, ds = _recompute_p_ds(
+            q, k, v_ref[0], do, m_ref[0], l_ref[0], d_ref[0],
+            bias_ref[0] if has_bias else None, scale=scale, causal=causal,
+            bq=bq, bk=bk, iq=iqb, ik=ikb)
+        dv_acc[:] = dv_acc[:] + lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = ds.astype(q.dtype)
+        dk_acc[:] = dk_acc[:] + lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        dq_acc[rows, :] = dq_acc[rows, :] + lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+
+    @pl.when(iqb == nq - 1)
+    def _emit_kv():
+        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when(ikb == nk - 1)
+    def _emit_q():
+        dq_ref[0, rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
+
+
+def _tiled_bwd_vmem_bytes(Tq, D, Dv, block_q, block_k, itemsize, has_bias):
+    """VMEM the several-tile fused backward holds, summed from its shapes:
+    every operand and result block twice (the pipeline's two buffers), the
+    three accumulators, and the [block_q, block_k] temporaries of one tile.
+    A trailing dim fills whole 128-lane tiles (192 deep takes 256; a
+    ``(block_q, 1)`` statistic 128)."""
+    lanes = lambda n: -(-n // _LANE) * _LANE
+    d, dv = lanes(D), lanes(Dv)
+    blocks = itemsize * (block_q + block_k) * (d + dv)   # q, dO; k, v
+    blocks += 3 * 4 * block_q * _LANE                    # m, l, dsum
+    if has_bias:
+        blocks += 4 * 8 * lanes(block_k)
+    blocks += itemsize * (block_k * (d + dv) + Tq * d)   # dk, dv; dq's row
+    acc = 4 * (block_k * (d + dv) + Tq * d)
+    # s -> p and dp -> ds in float32 with the operand-dtype copies of p and
+    # ds: four float32 tiles bound them (Mosaic keeps under three at 512).
+    tile = 4 * 4 * block_q * lanes(block_k)
+    return 2 * blocks + acc + tile
+
+
+def _kv_major_specs(D, Dv, block_q, block_k, has_bias):
+    """Block specs on grid (BH, k-block, q-block), q INNERMOST (the dK/dV
+    pass's and the fused tiled kernel's): the operands' in order, then dK's
+    and dV's."""
+    q_tile = lambda b, i, j: (b, j, 0)
+    k_tile = lambda b, i, j: (b, i, 0)
+    dk_spec = pl.BlockSpec((1, block_k, D), k_tile)
+    dv_spec = pl.BlockSpec((1, block_k, Dv), k_tile)
+    q_stat = pl.BlockSpec((1, block_q, 1), q_tile)
+    in_specs = [
+        pl.BlockSpec((1, block_q, D), q_tile),    # q
+        dk_spec, dv_spec,                         # k, v
+        pl.BlockSpec((1, block_q, Dv), q_tile),   # do
+        q_stat, q_stat, q_stat,                   # m, l, dsum
+    ]
+    if has_bias:
+        in_specs.append(
+            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, i)))
+    return in_specs, [dk_spec, dv_spec]
+
+
+def _fa_bwd_tiled(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
+                  block_q, block_k, vmem_bytes, interpret):
+    """The several-tile fused backward: one pallas_call on grid
+    (BH, nk, nq)."""
+    BH, Tq, D = q.shape
+    Tk, Dv = k.shape[1], v.shape[2]
+    nq, nk = Tq // block_q, Tk // block_k
+    has_bias = bias3 is not None
+    in_specs, dkv_specs = _kv_major_specs(D, Dv, block_q, block_k, has_bias)
+    operands = [q, k, v, do, m3, l3, dsum] + ([bias3] if has_bias else [])
+    params = None
+    if vmem_bytes > _VMEM_SCOPED_DEFAULT:
+        params = pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes)
+    return tuple(pl.pallas_call(
+        functools.partial(_fa_bwd_tiled_kernel, scale=scale, causal=causal,
+                          bq=block_q, bk=block_k, nq=nq, nk=nk,
+                          has_bias=has_bias),
+        grid=(BH, nk, nq),
+        in_specs=in_specs,
+        # dQ's block is the (batch*head)'s whole row
+        out_specs=[pl.BlockSpec((1, Tq, D), lambda b, i, j: (b, 0, 0)),
+                   *dkv_specs],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Tq, D), q.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, Dv), v.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((Tq, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
+        ],
+        compiler_params=params,
+        interpret=interpret,
+        name=_BWD_TILED,
+    )(*operands))
+
+
 def _fa_bwd_fused(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
                   interpret):
     """The single-tile backward: one pallas_call on grid (BH,), every
@@ -489,18 +650,29 @@ def _fa_bwd_fused(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
 def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
                    block_q, block_k, interpret):
     """Backward pallas_calls on PADDED folded shapes → (dq, dk, dv): the
-    fused kernel when the sequence is one tile in both directions, the dQ
-    and dK/dV passes otherwise."""
+    fused kernel when the sequence is one tile in both directions; with
+    several tiles the fused tiled kernel while a (batch*head)'s dQ row fits
+    ``_VMEM_BYTES`` beside the tiles, the dQ and dK/dV passes beyond."""
     BH, Tq, D = q.shape
     Tk, Dv = k.shape[1], v.shape[2]
     nq, nk = Tq // block_q, Tk // block_k
-    fused = nq == 1 and nk == 1
+    vmem_bytes = _tiled_bwd_vmem_bytes(Tq, D, Dv, block_q, block_k,
+                                       q.dtype.itemsize, bias3 is not None)
+    if nq == 1 and nk == 1:
+        kind = "fused"
+    elif vmem_bytes <= _VMEM_BYTES:
+        kind = "fused_tiled"
+    else:
+        kind = "two_pass"
     # Trace-time: fires once per traced program, never per step.
-    _telemetry.inc("hvd_flash_bwd_kernels_total",
-                   kind="fused" if fused else "two_pass")
-    if fused:
+    _telemetry.inc("hvd_flash_bwd_kernels_total", kind=kind)
+    if kind == "fused":
         return _fa_bwd_fused(q, k, v, do, m3, l3, dsum, bias3, causal=causal,
                              scale=scale, interpret=interpret)
+    if kind == "fused_tiled":
+        return _fa_bwd_tiled(q, k, v, do, m3, l3, dsum, bias3, causal=causal,
+                             scale=scale, block_q=block_q, block_k=block_k,
+                             vmem_bytes=vmem_bytes, interpret=interpret)
 
     base_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),   # q
@@ -530,30 +702,17 @@ def _fa_bwd_pallas(q, k, v, do, m3, l3, dsum, bias3, *, causal, scale,
         name=_BWD_DQ,
     )(*operands)[0]
 
-    # dK/dV pass iterates q INNERMOST: swap the grid index meaning (i = k
-    # block, j = q block) by re-deriving every spec.
-    kv_specs = [
-        pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, j, 0)),   # q
-        pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0)),   # k
-        pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, i, 0)),  # v
-        pl.BlockSpec((1, block_q, Dv), lambda b, i, j: (b, j, 0)),  # do
-        pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),   # m
-        pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),   # l
-        pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),   # dsum
-    ]
-    if bias3 is not None:
-        kv_specs.append(
-            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b, 0, i)))
+    # dK/dV pass iterates q INNERMOST: the grid indices swap meaning (i = k
+    # block, j = q block).
+    kv_specs, dkv_specs = _kv_major_specs(D, Dv, block_q, block_k,
+                                          bias3 is not None)
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
                           bq=block_q, bk=block_k, nq=nq,
                           has_bias=bias3 is not None),
         grid=(BH, nk, nq),
         in_specs=kv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, Dv), lambda b, i, j: (b, i, 0)),
-        ],
+        out_specs=dkv_specs,
         out_shape=[
             jax.ShapeDtypeStruct((BH, Tk, D), k.dtype),
             jax.ShapeDtypeStruct((BH, Tk, Dv), v.dtype),

@@ -1,0 +1,79 @@
+"""The flash backward's three forms compiled ahead of time for a TPU v5e at
+the widths the benchmark's cells run. No chip is needed: Mosaic and XLA:TPU
+are installed here and compile for a chip that is described, not attached.
+Interpret mode (every other flash test) cannot see what Mosaic refuses: a
+dynamic slice off the tiling, or more VMEM than the call may hold."""
+
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler on this machine
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """An executable compiled for an absent chip is written to the
+    persistent cache but cannot be read back: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# BH, T, D, Dv, block, causal, bias -> the kernels, and the VMEM asked for
+@pytest.mark.parametrize("BH,T,D,Dv,block,causal,bias,kernels,vmem", [
+    (128, 512, 64, 64, 512, False, True, ["hvd_flash_bwd"], None),
+    (64, 4096, 192, 128, 512, True, False, ["hvd_flash_bwd_tiled"],
+     17301504),
+    (16, 4096, 128, 128, 512, True, False, ["hvd_flash_bwd_tiled"], None),
+    (4, 32768, 128, 128, 512, True, False, ["hvd_flash_bwd_tiled"],
+     41418752),
+    (2, 65536, 128, 128, 512, True, False,
+     ["hvd_flash_bwd_dq", "hvd_flash_bwd_dkv"], None),
+    (8, 64, 64, 64, 8, True, False, ["hvd_flash_bwd_tiled"], None),
+], ids=["bert_512_one_tile", "joyai_4096_192_128", "llama_4096_128",
+        "row_32k", "row_64k_two_pass", "blocks_of_8_rows"])
+def test_flash_backward_compiles_for_v5e(one_chip, no_compile_cache, BH, T,
+                                         D, Dv, block, causal, bias, kernels,
+                                         vmem):
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stat = arg((BH, T, 1), jnp.float32)
+    args = [arg((BH, T, D)), arg((BH, T, D)), arg((BH, T, Dv)),
+            arg((BH, T, Dv)), stat, stat, stat,
+            arg((BH, 1, T), jnp.float32) if bias else None]
+
+    def backward(*a):
+        return fa._fa_bwd_pallas(*a, causal=causal, scale=D ** -0.5,
+                                 block_q=block, block_k=block,
+                                 interpret=False)
+
+    hlo = jax.jit(backward).lower(*args).compile().as_text()
+    calls = re.findall(r"%(hvd_flash_bwd\w*?)(?:\.\d+)? = ", hlo)
+    assert sorted(calls) == sorted(kernels)
+    need = fa._tiled_bwd_vmem_bytes(T, D, Dv, block, block, 2, bias)
+    if vmem is None:     # within Mosaic's default, or not the tiled kernel
+        assert kernels != ["hvd_flash_bwd_tiled"] \
+            or need <= fa._VMEM_SCOPED_DEFAULT
+    else:
+        assert need == vmem and f'"{vmem}"' in hlo

@@ -1,6 +1,7 @@
 """Flash attention with a value depth of its own (latent attention: q and k
-192 deep, v and o 128): forward and the three backward kernels (fused one
-tile, dQ pass, dK/dV pass) in interpret mode against ``_reference_partial``."""
+192 deep, v and o 128): forward and the four backward kernels (fused one
+tile, fused tiled, dQ pass, dK/dV pass) in interpret mode against
+``_reference_partial``."""
 
 import importlib
 
@@ -11,7 +12,8 @@ import pytest
 
 # ``horovod_tpu.ops`` re-exports the function under the module's name.
 fa = importlib.import_module("horovod_tpu.ops.flash_attention")
-from test_ops import _bwd_kernels  # noqa: E402
+from test_ops import (BWD_KERNELS, _bwd_kernels, bwd_form,  # noqa: E402,F401
+                      check_tiled_equals_two_pass)
 
 
 def _qkv(seed, B, T, H, D, Dv):
@@ -27,16 +29,21 @@ def _reference(q, k, v, causal):
     return fa._reference_partial(q, k, v, causal=causal, scale=scale)[0]
 
 
-# (T, block): one tile -> the fused backward; several -> the two passes.
-@pytest.mark.parametrize("T,block,kernels", [
-    (32, 32, ["hvd_flash_bwd"]),
-    (64, 16, ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"]),
-    (40, 16, ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"]),     # padded to 48
-], ids=["one_tile", "four_tiles", "padded_tiles"])
+# (T, block): one tile -> the fused backward; several -> the fused tiled
+# kernel, or the two passes where they are forced.
+@pytest.mark.parametrize("T,block,bwd_form", [
+    (32, 32, "fused"),
+    (64, 16, "fused_tiled"),
+    (64, 16, "two_pass"),
+    (40, 16, "fused_tiled"),                                 # padded to 48
+    (40, 16, "two_pass"),
+], ids=["one_tile", "four_tiles", "four_tiles_two_pass", "padded_tiles",
+        "padded_tiles_two_pass"], indirect=["bwd_form"])
 @pytest.mark.parametrize("D,Dv", [(24, 16), (16, 24)])
 @pytest.mark.parametrize("causal", [True, False])
-def test_forward_and_backward_at_another_value_depth(T, block, kernels, D,
+def test_forward_and_backward_at_another_value_depth(T, block, bwd_form, D,
                                                      Dv, causal):
+    kernels = BWD_KERNELS[bwd_form]
     q, k, v, do = _qkv(T + D, 2, T, 2, D, Dv)
 
     def flash(q, k, v):
@@ -54,7 +61,19 @@ def test_forward_and_backward_at_another_value_depth(T, block, kernels, D,
                                    err_msg="d" + name)
 
 
-def test_partitioned_under_a_gspmd_mesh_at_another_value_depth(monkeypatch):
+@pytest.mark.parametrize("T,block", [(64, 16), (40, 16)],
+                         ids=["four_tiles", "padded_to_48"])
+@pytest.mark.parametrize("masked", [False, True], ids=["", "masked"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_tiled_backward_equals_two_pass_bit_for_bit_at_192_128(causal, masked,
+                                                               T, block):
+    """The latent-attention cell's depths through the fused tiled kernel:
+    dQ and dK 192 deep, dV 128, the two passes' bits."""
+    check_tiled_equals_two_pass(T, block, 192, 128, causal, masked)
+
+
+def test_partitioned_under_a_gspmd_mesh_at_another_value_depth(monkeypatch,
+                                                               bwd_form):
     """The ``custom_partitioning`` wrappers give v, o, dO and dV their own
     depth factor: batch*head sharded, both depths replicated."""
     monkeypatch.setenv("HOROVOD_FLASH_PARTITION", "1")

@@ -4,6 +4,9 @@ Mirrors the reference's approach of testing device code end-to-end through
 the public API against a plain oracle (SURVEY.md §4: no C++ unit tests —
 behavior is pinned via Python-level parity checks)."""
 
+import contextlib
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +15,38 @@ import pytest
 from horovod_tpu.ops import (flash_attention, fused_combine, fused_norms_dot,
                              merge_partials)
 from horovod_tpu.ops.flash_attention import _reference_partial
+
+# ``horovod_tpu.ops`` re-exports the function under the module's name.
+fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+
+# The backward's kernels by the form ``_fa_bwd_pallas`` picks for a shape.
+BWD_KERNELS = {"fused": ["hvd_flash_bwd"],
+               "fused_tiled": ["hvd_flash_bwd_tiled"],
+               "two_pass": ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"]}
+
+
+@contextlib.contextmanager
+def forced_two_pass():
+    """The dQ and dK/dV passes for a shape whose dQ row fits VMEM: no option
+    selects them, so the module's VMEM constant is patched to nothing. jit
+    keeps a trace per shape, hence the cleared caches on the way in and
+    out."""
+    patch = pytest.MonkeyPatch()
+    patch.setattr(fa, "_VMEM_BYTES", 0)
+    jax.clear_caches()
+    try:
+        yield
+    finally:
+        patch.undo()
+        jax.clear_caches()
+
+
+@pytest.fixture(params=["fused_tiled", "two_pass"])
+def bwd_form(request):
+    """Both several-tile forms of the backward, the second one forced."""
+    with (forced_two_pass() if request.param == "two_pass"
+          else contextlib.nullcontext()):
+        yield request.param
 
 
 def _rand(shape, seed, dtype=jnp.float32):
@@ -232,10 +267,11 @@ def test_flash_kv_mask_grads_flow():
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_bwd_kernel_matches_reference(causal):
+def test_flash_bwd_kernel_matches_reference(causal, bwd_form):
     """The dedicated blockwise backward (no-residual path) must match the
     materialised-softmax vjp, including causal tile skipping and padding in
-    BOTH sequence dims (T=100/84 are not block multiples)."""
+    BOTH sequence dims (T=100/84 are not block multiples), as one fused
+    tiled kernel and as the two passes."""
     B, H, D = 2, 2, 32
     Tq, Tk = (100, 100) if causal else (100, 84)
     q = _rand((B, Tq, H, D), 20)
@@ -256,6 +292,7 @@ def test_flash_bwd_kernel_matches_reference(causal):
     for a, b in zip(g, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-4)
+    assert _bwd_kernels(loss, q, k, v) == BWD_KERNELS[bwd_form]
 
 
 def pallas_names(fn, *args):
@@ -330,9 +367,10 @@ def test_flash_bwd_fused_kernel_matches_reference(causal, Tq, Tk, masked,
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_bwd_fused_agrees_with_two_pass(causal):
-    """Same inputs through the fused kernel (one tile) and through the dQ and
-    dK/dV passes (forced by 32-token blocks): one algorithm, two grids."""
+def test_flash_bwd_fused_agrees_with_two_pass(causal, bwd_form):
+    """Same inputs through the fused kernel (one tile) and, at 32-token
+    blocks, through the fused tiled kernel or the dQ and dK/dV passes: one
+    algorithm, three grids."""
     B, H, D, Tq, Tk = 2, 2, 32, 100, 84
     q = _rand((B, Tq, H, D), 43)
     k = _rand((B, Tk, H, D), 44)
@@ -347,13 +385,96 @@ def test_flash_bwd_fused_agrees_with_two_pass(causal):
         return loss(q, k, v, block_q=32, block_k=32)
 
     assert _bwd_kernels(loss, q, k, v) == ["hvd_flash_bwd"]
-    assert _bwd_kernels(tiled, q, k, v) == ["hvd_flash_bwd_dkv",
-                                            "hvd_flash_bwd_dq"]
+    assert _bwd_kernels(tiled, q, k, v) == BWD_KERNELS[bwd_form]
     g1 = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(tiled, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-5, atol=5e-5)
+
+
+def check_tiled_equals_two_pass(T, block, D, Dv, causal, masked):
+    """dQ, dK, dV of the fused tiled kernel and of the two passes: the same
+    bits, and both the gradients of ``_reference_partial``. bf16 operands, as
+    every cell feeds the kernels. (In float32 XLA:CPU, which runs the
+    interpreted kernels here, fuses ``dot * scale - m`` differently in the
+    dQ pass's program than in the dK/dV pass's, so those two already differ
+    from each other by an ulp in one case, full and unmasked at 192/128.)"""
+    B, H, dtype = 2, 2, jnp.bfloat16
+    kq, kk, kv, kd = jax.random.split(jax.random.PRNGKey(T + D), 4)
+    q = jax.random.normal(kq, (B, T, H, D), dtype)
+    k = jax.random.normal(kk, (B, T, H, D), dtype)
+    v = jax.random.normal(kv, (B, T, H, Dv), dtype)
+    do = jax.random.normal(kd, (B, T, H, Dv), dtype)
+    mask = bias = None
+    if masked:
+        mask = jnp.arange(T)[None, :] < jnp.array([T - 7, T])[:, None]
+        bias = jnp.where(mask, 0.0, -1e30)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, kv_mask=mask,
+                               block_q=block, block_k=block)
+
+    def reference(q, k, v):
+        return _reference_partial(q, k, v, bias, causal=causal,
+                                  scale=D ** -0.5)[0]
+
+    def grads():
+        return jax.vjp(flash, q, k, v)[1](do)
+
+    tiled = grads()
+    assert _bwd_kernels(lambda *a: flash(*a).sum(), q, k, v) == \
+        BWD_KERNELS["fused_tiled"]
+    with forced_two_pass():
+        two_pass = grads()
+        assert _bwd_kernels(lambda *a: flash(*a).sum(), q, k, v) == \
+            BWD_KERNELS["two_pass"]
+    want = jax.vjp(reference, q, k, v)[1](do)
+    for name, a, b, ref in zip("qkv", tiled, two_pass, want):
+        assert a.dtype == dtype and a.shape == ref.shape, name
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), "d" + name
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   rtol=5e-2, atol=5e-2, err_msg="d" + name)
+
+
+@pytest.mark.parametrize("T,block", [(64, 16), (40, 16)],
+                         ids=["four_tiles", "padded_to_48"])
+@pytest.mark.parametrize("masked", [False, True], ids=["", "masked"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_bwd_tiled_equals_two_pass_bit_for_bit(causal, masked, T,
+                                                     block):
+    """Several tiles and a dQ row that fits VMEM: ONE kernel computes p and
+    ds once a tile and sums dQ over the key tiles in the order the dQ pass
+    does, so nothing of the two passes' result changes."""
+    check_tiled_equals_two_pass(T, block, 64, 64, causal, masked)
+
+
+def test_flash_bwd_form_follows_the_vmem_a_dq_row_needs(monkeypatch):
+    """The choice among the three forms is made from shapes alone: the sum
+    of ``_tiled_bwd_vmem_bytes`` against the module's VMEM constant."""
+    # the latent-attention cell's shape: 16.5 MiB (blocks twice 7.75, the
+    # float32 accumulators 4.75 of which dQ's row 4, four float32 tiles 4)
+    need = fa._tiled_bwd_vmem_bytes(4096, 192, 128, 512, 512, 2, False)
+    assert need == 17301504
+    assert fa._VMEM_SCOPED_DEFAULT < need < fa._VMEM_BYTES
+    longest = max(T for T in range(4096, 1 << 18, 4096)
+                  if fa._tiled_bwd_vmem_bytes(T, 128, 128, 512, 512, 2,
+                                              False) <= fa._VMEM_BYTES)
+    assert longest == 57344       # 128-deep heads: the two passes beyond
+
+    q = jnp.ones((1, 64, 1, 32), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, block_q=16, block_k=16).sum()
+
+    assert _bwd_kernels(loss, q, q, q) == BWD_KERNELS["fused_tiled"]
+    need = fa._tiled_bwd_vmem_bytes(64, 32, 32, 16, 16, 2, False)
+    for limit, form in ((need, "fused_tiled"), (need - 1, "two_pass")):
+        monkeypatch.setattr(fa, "_VMEM_BYTES", limit)
+        jax.clear_caches()
+        assert _bwd_kernels(loss, q, q, q) == BWD_KERNELS[form]
+    jax.clear_caches()
 
 
 def test_flash_residual_path_still_differentiable():
@@ -389,14 +510,15 @@ def test_flash_residual_path_still_differentiable():
                                    rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("blocks", [{"block_q": 32, "block_k": 32}, {}],
-                         ids=["two_pass", "fused"])
-def test_flash_partitioned_under_gspmd_mesh(monkeypatch, blocks):
+@pytest.mark.parametrize("bwd_form", ["two_pass", "fused_tiled", "fused"],
+                         indirect=True)
+def test_flash_partitioned_under_gspmd_mesh(monkeypatch, bwd_form):
     """flash_attention inside jit with dp x tp sharded operands: the
     custom_partitioning rule shards batch*head and replicates seq/depth, so
     the kernel runs per-shard and matches the unsharded result, whichever
     backward the shape selects."""
     monkeypatch.setenv("HOROVOD_FLASH_PARTITION", "1")
+    blocks = {} if bwd_form == "fused" else {"block_q": 32, "block_k": 32}
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     B, T, H, D = 4, 64, 4, 32
@@ -412,6 +534,9 @@ def test_flash_partitioned_under_gspmd_mesh(monkeypatch, blocks):
         return (flash_attention(q, k, v, causal=True, **blocks) ** 2).sum()
 
     f = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    hlo = f.lower(qs, ks, vs).compile().as_text()
+    assert [n for n in fa.KERNEL_BWD if n + "/" in hlo] == \
+        sorted(BWD_KERNELS[bwd_form], key=fa.KERNEL_BWD.index)
     val_s, grads_s = f(qs, ks, vs)
     val_r, grads_r = f(q, k, v)  # unsharded oracle (same jit, fresh compile)
     np.testing.assert_allclose(float(val_s), float(val_r), rtol=1e-4)

@@ -25,7 +25,7 @@ from horovod_tpu.train import (create_gspmd_train_state,
                                create_train_state, make_gspmd_train_step,
                                make_pipeline_train_step, make_train_step,
                                next_token_loss)
-from test_ops import pallas_names
+from test_ops import BWD_KERNELS, bwd_form, pallas_names  # noqa: F401
 
 
 @pytest.fixture(autouse=True)
@@ -417,12 +417,13 @@ def test_named_scopes_are_metadata_only(monkeypatch):
     assert [a.tobytes() for a in named] == [b.tobytes() for b in plain]
 
 
-@pytest.mark.parametrize("blocks,bwd_names,kind", [
-    ({}, ["hvd_flash_bwd"], "fused"),
-    ({"block_q": 64, "block_k": 64},
-     ["hvd_flash_bwd_dkv", "hvd_flash_bwd_dq"], "two_pass"),
-], ids=["single_tile", "multi_tile"])
-def test_flash_kernels_carry_their_names(blocks, bwd_names, kind):
+@pytest.mark.parametrize("blocks,bwd_form", [
+    ({}, "fused"),
+    ({"block_q": 64, "block_k": 64}, "fused_tiled"),
+    ({"block_q": 64, "block_k": 64}, "two_pass"),
+], ids=["single_tile", "multi_tile", "multi_tile_two_pass"],
+    indirect=["bwd_form"])
+def test_flash_kernels_carry_their_names(blocks, bwd_form):
     from horovod_tpu.ops.flash_attention import (KERNEL_BWD, KERNEL_FWD,
                                                  flash_attention)
     # three heads: a shape no other test traces, so the jitted backward is
@@ -434,11 +435,13 @@ def test_flash_kernels_carry_their_names(blocks, bwd_names, kind):
 
     assert pallas_names(loss, q, q, q) == ["hvd_flash_fwd"]
     names = sorted(pallas_names(jax.grad(loss, argnums=(0, 1, 2)), q, q, q))
-    assert names == bwd_names + ["hvd_flash_fwd"]
+    assert names == BWD_KERNELS[bwd_form] + ["hvd_flash_fwd"]
     assert set(names) <= {KERNEL_FWD, *KERNEL_BWD}
+    assert len(KERNEL_BWD) == 4 and set(KERNEL_BWD) == {
+        n for form in BWD_KERNELS.values() for n in form}
     counted = {k: T.active().registry.counter_value(
-        "hvd_flash_bwd_kernels_total", kind=k) for k in ("fused", "two_pass")}
-    assert counted == {"fused": 0.0, "two_pass": 0.0, kind: 1.0}
+        "hvd_flash_bwd_kernels_total", kind=k) for k in BWD_KERNELS}
+    assert counted == {**dict.fromkeys(BWD_KERNELS, 0.0), bwd_form: 1.0}
 
 
 def test_adasum_kernels_carry_their_names():
