@@ -24,6 +24,29 @@ Two properties matter for the distributed design:
 - **Causal block skipping**: with ``causal=True`` tiles strictly above the
   diagonal are predicated off with ``pl.when``, saving ~half the MXU work.
 
+The forward (``hvd_flash_fwd``, grid ``(batch*heads, nq, nk)``) keeps its
+running statistics WHOLE across the 128 lanes: ``m`` and the correction
+``exp(m_prev - m_new)`` as ``[rows, 128]`` arrays with every lane of a row
+alike, read, updated and stored without a slice or a broadcast, and the
+denominator, where a key tile is whole 128-lane tiles, as a row's 128
+per-lane partial sums (lane j gathers the probabilities of columns j,
+j + 128, ... by elementwise adds), added up across lanes once a query tile
+in the last visit. As ``(rows, 1)`` columns each use of a statistic went
+through the cross-lane unit, and on the v5e that unit, not the array, set a
+visit's time: at 4,096 causal tokens the call fell from 7.12 to 4.21 ms at
+192/128 depth and from 1.385 to 0.635 at 128, to within 3% of its two dots
+alone (PERF.md section 6, PR 34). ``m`` and every ``o`` of a one-tile
+sequence keep their bits; over several key tiles a row's denominator is
+added in another order (5 float32 places at most measured, one bfloat16
+place of ``o`` in a hundred thousandth of the elements), and ``l`` as
+returned is still the whole row's sum, which :func:`merge_partials` needs.
+A visit is computed in row groups (``_fwd_groups``, from the tile's static
+shape): two halves where a 512-row tile meets the sequence's one key tile,
+one group otherwise; rows never mix, so the groups change no number. The
+trace-time counter
+``hvd_flash_fwd_kernels_total{groups="1"|"2", denominator="lanes"|"rows"}``
+says which a program got.
+
 The backward pass recomputes probability tiles from the saved
 (q, k, v, o, m, l) — the standard flash trade of FLOPs for HBM (SURVEY.md §7
 lists remat as the stock TPU memory lever). Two implementations exist:
@@ -58,7 +81,8 @@ the saved statistics; they differ in how often.
 
 The trace-time counter
 ``hvd_flash_bwd_kernels_total{kind="fused"|"fused_tiled"|"two_pass"}`` says
-which a program got.
+which a program got. The backward kernels read the saved ``m`` and ``l``
+as ``(rows, 1)`` blocks and hide their softmax arithmetic under five dots.
 
 On non-TPU backends the kernel runs in Pallas interpreter mode, which is how
 the CPU test mesh exercises it (the reference's CPU+Gloo fake-backend trick,
@@ -97,19 +121,40 @@ _BWD_DQ, _BWD_DKV, _BWD_FUSED, _BWD_TILED = KERNEL_BWD
 _VMEM_BYTES = 64 * 1024 * 1024
 _VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024
 
+# Rows of a group where the forward splits a tile (``_fwd_groups``).
+_GROUP_ROWS = 256
+
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _lanes(x, n):
+    """``x`` [rows, 128] with every lane of a row alike, as [rows, n]: a lane
+    slice, or whole 128-lane copies side by side. Neither crosses lanes;
+    another width broadcasts lane 0."""
+    if n <= _LANE:
+        return x[:, :n]
+    if n % _LANE == 0:
+        return pltpu.repeat(x, n // _LANE, axis=1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, nk,
-               has_bias):
+               has_bias, groups, lane_sums):
     """One (batch*head, q-block, k-block) grid step.
 
     Scratch (persists across the innermost k-block grid dim):
       acc [bq, Dv] f32 — unnormalised output accumulator
-      m_s [bq, 128] f32 — running row max (broadcast over lanes)
-      l_s [bq, 128] f32 — running denominator (broadcast over lanes)
+      m_s [bq, 128] f32 — running row max, every lane of a row alike
+      l_s [bq, 128] f32 — running denominator: with ``lane_sums`` (``bk``
+        is whole 128-lane tiles) a row's per-lane partial sums, which
+        ``_emit`` adds up; every lane alike otherwise
+
+    The statistics are read, updated and written whole, [rows, 128], so
+    that no use of one goes through the cross-lane unit (module docstring):
+    what crosses lanes is the row max, once a visit, and the denominator,
+    once a query tile. The visit runs in ``groups`` row groups.
     """
     if has_bias:
         bias_ref, o_ref, m_ref, l_ref, acc, m_s, l_s = refs
@@ -117,6 +162,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, nk,
         o_ref, m_ref, l_ref, acc, m_s, l_s = refs
     iq = pl.program_id(1)
     ik = pl.program_id(2)
+    g = bq // groups
 
     @pl.when(ik == 0)
     def _init():
@@ -128,46 +174,74 @@ def _fa_kernel(q_ref, k_ref, v_ref, *refs, scale, causal, bq, bk, nk,
     # block can see any k position in that block).
     visible = ((iq + 1) * bq - 1 >= ik * bk) if causal else (ik >= 0)
 
-    @pl.when(visible)
-    def _compute():
+    def _visit(r0):
+        """Rows ``r0 : r0 + g`` of the tile against the whole key block.
+        Rows never mix, so the groups change no number."""
+        rows = pl.ds(r0, g)
         # Keep the input dtype (bf16 on TPU) for both MXU dots and accumulate
         # in f32 via preferred_element_type — casting up first would force
         # fp32 MXU passes, ~4x the matmul cost for no accuracy the f32
         # accumulation doesn't already give.
-        q = q_ref[0]
+        q = q_ref[0, rows, :]
         k = k_ref[0]
         v = v_ref[0]
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        k_pos = ik * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         if has_bias:
             s = s + bias_ref[0].astype(jnp.float32)
         if causal:
-            q_pos = iq * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            q_pos = iq * bq + r0 + lax.broadcasted_iota(jnp.int32, (g, bk), 0)
+            k_pos = ik * bk + lax.broadcasted_iota(jnp.int32, (g, bk), 1)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m_prev = m_s[:, :1]
+        m_prev = m_s[rows, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # Rows still fully masked have m_new == NEG_INF; exp(s - m_new) would
         # be exp(0) = 1 there, so zero those probabilities explicitly.
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - _lanes(m_new, bk)))
         corr = jnp.exp(m_prev - m_new)
-        l_s[:] = jnp.broadcast_to(
-            l_s[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
-            l_s.shape)
-        acc[:] = acc[:] * corr + lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_s[:] = jnp.broadcast_to(m_new, m_s.shape)
+        if lane_sums:
+            # lane j gathers columns j, j + 128, ...: elementwise adds
+            p_sum = p[:, :_LANE]
+            for c in range(_LANE, bk, _LANE):
+                p_sum = p_sum + p[:, c:c + _LANE]
+        else:
+            p_sum = jnp.sum(p, axis=1, keepdims=True)
+        l_s[rows, :] = l_s[rows, :] * corr + p_sum
+        acc[rows, :] = acc[rows, :] * _lanes(corr, acc.shape[1]) \
+            + lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        m_s[rows, :] = m_new
+
+    @pl.when(visible)
+    def _compute():
+        for r0 in range(0, bq, g):
+            _visit(r0)
 
     @pl.when(ik == nk - 1)
     def _emit():
-        l = l_s[:, :1]
+        if lane_sums:
+            l = jnp.sum(l_s[:], axis=1, keepdims=True)
+        else:
+            l = l_s[:, :1]
         o_ref[0] = (acc[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
         # Residuals are [BH, Tq, 1] so the block's trailing dims (bq, 1)
         # satisfy the TPU tiling rule (sublane divisible by 8, lane equal to
         # the array dim).
         m_ref[0] = m_s[:, :1]
-        l_ref[0] = l_s[:, :1]
+        l_ref[0] = l
+
+
+def _fwd_groups(block_q, nk):
+    """Row groups the forward computes a visit in, from the tile's static
+    shape alone: two of ``_GROUP_ROWS`` where a 512-row tile meets the
+    sequence's ONE key tile, else one. Measured on the v5e (PERF.md section
+    6, PR 34): halves are 4-9% faster than the whole tile when init, visit
+    and emit share every grid step, and 3-8% slower across several key
+    tiles, where each group pushes the key block into the array again;
+    quarters lose everywhere."""
+    if nk == 1 and block_q > _GROUP_ROWS and block_q % _GROUP_ROWS == 0:
+        return block_q // _GROUP_ROWS
+    return 1
 
 
 def _pad_axis(x, axis, mult):
@@ -188,9 +262,15 @@ def _fa_pallas(q, k, v, bias3, *, causal, scale, block_q, block_k,
     BH, Tq, D = q.shape
     Tk, Dv = k.shape[1], v.shape[2]
     nq, nk = Tq // block_q, Tk // block_k
+    groups = _fwd_groups(block_q, nk)
+    lane_sums = block_k % _LANE == 0
+    # Trace-time: fires once per traced program, never per step.
+    _telemetry.inc("hvd_flash_fwd_kernels_total", groups=str(groups),
+                   denominator="lanes" if lane_sums else "rows")
     kern = functools.partial(_fa_kernel, scale=scale, causal=causal,
                              bq=block_q, bk=block_k, nk=nk,
-                             has_bias=bias3 is not None)
+                             has_bias=bias3 is not None, groups=groups,
+                             lane_sums=lane_sums)
     in_specs = [
         pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_k, D), lambda b, i, j: (b, j, 0)),

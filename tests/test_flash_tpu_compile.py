@@ -1,6 +1,7 @@
-"""The flash backward's three forms compiled ahead of time for a TPU v5e at
-the widths the benchmark's cells run. No chip is needed: Mosaic and XLA:TPU
-are installed here and compile for a chip that is described, not attached.
+"""The flash forward and the backward's three forms compiled ahead of time
+for a TPU v5e at the widths the benchmark's cells run. No chip is needed:
+Mosaic and XLA:TPU are installed here and compile for a chip that is
+described, not attached.
 Interpret mode (every other flash test) cannot see what Mosaic refuses: a
 dynamic slice off the tiling, or more VMEM than the call may hold."""
 
@@ -77,3 +78,35 @@ def test_flash_backward_compiles_for_v5e(one_chip, no_compile_cache, BH, T,
             or need <= fa._VMEM_SCOPED_DEFAULT
     else:
         assert need == vmem and f'"{vmem}"' in hlo
+
+
+# BH, T, D, Dv, block, causal, bias -> row groups of a visit
+@pytest.mark.parametrize("BH,T,D,Dv,block,causal,bias,groups", [
+    (128, 512, 64, 64, 512, False, True, 2),
+    (64, 4096, 192, 128, 512, True, False, 1),
+    (16, 4096, 128, 128, 512, True, False, 1),
+    (8, 64, 64, 64, 8, True, False, 1),
+], ids=["bert_512_one_tile", "joyai_4096_192_128", "ouro_4096_128",
+        "blocks_of_8_rows"])
+def test_flash_forward_compiles_for_v5e(one_chip, no_compile_cache, BH, T, D,
+                                        Dv, block, causal, bias, groups):
+    """The three cells' forward calls and the tests' smallest tile: one
+    custom call named ``hvd_flash_fwd``, in the row groups the shape gets,
+    within Mosaic's default scoped VMEM (no limit is asked for)."""
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [arg((BH, T, D)), arg((BH, T, D)), arg((BH, T, Dv)),
+            arg((BH, 1, T), jnp.float32) if bias else None]
+
+    def forward(*a):
+        return fa._fa_pallas(*a, causal=causal, scale=D ** -0.5,
+                             block_q=block, block_k=block, interpret=False)
+
+    assert fa._fwd_groups(block, T // block) == groups
+    hlo = jax.jit(forward).lower(*args).compile().as_text()
+    assert re.findall(r"%(hvd_flash\w*?)(?:\.\d+)? = ", hlo) == \
+        [fa.KERNEL_FWD] == ["hvd_flash_fwd"]
+    # a call that asks for a VMEM limit carries it as a scoped memory config
+    assert '"scoped_memory_configs":[]' in hlo and \
+        '"scoped_memory_configs":[{' not in hlo

@@ -1,7 +1,7 @@
 """Flash attention with a value depth of its own (latent attention: q and k
-192 deep, v and o 128): forward and the four backward kernels (fused one
-tile, fused tiled, dQ pass, dK/dV pass) in interpret mode against
-``_reference_partial``."""
+192 deep, v and o 128): forward (in row groups and whole) and the four
+backward kernels (fused one tile, fused tiled, dQ pass, dK/dV pass) in
+interpret mode against ``_reference_partial``."""
 
 import importlib
 
@@ -12,7 +12,8 @@ import pytest
 
 # ``horovod_tpu.ops`` re-exports the function under the module's name.
 fa = importlib.import_module("horovod_tpu.ops.flash_attention")
-from test_ops import (BWD_KERNELS, _bwd_kernels, bwd_form,  # noqa: E402,F401
+from test_ops import (BWD_KERNELS, FORWARD_TILES,  # noqa: E402,F401
+                      _bwd_kernels, bwd_form, check_forward_forms,
                       check_tiled_equals_two_pass)
 
 
@@ -70,6 +71,17 @@ def test_tiled_backward_equals_two_pass_bit_for_bit_at_192_128(causal, masked,
     """The latent-attention cell's depths through the fused tiled kernel:
     dQ and dK 192 deep, dV 128, the two passes' bits."""
     check_tiled_equals_two_pass(T, block, 192, 128, causal, masked)
+
+
+@pytest.mark.parametrize("causal,masked", [(True, False), (False, True)],
+                         ids=["causal", "masked"])
+@pytest.mark.parametrize("tile", list(FORWARD_TILES))
+def test_forward_forms_agree_at_192_128(tile, causal, masked):
+    """The latent-attention cell's depths through the forward: the
+    accumulator is rescaled by the lane-whole correction 128 wide while q
+    and k are 192 deep; row groups, the form before PR 34 and the plain
+    softmax as at equal depths."""
+    check_forward_forms(*FORWARD_TILES[tile], 192, 128, causal, masked)
 
 
 def test_partitioned_under_a_gspmd_mesh_at_another_value_depth(monkeypatch,

@@ -111,6 +111,31 @@ def test_merge_partials_equals_full_attention():
                                rtol=2e-5, atol=2e-5)
 
 
+def test_merge_partials_of_512_key_tiles_equals_full_attention():
+    """Ring attention's contract at the tile the models run: with key tiles
+    of whole 128-lane tiles the kernel carries the denominator as per-lane
+    partial sums, and the ``l`` it returns must still be the whole row's
+    sum: two halves of the keys, one tile each, merge into the attention
+    over both."""
+    B, T, H, D = 1, 1024, 2, 64
+    q, k, v = (_rand((B, T, H, D), seed) for seed in (20, 21, 22))
+    mask = jnp.arange(T)[None, :] < T - 9
+    full, (m, l) = flash_attention(q, k, v, causal=False, kv_mask=mask,
+                                   return_residuals=True)
+    half = T // 2
+    parts = []
+    for keys in (slice(0, half), slice(half, T)):
+        o_i, (m_i, l_i) = flash_attention(
+            q, k[:, keys], v[:, keys], causal=False, kv_mask=mask[:, keys],
+            return_residuals=True)
+        parts.append((o_i, m_i, l_i))
+    merged, m2, l2 = merge_partials(*parts)
+    np.testing.assert_allclose(np.asarray(merged), np.asarray(full),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(m2), np.asarray(m))
+    np.testing.assert_allclose(np.asarray(l2), np.asarray(l), rtol=2e-6)
+
+
 def test_flash_gradients_match_reference():
     B, T, H, D = 1, 32, 2, 16
     q = _rand((B, T, H, D), 20)
@@ -448,6 +473,150 @@ def test_flash_bwd_tiled_equals_two_pass_bit_for_bit(causal, masked, T,
     ds once a tile and sums dQ over the key tiles in the order the dQ pass
     does, so nothing of the two passes' result changes."""
     check_tiled_equals_two_pass(T, block, 64, 64, causal, masked)
+
+
+def _tiled_forward_as_before(q, k, v, bias3, *, causal, scale, block):
+    """(o, m, l) as the forward kernel computed them up to PR 34, tile by
+    tile in plain ``jnp`` on padded folded operands: a visit takes the whole
+    tile's row max and row SUM and carries one denominator a row, where the
+    kernel now carries 128 per-lane partial sums and adds them up once a
+    query tile."""
+    BH, Tq, _ = q.shape
+    Tk = k.shape[1]
+    dot = lambda a, b, dims: jax.lax.dot_general(
+        a, b, (dims, ((), ())), preferred_element_type=jnp.float32)
+    o, ms, ls = [], [], []
+    for b in range(BH):
+        for q0 in range(0, Tq, block):
+            acc = jnp.zeros((block, v.shape[2]), jnp.float32)
+            m = jnp.full((block, 1), fa.NEG_INF, jnp.float32)
+            l = jnp.zeros((block, 1), jnp.float32)
+            for k0 in range(0, Tk, block):
+                if causal and q0 + block - 1 < k0:
+                    continue
+                s = dot(q[b, q0:q0 + block], k[b, k0:k0 + block],
+                        ((1,), (1,))) * scale
+                if bias3 is not None:
+                    s = s + bias3[b, :, k0:k0 + block]
+                if causal:
+                    seen = (q0 + jnp.arange(block)[:, None]
+                            >= k0 + jnp.arange(block)[None, :])
+                    s = jnp.where(seen, s, fa.NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.where(s <= fa.NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+                corr = jnp.exp(m - m_new)
+                l = l * corr + jnp.sum(p, axis=1, keepdims=True)
+                acc = acc * corr + dot(p.astype(v.dtype),
+                                       v[b, k0:k0 + block], ((1,), (0,)))
+                m = m_new
+            o.append((acc / jnp.where(l == 0.0, 1.0, l)).astype(q.dtype))
+            ms.append(m)
+            ls.append(l)
+    fold = lambda xs: jnp.concatenate(xs).reshape(BH, Tq, -1)
+    return fold(o), fold(ms), fold(ls)
+
+
+def _places(a, b, dtype):
+    """Largest distance between two arrays in representable values of
+    ``dtype`` (finite numbers of one sign order as their bit patterns)."""
+    bits = {jnp.float32: np.int32, jnp.bfloat16: np.int16}[dtype]
+    a, b = (np.asarray(jnp.asarray(x, dtype)).view(bits).astype(np.int64)
+            for x in (a, b))
+    return int(np.abs(a - b).max())
+
+
+def check_forward_forms(T, block, D, Dv, causal, masked):
+    """The forward kernel on bf16 operands, as the cells feed it: in the
+    row groups its shape gets, in ONE group (the form before PR 34) and in
+    four where 128 rows divide the tile, forced as the two passes are: the
+    same bits of o, m and l, for rows never mix. Against the tile-by-tile
+    arithmetic before PR 34: m bit for bit, l within a few float32 places
+    and o within one bfloat16 place of its value (where a key tile is whole
+    128-lane tiles the denominator's terms are added lanes first, then
+    across them once a query tile: 6 places at most on the chip at 4,096
+    tokens, 4 here; where it is not, the order is the same and XLA:CPU's
+    fusions, which run the interpreted kernel here, still move l by up to
+    2). And within bfloat16's reach of the plain softmax."""
+    BH, dtype, scale = 2, jnp.bfloat16, D ** -0.5
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(T + D + block), 3)
+    q = jax.random.normal(kq, (BH, T, D), dtype)
+    k = jax.random.normal(kk, (BH, T, D), dtype)
+    v = jax.random.normal(kv, (BH, T, Dv), dtype)
+    bias = None
+    if masked:
+        bias = jnp.where(jnp.arange(T)[None, :]
+                         < jnp.array([T - 7, T])[:, None], 0.0, fa.NEG_INF)
+    qp, kp, vp = (fa._pad_axis(x, 1, block)[0] for x in (q, k, v))
+    bias3 = fa._pad_bias3(bias, BH, T, kp.shape[1])
+    nk = kp.shape[1] // block
+
+    def forward(groups=None):
+        with pytest.MonkeyPatch.context() as patch:
+            if groups is not None:
+                patch.setattr(fa, "_fwd_groups", lambda block_q, nk: groups)
+            # a jit of its own: a trace that sees the patched groups
+            return jax.jit(lambda *a: fa._fa_pallas(
+                *a, causal=causal, scale=scale, block_q=block, block_k=block,
+                interpret=True))(qp, kp, vp, bias3)
+
+    landed = forward()
+    want_groups = 2 if block == 512 and nk == 1 else 1
+    assert fa._fwd_groups(block, nk) == want_groups
+    for groups in {1, want_groups, 4 if block % 512 == 0 else 1}:
+        for name, a, b in zip("oml", forward(groups), landed):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), \
+                (name, groups)
+    before = _tiled_forward_as_before(qp, kp, vp, bias3, causal=causal,
+                                      scale=scale, block=block)
+    o, m, l = landed
+    assert np.asarray(m).tobytes() == np.asarray(before[1]).tobytes()
+    assert _places(l, before[2], jnp.float32) <= 8
+    # one bfloat16 place (2**-8 to 2**-7 of the value) but for sums that
+    # cancel to nearly nothing, where a float32 place of the terms shows
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(before[0], np.float32),
+                               rtol=2.0 ** -7, atol=1e-5)
+    ref_o, ref_m, ref_l = _reference_partial(
+        q[:, :, None], k[:, :, None], v[:, :, None], bias, causal=causal,
+        scale=scale)
+    np.testing.assert_allclose(np.asarray(o[:, :T], np.float32),
+                               np.asarray(ref_o[:, :, 0], np.float32),
+                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(m[:, :T, 0], ref_m[:, 0], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(l[:, :T, 0], ref_l[:, 0], rtol=1e-5)
+
+
+# (T, block): a 512-row tile is two groups against one key tile and one
+# against several; tiles of 48 and 16 rows are one group with a denominator
+# a row (a key tile is no whole number of 128-lane tiles). Then the depth,
+# causal or not, a key-padding bias or not.
+FORWARD_TILES = {"one_tile_512": (512, 512), "two_tiles_512": (1024, 512),
+                 "one_tile_48": (48, 48), "four_tiles_16": (64, 16),
+                 "padded_to_48": (40, 16)}
+FORWARD_CASES = [
+    (tile, D, causal, masked)
+    for tile, depths, kinds in (
+        ("one_tile_512", (64, 128), ((True, False), (False, True))),
+        ("two_tiles_512", (64, 128), ((True, False), (False, True),
+                                      (True, True))),
+        ("one_tile_48", (64,), ((True, True), (False, False))),
+        ("four_tiles_16", (64,), ((True, False), (False, True),
+                                  (True, True), (False, False))),
+        ("padded_to_48", (64,), ((True, False), (False, True),
+                                 (True, True), (False, False))))
+    for D in depths for causal, masked in kinds]
+
+
+def forward_case_id(case):
+    tile, D, causal, masked = case
+    return "-".join([tile, f"{D}_deep", "causal" if causal else "full"]
+                    + ["masked"] * masked)
+
+
+@pytest.mark.parametrize("case", FORWARD_CASES, ids=forward_case_id)
+def test_flash_forward_forms_agree(case):
+    tile, D, causal, masked = case
+    check_forward_forms(*FORWARD_TILES[tile], D, D, causal, masked)
 
 
 def test_flash_bwd_form_follows_the_vmem_a_dq_row_needs(monkeypatch):

@@ -444,6 +444,27 @@ def test_flash_kernels_carry_their_names(blocks, bwd_form):
     assert counted == {**dict.fromkeys(BWD_KERNELS, 0.0), bwd_form: 1.0}
 
 
+@pytest.mark.parametrize("tokens,groups,denominator", [
+    (512, "2", "lanes"), (1024, "1", "lanes"), (48, "1", "rows")],
+    ids=["one_tile_of_512", "two_tiles_of_512", "one_tile_of_48"])
+def test_flash_forward_counts_its_row_groups(tokens, groups, denominator):
+    """``hvd_flash_fwd_kernels_total`` says, once a traced forward, in how
+    many row groups a visit is computed (two where a 512-row tile meets the
+    sequence's one key tile) and how the denominator is carried (per-lane
+    partial sums where a key tile is whole 128-lane tiles)."""
+    from horovod_tpu.ops.flash_attention import flash_attention
+    # five heads: a shape no other test traces (jit caches traces)
+    q = jnp.ones((1, tokens, 5, 64), jnp.bfloat16)
+    assert pallas_names(
+        lambda q: flash_attention(q, q, q, causal=True), q) == \
+        ["hvd_flash_fwd"]
+    counted = {(g, d): T.active().registry.counter_value(
+        "hvd_flash_fwd_kernels_total", groups=g, denominator=d)
+        for g in ("1", "2", "4") for d in ("lanes", "rows")}
+    assert {key for key, n in counted.items() if n} == \
+        {(groups, denominator)}
+
+
 def test_adasum_kernels_carry_their_names():
     from horovod_tpu.ops import fused
     a = jnp.ones((4096,), jnp.float32)
