@@ -11,7 +11,8 @@ def __getattr__(name):
     import importlib
     lazy = {"bert": ".bert", "llama": ".llama", "mixtral": ".mixtral",
             "dlrm": ".dlrm", "decode": ".decode",
-            "latent_moe": ".latent_moe", "looped": ".looped"}
+            "latent_moe": ".latent_moe", "looped": ".looped",
+            "window_moe": ".window_moe"}
     for mod, path in lazy.items():
         if name == mod:
             try:

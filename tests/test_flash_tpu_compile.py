@@ -110,3 +110,39 @@ def test_flash_forward_compiles_for_v5e(one_chip, no_compile_cache, BH, T, D,
     # a call that asks for a VMEM limit carries it as a scoped memory config
     assert '"scoped_memory_configs":[]' in hlo and \
         '"scoped_memory_configs":[{' not in hlo
+
+
+# BH, T, window -> the kernels, and the VMEM the tiled backward's shapes sum to
+@pytest.mark.parametrize("BH,T,window,kernels,vmem", [
+    (36, 8192, 512, ["hvd_flash_fwd_window", "hvd_flash_bwd_tiled_window"],
+     16252928),
+    (24, 8192, None, ["hvd_flash_fwd", "hvd_flash_bwd_tiled"], 16252928),
+    (2, 65536, 512, ["hvd_flash_fwd_window", "hvd_flash_bwd_dq_window",
+                     "hvd_flash_bwd_dkv_window"], None),
+], ids=["laguna_sliding_8192", "laguna_full_8192", "row_64k_two_pass"])
+def test_windowed_flash_compiles_for_v5e(one_chip, no_compile_cache, BH, T,
+                                         window, kernels, vmem):
+    """The layer-pattern cell's two calls at 128-deep heads in tiles of 512:
+    Mosaic takes the band's index maps (a traced first tile added to the
+    step), the dQ scratch zeroed and written by the band's own first and
+    last key tile, and the windowed names reach the compiled program."""
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stat = arg((BH, T, 1), jnp.float32)
+    qkv = [arg((BH, T, 128))] * 3
+    kw = dict(causal=True, scale=128 ** -0.5, block_q=512, block_k=512,
+              interpret=False, window=window)
+    forward = jax.jit(lambda *a: fa._fa_pallas(*a, None, **kw))
+    backward = jax.jit(lambda *a: fa._fa_bwd_pallas(*a, None, **kw))
+    hlo = forward.lower(*qkv).compile().as_text() \
+        + backward.lower(*qkv, qkv[0], stat, stat, stat).compile().as_text()
+    assert sorted(re.findall(r"%(hvd_flash\w*?)(?:\.\d+)? = ", hlo)) == \
+        sorted(kernels)
+    if vmem is not None:    # within Mosaic's default: no limit is asked for
+        assert fa._tiled_bwd_vmem_bytes(T, 128, 128, 512, 512, 2, False) \
+            == vmem <= fa._VMEM_SCOPED_DEFAULT
+        assert '"scoped_memory_configs":[{' not in hlo
+    if window is not None:
+        assert len(fa.band_tiles(T // 512, T // 512, 512, 512, window)) \
+            == 2 * (T // 512) - 1

@@ -465,6 +465,108 @@ def test_flash_forward_counts_its_row_groups(tokens, groups, denominator):
         {(groups, denominator)}
 
 
+@pytest.mark.parametrize("blocks,bwd_form,kind", [
+    ({}, "fused", "bwd_fused"),
+    ({"block_q": 16, "block_k": 16}, "fused_tiled", "bwd_tiled"),
+    ({"block_q": 16, "block_k": 16}, "two_pass", "bwd_two_pass"),
+], ids=["single_tile", "multi_tile", "multi_tile_two_pass"],
+    indirect=["bwd_form"])
+def test_windowed_flash_kernels_carry_their_names_and_count_themselves(
+        blocks, bwd_form, kind):
+    """A windowed call's kernels have names of their own, so the readers of
+    the unwindowed kernels' time see none of it, and
+    ``hvd_flash_window_kernels_total`` counts one a traced windowed call,
+    where the kernels are chosen, with the band's tiles a (batch*head)."""
+    import importlib
+    # ``horovod_tpu.ops`` re-exports the function under the module's name
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+    # seven heads: a shape no other test traces (jit caches traces)
+    q = jnp.ones((1, 64, 7, 16), jnp.float32)
+
+    def loss(q, k, v, window=24):
+        return fa.flash_attention(q, k, v, causal=True, window=window,
+                                  **blocks).sum()
+
+    names = sorted(pallas_names(jax.grad(loss, argnums=(0, 1, 2)), q, q, q))
+    assert names == [n + "_window" for n in BWD_KERNELS[bwd_form]] \
+        + ["hvd_flash_fwd_window"]
+    assert set(names) <= {fa.KERNEL_FWD_WINDOW, *fa.KERNEL_BWD_WINDOW}
+    assert not set(names) & {fa.KERNEL_FWD, *fa.KERNEL_BWD}
+    tiles = 1 if bwd_form == "fused" else 4 + 3 + 2   # diagonal, two below
+    counted = {k: T.active().registry.counter_value(
+        "hvd_flash_window_kernels_total", window=24, band_tiles=tiles,
+        kind=k) for k in ("fwd", "bwd_fused", "bwd_tiled", "bwd_two_pass")}
+    assert counted == {"fwd": 1.0, "bwd_fused": 0.0, "bwd_tiled": 0.0,
+                       "bwd_two_pass": 0.0, kind: 1.0}
+    # the unwindowed counter still says which form ran; an unwindowed call
+    # counts no windowed kernel
+    assert T.active().registry.counter_value(
+        "hvd_flash_bwd_kernels_total", kind=bwd_form) == 1.0
+    pallas_names(jax.grad(lambda *a: loss(*a, window=None),
+                          argnums=(0, 1, 2)), q, q, q)
+    assert sum(n for s, n in T.active().registry.export()["c"].items()
+               if s.startswith("hvd_flash_window_kernels_total")) == 2.0
+
+
+def test_pattern_layers_carry_their_scopes_and_count_themselves_by_kind():
+    """``models/window_moe.py`` through the data-parallel step: scopes
+    ``hvd_attn_full`` / ``hvd_attn_window`` with ``hvd_head_gate`` inside,
+    the ``hvd_moe_*`` scopes as they are, and one
+    ``hvd_attn_layers_total{kind=,heads=,kv_heads=}`` a traced layer."""
+    from horovod_tpu.models.latent_moe import next_tokens_loss
+    from horovod_tpu.models.window_moe import (FULL, SLIDING, WindowMoE,
+                                               window_moe_tiny)
+    hvd.init()
+    c = window_moe_tiny(axis_name=hvd.RANK_AXIS)
+    tokens = jnp.asarray(np.random.RandomState(8).randint(0, 128, (8, 16)),
+                         jnp.int32)
+    dopt = distributed(optax.sgd(0.1))
+    model = WindowMoE(c)
+    state = create_train_state(model, jax.random.PRNGKey(0), tokens[:1], dopt)
+    labelled = [dict(kind=FULL, heads=8, kv_heads=4),
+                dict(kind=SLIDING, heads=12, kv_heads=4)]
+    count = lambda: [T.active().registry.counter_value(
+        "hvd_attn_layers_total", **l) for l in labelled]
+    before = count()
+    step = make_train_step(model, dopt, next_tokens_loss())
+    text = step.lower(state, tokens, tokens).as_text(debug_info=True)
+    for scope in ("hvd_attn_full", "hvd_attn_window", "hvd_head_gate",
+                  "hvd_moe_route", "hvd_moe_experts", "hvd_moe_shared"):
+        assert f"/{scope}" in text or f'"{scope}/' in text, scope
+    assert "hvd_forward)/WindowMoE/layer_1/attn/hvd_attn_window/" \
+        "hvd_head_gate/" in text
+    assert "/layer_0/attn/hvd_attn_full/" in text
+    # trace-time: one a traced layer (two full, two sliding)
+    assert [a - b for a, b in zip(count(), before)] == [2.0, 2.0]
+    from horovod_tpu.analysis import analyze_step
+    assert analyze_step(step, state, tokens, tokens) == []
+
+
+def test_a_step_counts_one_windowed_forward_and_backward_a_sliding_layer():
+    """Two sliding layers of ONE shape: the kernels' own jit keeps one trace
+    for both, and ``hvd_flash_window_kernels_total`` still counts two
+    forward and two backward calls. (Trace-time: under ``nn.remat`` a layer
+    is traced for the forward and again for the backward's recomputation,
+    so the forward's count doubles whether or not the policy then keeps the
+    kernel's outputs; the kernels of the compiled program are read by their
+    names.)"""
+    from horovod_tpu.models.latent_moe import next_tokens_loss
+    from horovod_tpu.models.window_moe import WindowMoE, window_moe_tiny
+    hvd.init()
+    model = WindowMoE(window_moe_tiny(use_flash=True))
+    tokens = jnp.asarray(np.random.RandomState(8).randint(0, 128, (8, 24)),
+                         jnp.int32)
+    dopt = distributed(optax.sgd(0.1))
+    state = create_train_state(model, jax.random.PRNGKey(0), tokens[:1], dopt)
+    count = lambda kind: T.active().registry.counter_value(
+        "hvd_flash_window_kernels_total", window=5, band_tiles=1, kind=kind)
+    before = [count("fwd"), count("bwd_fused")]
+    make_train_step(model, dopt, next_tokens_loss()).lower(
+        state, tokens, tokens)
+    assert [count("fwd") - before[0], count("bwd_fused") - before[1]] == \
+        [2.0, 2.0]
+
+
 def test_adasum_kernels_carry_their_names():
     from horovod_tpu.ops import fused
     a = jnp.ones((4096,), jnp.float32)
