@@ -312,7 +312,8 @@ class HeldRouting(NamedTuple):
     :func:`dropless_share` works over windows of the plan that hold them
     (:func:`_window`). Both directions of the mapping
     are kept so that dispatch and combine, forward and backward, are row
-    gathers (see :class:`SortedRouting` for why not scatters)."""
+    gathers (see :class:`SortedRouting` for why not scatters of ``k*T``
+    rows; a window of few rows is scattered, :func:`_row_sums`)."""
     row_token: jnp.ndarray    # [k*T] int32: source token of each sorted row
     row_entry: jnp.ndarray    # [k*T] int32: its (token*k + choice) pair
     dest: jnp.ndarray         # [T, k] int32: row of each pair (if ``on``)
@@ -351,68 +352,117 @@ def held_plan(choice, first: int, held: int) -> HeldRouting:
                        rows < group_sizes.sum(), group_sizes)
 
 
+def _sums_by_rows(n: int, pairs: int) -> bool:
+    """Whether a window of ``n`` rows brings its rows to the tokens row by
+    row (:func:`_row_sums`) or pair by pair (:func:`_pair_sums`). The pair
+    form writes and reads ``pairs`` rows of which ``n`` at most are held;
+    the row form costs by the row, about a fifth of a microsecond each on a
+    v5e, and is ahead where the window is a sixteenth of the pairs and
+    behind at an eighth (8 choices of 2048-wide rows)."""
+    return 16 * n <= pairs
+
+
+def _pair_sums(rows, gates, plan: HeldRouting):
+    """``y[t] = sum over t's held pairs of gates[t, c] * rows[dest[t, c]]``
+    in float32 (``gates`` None: the rows as they are): a ``[T, k, D]``
+    gather, masked and summed over ``k``."""
+    picked = jnp.where(plan.on[..., None], rows[plan.dest], 0)
+    picked = picked.astype(jnp.float32)
+    if gates is not None:
+        picked = picked * gates[..., None]
+    return picked.sum(1)
+
+
+def _row_sums(rows, gates, plan: HeldRouting):
+    """:func:`_pair_sums` from the rows' side: each filled row weighed by
+    its own pair's gate and added to its token's sum, a scatter-add of ``n``
+    float32 rows (a scatter of all ``k*T`` would serialize sixteen times as
+    many). The order of a token's terms is the scatter's."""
+    rows = rows.astype(jnp.float32)
+    if gates is not None:
+        rows = rows * gates.reshape(-1)[plan.row_entry][:, None]
+    # what lies past the filled rows is undefined in a cotangent
+    rows = jnp.where(plan.valid[:, None], rows, 0)
+    sums = jnp.zeros((plan.on.shape[0], rows.shape[1]), jnp.float32)
+    return sums.at[plan.row_token].add(rows)
+
+
+def _token_sums(rows, gates, plan: HeldRouting):
+    """The window's rows summed into their tokens, [T, D] float32, in the
+    form its shapes give (:func:`_sums_by_rows`)."""
+    by_rows = _sums_by_rows(plan.valid.shape[0], plan.on.size)
+    return (_row_sums if by_rows else _pair_sums)(rows, gates, plan)
+
+
+def _no_tangent(plan: HeldRouting):
+    return jax.tree_util.tree_map(_zero_tan, plan)
+
+
 @jax.custom_vjp
-def _held_rows(x, row_token, dest, on):
+def _held_rows(x, plan: HeldRouting):
     """rows[p] = x[row_token[p]]: a gather. Rows past the filled ones hold
     copies nobody reads."""
-    return x[row_token]
+    return x[plan.row_token]
 
 
-def _held_rows_fwd(x, row_token, dest, on):
-    return x[row_token], (row_token, dest, on)
+def _held_rows_fwd(x, plan):
+    return x[plan.row_token], plan
 
 
-def _held_rows_bwd(res, drows):
+def _held_rows_bwd(plan, drows):
     # dx[t] = sum over t's pairs that are held of drows[dest[t, c]]: the
-    # mirror of the combine's forward, also a gather.
-    row_token, dest, on = res
-    picked = jnp.where(on[..., None], drows[dest], 0)
-    dx = picked.astype(jnp.float32).sum(1).astype(drows.dtype)
-    return dx, _zero_tan(row_token), _zero_tan(dest), _zero_tan(on)
+    # mirror of the combine's forward
+    dx = _token_sums(drows, None, plan).astype(drows.dtype)
+    return dx, _no_tangent(plan)
 
 
 _held_rows.defvjp(_held_rows_fwd, _held_rows_bwd)
 
 
 @jax.custom_vjp
-def _held_sum(out, gates, row_entry, row_token, dest, on, valid):
+def _held_sum(out, gates, plan: HeldRouting):
     """y[t] = sum over t's held pairs of gates[t, c] * out[dest[t, c]], in
     float32."""
-    picked = jnp.where(on[..., None], out[dest], 0).astype(jnp.float32)
-    return (picked * gates[..., None]).sum(1)
+    return _token_sums(out, gates, plan)
 
 
-def _held_sum_fwd(out, gates, row_entry, row_token, dest, on, valid):
-    return _held_sum(out, gates, row_entry, row_token, dest, on, valid), \
-        (out, gates, row_entry, row_token, dest, on, valid)
+def _held_sum_fwd(out, gates, plan):
+    return _held_sum(out, gates, plan), (out, gates, plan)
 
 
 def _held_sum_bwd(res, dy):
-    out, gates, row_entry, row_token, dest, on, valid = res
-    gate_row = jnp.where(valid, gates.reshape(-1)[row_entry], 0)
-    # gathered in the rows' own type: a float32 [k*T, D] copy of dy is the
-    # layer's largest buffer otherwise
-    dout = (dy.astype(out.dtype)[row_token]
-            * gate_row[:, None]).astype(out.dtype)
-    picked = jnp.where(on[..., None], out[dest], 0).astype(jnp.float32)
-    dgates = (picked * dy[:, None, :]).sum(-1)
-    return (dout, dgates, _zero_tan(row_entry), _zero_tan(row_token),
-            _zero_tan(dest), _zero_tan(on), _zero_tan(valid))
+    out, gates, plan = res
+    gate_row = jnp.where(plan.valid, gates.reshape(-1)[plan.row_entry], 0)
+    if plan.valid.shape[0] < plan.on.size:
+        # <out[dest[t, c]], dy[t]> is a number a ROW: the same products in
+        # the same order over D from the rows of dy that dout is made of,
+        # then T*k scalars
+        dy_row = dy[plan.row_token]
+        by_row = (out.astype(jnp.float32) * dy_row).sum(-1)
+        dgates = jnp.where(plan.on, by_row[plan.dest], 0)
+        dy_row = dy_row.astype(out.dtype)
+    else:
+        # gathered in the rows' own type: a float32 [k*T, D] copy of dy is
+        # the layer's largest buffer otherwise
+        dy_row = dy.astype(out.dtype)[plan.row_token]
+        picked = jnp.where(plan.on[..., None], out[plan.dest], 0)
+        dgates = (picked.astype(jnp.float32) * dy[:, None, :]).sum(-1)
+    dout = (dy_row * gate_row[:, None]).astype(out.dtype)
+    return dout, dgates, _no_tangent(plan)
 
 
 _held_sum.defvjp(_held_sum_fwd, _held_sum_bwd)
 
 
 def held_dispatch(x, plan: HeldRouting):
-    """[T, D] tokens -> [k*T, D] rows grouped by held expert."""
-    return _held_rows(x, plan.row_token, plan.dest, plan.on)
+    """[T, D] tokens -> [n, D] rows grouped by held expert."""
+    return _held_rows(x, plan)
 
 
 def held_combine(out, gates, plan: HeldRouting):
-    """[k*T, D] expert outputs -> [T, D] float32 gate-weighted sum over each
+    """[n, D] expert outputs -> [T, D] float32 gate-weighted sum over each
     token's held pairs (zero for a token with none)."""
-    return _held_sum(out, gates, plan.row_entry, plan.row_token, plan.dest,
-                     plan.on, plan.valid)
+    return _held_sum(out, gates, plan)
 
 
 def grouped_swiglu(rows, w1, w3, w2, plan: HeldRouting):
@@ -531,7 +581,7 @@ def _routed_two_plans_bwd(small, res, dy):
     # at 16 experts of 2048 x 768).
     grads = lax.optimization_barrier(_first_window_or_every(
         pull, tuple(args), plan, small, dy, *args))
-    return (*grads, jax.tree_util.tree_map(_zero_tan, plan))
+    return (*grads, _no_tangent(plan))
 
 
 _routed_two_plans.defvjp(_routed_two_plans_fwd, _routed_two_plans_bwd)
@@ -554,6 +604,8 @@ def dropless_share(x, router_logits, bias, w1, w3, w2, *, first: int,
     small = plan_rows(pairs, held, routed)
     _telemetry.inc("hvd_moe_layers_total", held=held, routed=routed,
                    top_k=top_k, plan_rows=small)
+    _telemetry.inc("hvd_moe_token_side_total",
+                   form="rows" if _sums_by_rows(small, pairs) else "pairs")
     with jax.named_scope("hvd_moe_route"):
         choice, gates = sigmoid_topk_gates(router_logits, bias, top_k,
                                            scaling)

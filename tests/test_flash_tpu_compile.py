@@ -1,4 +1,5 @@
-"""The flash forward and the backward's three forms compiled ahead of time
+"""The flash forward and the backward's three forms, and the dropless expert
+layer whose programs are chosen by shape, compiled ahead of time
 for a TPU v5e at the widths the benchmark's cells run. No chip is needed:
 Mosaic and XLA:TPU are installed here and compile for a chip that is
 described, not attached.
@@ -146,3 +147,35 @@ def test_windowed_flash_compiles_for_v5e(one_chip, no_compile_cache, BH, T,
     if window is not None:
         assert len(fa.band_tiles(T // 512, T // 512, 512, 512, window)) \
             == 2 * (T // 512) - 1
+
+
+# T, D, M, k, held -> the window's rows, and the form the token side takes
+@pytest.mark.parametrize("T,D,M,k,held,window,by_rows", [
+    (8192, 3072, 1024, 10, 8, 5120, True),
+    (8192, 2048, 768, 8, 16, 8192, False),
+], ids=["laguna_8192_k10_8_of_256", "joyai_8192_k8_16_of_256"])
+def test_dropless_share_compiles_for_v5e(one_chip, no_compile_cache, T, D, M,
+                                         k, held, window, by_rows):
+    """Both expert cells' layer, forward and backward: where the token side
+    sums row by row the optimized program holds no array of the pairs,
+    ``[T, k, D]`` or flattened; where it sums pair by pair it holds them."""
+    from horovod_tpu.parallel import moe
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def both(x, logits, bias, w1, w3, w2):
+        def loss(x, logits, w1, w3, w2):
+            return (moe.dropless_share(x, logits, bias, w1, w3, w2, first=8,
+                                       top_k=k, scaling=2.5)[0] ** 2).sum()
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(x, logits, w1, w3, w2)
+
+    # the CPU's row tile of 8 and the chip's 512 give the same window here
+    assert moe.plan_rows(T * k, held, 256) == window
+    assert moe._sums_by_rows(window, T * k) == by_rows
+    hlo = jax.jit(both).lower(
+        arg((T, D)), arg((T, 256), jnp.float32), arg((256,), jnp.float32),
+        arg((held, D, M)), arg((held, D, M)), arg((held, M, D))
+    ).compile().as_text()
+    pairs = [f"[{T},{k},{D}]", f"[{T * k},{D}]"]
+    assert any(shape in hlo for shape in pairs) == (not by_rows)

@@ -189,7 +189,7 @@ def test_nothing_is_dropped_when_one_held_expert_gets_most_rows():
 PLAN_T, PLAN_K, PLAN_E, PLAN_D, PLAN_M, PLAN_FIRST = 1024, 4, 16, 16, 8, 6
 
 
-def _plan_case(held, filled=None, biased=0, T=PLAN_T):
+def _plan_case(held, filled=None, biased=0, T=PLAN_T, routed=PLAN_E):
     """Inputs of ``dropless_share`` for ``T`` tokens. ``filled`` pins the
     number of held pairs: the held experts' logits are -6 (never among the
     top of 16) but for that many (token, held expert) pairs at +6 (always
@@ -199,13 +199,13 @@ def _plan_case(held, filled=None, biased=0, T=PLAN_T):
     w1, w3 = (jax.random.normal(kk, (held, PLAN_D, PLAN_M)) * 0.3
               for kk in keys[1:3])
     w2 = jax.random.normal(keys[3], (held, PLAN_M, PLAN_D)) * 0.3
-    logits = jax.random.normal(keys[4], (T, PLAN_E))
+    logits = jax.random.normal(keys[4], (T, routed))
     if filled is not None:
         mine = np.full(T * held, -6.0, np.float32)
         mine[:filled] = 6.0
         logits = logits.at[:, PLAN_FIRST:PLAN_FIRST + held].set(
             mine.reshape(held, T).T)
-    bias = jnp.zeros(PLAN_E).at[PLAN_FIRST:PLAN_FIRST + biased].set(10.0)
+    bias = jnp.zeros(routed).at[PLAN_FIRST:PLAN_FIRST + biased].set(10.0)
     return (x, logits, w1, w3, w2), bias
 
 
@@ -234,21 +234,28 @@ def _eqns(jaxpr, primitive):
             if eqn.primitive.name == primitive]
 
 
-@pytest.mark.parametrize("T, k, held, filled, biased, small, fits", [
-    pytest.param(1024, 4, 2, None, 0, 1024, True, id="small_plan"),
-    pytest.param(1024, 4, 2, None, 1, 1024, False,
+@pytest.mark.parametrize("T, k, held, filled, biased, small, fits, routed", [
+    pytest.param(1024, 4, 2, None, 0, 1024, True, 16, id="small_plan"),
+    pytest.param(1024, 4, 2, None, 1, 1024, False, 16,
                  id="every_token_on_a_held_expert"),
-    pytest.param(1024, 4, 2, 1024, 0, 1024, True, id="filled_is_plan_rows"),
-    pytest.param(1024, 4, 2, 1025, 0, 1024, False, id="one_row_over"),
+    pytest.param(1024, 4, 2, 1024, 0, 1024, True, 16,
+                 id="filled_is_plan_rows"),
+    pytest.param(1024, 4, 2, 1025, 0, 1024, False, 16, id="one_row_over"),
     # 200 pairs, all on held experts, in four windows of 56: the last padded
-    pytest.param(100, 2, 2, None, 2, 56, False,
+    pytest.param(100, 2, 2, None, 2, 56, False, 16,
                  id="every_window_and_a_ragged_last"),
-    pytest.param(1024, 4, 8, None, 0, 4096, True, id="no_second_plan"),
+    pytest.param(1024, 4, 8, None, 0, 4096, True, 16, id="no_second_plan"),
+    # of 64 experts: a window of a sixteenth of the pairs, summed row by row
+    pytest.param(1024, 4, 2, None, 0, 256, True, 64, id="rows_small_plan"),
+    pytest.param(1024, 4, 2, None, 1, 256, False, 64,
+                 id="rows_every_window"),
+    pytest.param(1024, 4, 2, 257, 0, 256, False, 64,
+                 id="rows_one_row_over"),
 ])
 def test_both_plans_give_the_masked_loops_layer_and_drop_nothing(
-        T, k, held, filled, biased, small, fits):
-    args, bias = _plan_case(held, filled, biased, T)
-    assert moe.plan_rows(k * T, held, PLAN_E) == small
+        T, k, held, filled, biased, small, fits, routed):
+    args, bias = _plan_case(held, filled, biased, T, routed)
+    assert moe.plan_rows(k * T, held, routed) == small
 
     def loop(x, logits, w1, w3, w2):
         y, counts = _masked_loop(x, logits, bias, w1, w3, w2, PLAN_FIRST,
@@ -301,6 +308,126 @@ def test_no_branch_holds_an_array_of_the_bounds_rows():
             assert 1024 in rows and PLAN_T * PLAN_K not in rows
         assert len(_eqns(every.jaxpr, "while")) == 1
         assert not _eqns(first.jaxpr, "while")
+
+
+def _pair_arrays(jaxpr, k, width):
+    """The equations with an operand or result ``[., k, width]`` (the index
+    of a gather of ``T * k`` scalars is ``[T, k, 1]``)."""
+    return [eqn for sub in _subjaxprs(jaxpr) for eqn in sub.eqns
+            if any(getattr(v.aval, "shape", ())[1:] == (k, width)
+                   for v in (*eqn.invars, *eqn.outvars))]
+
+
+@pytest.mark.parametrize("held, routed, small, form", [
+    pytest.param(2, 64, 256, "rows", id="a_sixteenth_of_the_pairs"),
+    pytest.param(2, 16, 1024, "pairs", id="a_quarter_of_the_pairs"),
+    pytest.param(8, 16, 4096, "pairs", id="no_second_plan"),
+])
+def test_the_token_side_takes_the_form_the_windows_rows_give(
+        held, routed, small, form):
+    """A window of a sixteenth of the pairs: no equation of either branch,
+    forward or backward, has a ``[T, k, D]`` array. A larger one sums pair
+    by pair and takes the gate gradient a row; with no second plan the
+    program is the one before there were forms: three ``[T, k, D]`` gathers
+    (combine, its gate gradient, the dispatch's transpose). The counter says
+    which, once a traced layer."""
+    from horovod_tpu.core import telemetry as T
+    args, bias = _plan_case(held, routed=routed)
+    assert moe.plan_rows(PLAN_K * PLAN_T, held, routed) == small
+    count = lambda f: T.active().registry.counter_value(
+        "hvd_moe_token_side_total", form=f)
+    before = {f: count(f) for f in ("rows", "pairs")}
+    jaxpr = jax.make_jaxpr(_share_with_grads(bias))(*args).jaxpr
+    assert {f: count(f) - before[f] for f in before} == {
+        "rows": float(form == "rows"), "pairs": float(form == "pairs")}
+    conds = _eqns(jaxpr, "cond")
+    if small == PLAN_K * PLAN_T:
+        pairs = _pair_arrays(jaxpr, PLAN_K, PLAN_D)
+        assert not conds
+        assert [e.primitive.name for e in pairs].count("gather") == 3
+        return
+    assert len(conds) == 2
+    for cond in conds:
+        for branch in cond.params["branches"]:
+            pairs = _pair_arrays(branch.jaxpr, PLAN_K, PLAN_D)
+            assert bool(pairs) == (form == "pairs")
+
+
+def _window_case(name):
+    """A window of a real :func:`moe.held_plan`, the plan and the window's
+    first row."""
+    rng = np.random.RandomState(3)
+
+    def some(T, k, routed):
+        return np.stack([rng.permutation(routed)[:k] for _ in range(T)])
+
+    if name == "one_filled_row":            # held: experts 4 and 5
+        choice = some(16, 2, 4)
+        choice[9, 1] = 5
+        first, held, start, n = 4, 2, 0, 8
+    elif name == "a_token_with_every_held_expert":
+        choice = some(32, 4, 16)
+        choice[7] = [6, 4, 7, 5]
+        first, held, start, n = 4, 4, 0, 40
+    elif name == "cut_inside_a_tokens_rows":
+        # every token on all three held experts: 3 groups of 8 rows, and
+        # rows 5..16 hold the second of three rows of tokens 1..4
+        choice = np.tile(np.array([2, 0, 1]), (8, 1))
+        first, held, start, n = 0, 3, 5, 12
+    elif name == "all_rows_invalid":        # a window past the filled rows
+        choice = some(16, 2, 8)
+        first, held, start, n = 0, 2, 24, 8
+    elif name == "k10_8_held":
+        choice = some(64, 10, 32)
+        choice[5] = [3, *range(8, 16), 20]          # every held expert
+        first, held, start, n = 8, 8, 0, 192
+    elif name == "k8_16_held":
+        choice = some(64, 8, 32)
+        choice[40] = range(24, 32)                  # k held experts
+        first, held, start, n = 16, 16, 64, 256
+    plan = moe.held_plan(jnp.asarray(choice, jnp.int32), first, held)
+    return moe._window(plan, jnp.int32(start), n), plan, start
+
+
+@pytest.mark.parametrize("name, filled, most", [
+    ("one_filled_row", 1, 1), ("a_token_with_every_held_expert", None, 4),
+    ("cut_inside_a_tokens_rows", 12, 2), ("all_rows_invalid", 0, 0),
+    ("k10_8_held", None, 8), ("k8_16_held", None, 8)])
+def test_the_rows_side_gives_the_pair_forms_sums_and_gate_gradient(
+        name, filled, most):
+    """``_row_sums`` against ``_pair_sums`` (``held_combine``'s form before
+    the window had fewer rows than pairs), weighed and plain, and the gate
+    gradient a row against the ``[T, k, D]`` one, over windows of plans."""
+    win, plan, start = _window_case(name)
+    (T, k), n = win.on.shape, win.valid.shape[0]
+    if filled is not None:
+        assert int(win.valid.sum()) == int(win.on.sum()) == filled
+    assert int(win.on.sum(1).max()) == most <= min(k, len(win.group_sizes))
+    if name == "cut_inside_a_tokens_rows":      # rows before, in and after
+        assert all(int(plan.dest[1, c]) - start in range(*r) for c, r in
+                   ((1, (-9, 0)), (2, (0, n)), (0, (n, 99))))
+    keys = jax.random.split(jax.random.PRNGKey(n), 3)
+    out = jax.random.normal(keys[0], (n, 24)).astype(jnp.bfloat16)
+    # what lies past the filled rows is undefined, and nothing may read it
+    out = jnp.where(win.valid[:, None], out, jnp.nan)
+    gates = jax.random.uniform(keys[1], (T, k), minval=0.1)
+    dy = jax.random.normal(keys[2], (T, 24))
+    for g in (gates, None):
+        want = moe._pair_sums(out, g, win)
+        got = jax.jit(moe._row_sums)(out, g, win)
+        assert got.dtype == want.dtype == jnp.float32
+        np.testing.assert_allclose(got, want, rtol=1e-6,
+                                   atol=1e-6 * float(np.abs(want).max()))
+        assert (float(np.abs(want).max()) > 0) == (most > 0)
+    # the gate gradient, a number a row, to the bit; dout as it was
+    dout, dgates, _ = moe._held_sum_bwd((out, gates, win), dy)
+    picked = jnp.where(win.on[..., None], out[win.dest], 0)
+    np.testing.assert_array_equal(
+        dgates, (picked.astype(jnp.float32) * dy[:, None, :]).sum(-1))
+    gate_row = jnp.where(win.valid, gates.reshape(-1)[win.row_entry], 0)
+    np.testing.assert_array_equal(
+        dout, (dy.astype(out.dtype)[win.row_token]
+               * gate_row[:, None]).astype(out.dtype))
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
