@@ -1,0 +1,18 @@
+"""Device time a step under ``hvd_loop_pass`` as the innermost model scope in
+the step program's own map (``chipbench/scopes.py``), forward, backward
+and recomputed together, Mosaic kernels (the library's and XLA's
+ragged-dot kernels) left out: the passes of the shared stack and
+the final norm."""
+from chipbench import scopes
+
+UNIT = "ms"
+LAYER = "model"
+MOVES = "samples_per_s_chip"
+
+
+def applies(cell):
+    return scopes.family(cell, "looped")
+
+
+def read(run):
+    return scopes.of(run).scope_ms["hvd_loop_pass"]

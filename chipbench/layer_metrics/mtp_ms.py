@@ -1,0 +1,19 @@
+"""Device time a step under ``hvd_mtp`` as the innermost model scope in
+the step program's own map (``chipbench/scopes.py``), forward, backward
+and recomputed together, Mosaic kernels (the library's and XLA's
+ragged-dot kernels) left out: the prediction module's own operations
+(its norms, the joining product, its head and loss); its attention and
+expert layer count under their own scopes."""
+from chipbench import scopes
+
+UNIT = "ms"
+LAYER = "model"
+MOVES = "samples_per_s_chip"
+
+
+def applies(cell):
+    return scopes.family(cell, "latent_moe")
+
+
+def read(run):
+    return scopes.of(run).scope_ms["hvd_mtp"]

@@ -24,8 +24,11 @@ formulas of ``benchmarks/collectives.py``)::
 
 plus the ``input_output_alias`` donation map, the layout-changing
 ``copy``/``transpose`` instructions with their shapes, and fusion/line
-counts.  ``analysis/contracts.py`` evaluates every shipped program
-family's invariants against this summary; the legacy dict API
+counts; and, for joining a device profile with the program's scopes,
+:func:`program_map`: every instruction of the optimized module by the name
+a profile gives its event, with its opcode, scope path and fused parts.
+``analysis/contracts.py`` evaluates every shipped program family's
+invariants against the summary; the legacy dict API
 (:func:`collective_wire_costs`) is preserved verbatim for the
 ``tests/wire_accounting.py`` shim.
 """
@@ -352,6 +355,127 @@ def summarize_optimized(hlo_text: str) -> HloSummary:
         layout_moves=tuple(_layout_moves(hlo_text)),
         fusion_count=hlo_text.count("fusion("),
         n_lines=len(hlo_text.splitlines()))
+
+
+# ------------------------------------------- the program's map of itself
+
+class HloInstruction(NamedTuple):
+    """One instruction of an optimized module, as a profile names it."""
+    name: str                     # "fusion.4749": the device event's name
+    opcode: str                   # "fusion", "custom-call", "while", ...
+    # metadata op_name: the scope path, or "". An instruction XLA made
+    # without one inside the body of a while, conditional or call takes
+    # that caller's, as the profiler's ``tf_op`` stat does.
+    op_name: str
+    computation: str              # the computation that holds it
+    umbrella: bool                # its event spans its body's leaf events
+    target: str                   # custom-call: custom_call_target, else ""
+    # fusion: ``(op_name, output bytes)`` of what was fused, one entry a
+    # distinct op_name (parameters, which do no work, left out)
+    parts: Tuple[Tuple[str, int], ...]
+
+
+# ``while``, ``conditional`` and ``call`` run other computations whose
+# instructions have device events of their own; the ``-start``/``-done``
+# halves of an asynchronous operation are leaf events (chipbench/trace.py)
+UMBRELLA_OPCODES = ("while", "conditional", "call")
+
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*\{\s*$")
+_INSTRUCTION_RE = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+")
+_OPCODE_RE = re.compile(r"\s*([a-z][\w\-]*)\(")
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+_BODIES_RE = re.compile(
+    r"\b(?:body|condition|to_apply|true_computation|false_computation)="
+    r"%?([\w.\-]+)|\bbranch_computations=\{([^}]*)\}")
+
+
+def _attribute(line: str, key: str) -> str:
+    """``key="value"`` of an instruction line ("" when absent). Searched by
+    ``str.find``: a Mosaic call's line carries megabytes of kernel body."""
+    i = line.find(key + '="')
+    if i < 0:
+        return ""
+    i += len(key) + 2
+    return line[i:line.find('"', i)]
+
+
+def _skip_shape(line: str, i: int) -> int:
+    """Index just past the result shape that starts at ``line[i]``: a tuple
+    ``(f32[8]{0}, (s32[], ...))`` or one array ``f32[8]{1,0:T(8,128)}``."""
+    if line[i] != "(":
+        return line.find(" ", i)
+    depth = 0
+    for j in range(i, len(line)):
+        if line[j] == "(":
+            depth += 1
+        elif line[j] == ")":
+            depth -= 1
+            if depth == 0:
+                return j + 1
+    return len(line)
+
+
+def program_map(hlo_text: str) -> "dict[str, HloInstruction]":
+    """``{instruction name: HloInstruction}`` over EVERY computation of an
+    optimized module (``fn.lower(...).compile().as_text()``), fused ones
+    too. A device profile names each event after the instruction it ran
+    (``%fusion.4749 = ...``) and instruction names are unique in a module,
+    so this joins a profile with the scopes (``jax.named_scope``) that the
+    program text carries and the profile's event names do not."""
+    found: dict = {}
+    bodies: dict = {}             # computation -> [(opcode, op_name, bytes)]
+    fusions = []                  # (name, fused computation)
+    callers: dict = {}            # computation -> the umbrella that runs it
+    computation = None
+    for line in hlo_text.splitlines():
+        if computation is None:
+            m = _COMPUTATION_RE.match(line)
+            if m:
+                computation = m.group(1)
+                bodies[computation] = []
+            continue
+        if line.startswith("}"):
+            computation = None
+            continue
+        m = _INSTRUCTION_RE.match(line)
+        if not m:
+            continue
+        name = m.group(1)
+        shape_end = _skip_shape(line, m.end())
+        om = _OPCODE_RE.match(line, shape_end) if shape_end > 0 else None
+        if not om:
+            continue
+        opcode = om.group(1)
+        op_name = _attribute(line, "op_name")
+        bodies[computation].append(
+            (opcode, op_name, sum(_hlo_shape_bytes(s) for s in re.findall(
+                _HLO_SHAPE_RE, line[m.end():shape_end]))))
+        if opcode == "fusion":
+            cm = _CALLS_RE.search(line, om.end())
+            if cm:
+                fusions.append((name, cm.group(1)))
+        elif opcode in UMBRELLA_OPCODES:
+            for one, several in _BODIES_RE.findall(line, om.end()):
+                for body in [one] if one else several.split(","):
+                    callers.setdefault(body.strip().lstrip("%"), name)
+        found.setdefault(name, HloInstruction(
+            name, opcode, op_name, computation,
+            opcode in UMBRELLA_OPCODES,
+            _attribute(line, "custom_call_target")
+            if opcode == "custom-call" else "", ()))
+    for name, fused in fusions:
+        per: dict = {}
+        for opcode, op_name, nbytes in bodies.get(fused, ()):
+            if opcode != "parameter":
+                per[op_name] = per.get(op_name, 0) + nbytes
+        found[name] = found[name]._replace(parts=tuple(per.items()))
+    for name, inst in found.items():
+        caller = inst
+        while not caller.op_name and caller.computation in callers:
+            caller = found[callers[caller.computation]]
+        if caller.op_name and not inst.op_name:
+            found[name] = inst._replace(op_name=caller.op_name)
+    return found
 
 
 def summarize(hlo_text: str,

@@ -19,6 +19,11 @@ dies.  This module is the TPU rebuild's single telemetry surface:
   ``hvd_span_seconds{name=}`` histogram, and a ``TraceAnnotation`` so the
   span sits in a running profiler session on the device trace's clock
   (docs/telemetry.md lists every span the library emits);
+* :func:`program` — the step program's map of itself: every instruction
+  of the optimized module by the name a device profile gives its event,
+  with its opcode, its ``hvd_*`` scope path and its fused parts, made on
+  request from the signature the step kept at its first call
+  (docs/profiling.md, "read your own profile by scope");
 * Prometheus text rendering (served by the coordinator at
   ``GET /metrics``) and a compact cumulative-delta export pushed to the
   coordinator piggybacked on the existing poll cadence;
@@ -40,7 +45,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax.monitoring
 from jax.profiler import TraceAnnotation
@@ -332,6 +337,8 @@ class Telemetry:
         self.ring = FlightRecorder(ring_size)
         # (name, seq, t0_ns, t1_ns, thread_id); append is atomic, no lock
         self.span_log: deque = deque(maxlen=SPAN_LOG)
+        # what -> {"lower", "signature" of the first call, "program" once made}
+        self.programs: Dict[str, Dict[str, Any]] = {}
         self._dump_lock = threading.Lock()
 
 
@@ -532,6 +539,74 @@ def install_compile_listener() -> None:
             return
         _compile_listener_installed = True
     jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+# -- the step program's map of itself -------------------------------------
+
+class Program(NamedTuple):
+    """What :func:`program` returns."""
+    what: str
+    instructions: Dict[str, Any]  # name -> analysis.hlo.HloInstruction
+    map_seconds: float            # lower + compile (both cached) + parse
+    compiled: bool                # making it lowered or compiled anew
+
+
+def _compile_events(t: Telemetry) -> Tuple[float, float, float]:
+    """Programs lowered and programs handed to the backend so far, as the
+    compile listener counted them."""
+    c = t.registry.export()["c"]
+    return (c.get("hvd_compiles_total", 0.0),
+            c.get('hvd_span_seconds_count{name="compile.lower"}', 0.0),
+            c.get('hvd_span_seconds_count{name="compile.backend"}', 0.0))
+
+
+def register_program(what: str, lower: Any, signature: Tuple) -> None:
+    """Keep the step kind ``what``'s AOT handle and the abstract signature
+    ``(args, kwargs)`` of the call that compiled it (the step's first): all
+    :func:`program` needs, later and off the step's path. A later step of
+    the same kind replaces it. Nothing is kept with telemetry off."""
+    t = active()
+    if t.enabled:
+        t.programs[what] = {"lower": lower, "signature": signature,
+                            "program": None}
+
+
+def program(what: str = "train_step") -> Program:
+    """The optimized step program as a map ``{instruction name:
+    (opcode, op_name scope path, umbrella, custom-call target, fused
+    parts)}`` (``analysis.hlo.program_map``), made on the first request and
+    cached. Lowering and compiling again with the signature of the call
+    that ran are in-memory cache hits, so this compiles nothing; whether
+    that held is ``compiled`` here and on the flight-recorder event
+    ``program``. ``LookupError`` where no signature was kept."""
+    t = active()
+    kept = t.programs.get(what)
+    if kept is None:
+        raise LookupError(
+            "telemetry is off (%s=0): no step signature is kept" % ENABLE_ENV
+            if not t.enabled else
+            "no %r step has made its first call in this process (kept: %s)"
+            % (what, sorted(t.programs) or "none"))
+    if kept["program"] is None:
+        from ..analysis import hlo
+        install_compile_listener()
+        args, kwargs = kept["signature"]
+        before = _compile_events(t)
+        t0 = time.perf_counter()
+        instructions = hlo.program_map(
+            kept["lower"](*args, **kwargs).compile().as_text())
+        seconds = time.perf_counter() - t0
+        compiled = _compile_events(t) != before
+        kept["program"] = Program(what, instructions, seconds, compiled)
+        every = instructions.values()
+        t.ring.record(
+            "program", what=what, instructions=len(instructions),
+            fusions=sum(i.opcode == "fusion" for i in every),
+            umbrellas=sum(i.umbrella for i in every),
+            scoped_share=round(sum("hvd_" in i.op_name for i in every)
+                               / max(1, len(instructions)), 4),
+            map_seconds=round(seconds, 6), compiled=compiled)
+    return kept["program"]
 
 
 def export_delta() -> Optional[Dict[str, Dict[str, float]]]:

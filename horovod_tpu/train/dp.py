@@ -260,7 +260,8 @@ def make_train_step(model, optimizer: optax.GradientTransformation,
     dispatch = make_dispatch(programs, sentinel=sentinel,
                              scan_steps=scan_steps)
 
-    marked = instrument_dispatch(dispatch, what="train_step")
+    marked = instrument_dispatch(dispatch, what="train_step",
+                                 lower=jitted.lower)
     marked.lower = jitted.lower  # keep AOT introspection available
     if sentinel is not None:
         marked.lower_probe = programs["probe"].lower

@@ -231,7 +231,7 @@ def _build_gspmd_step(model, mesh, rules, *, optimizer=None, pair=None,
         return lower
 
     lower = _mesh_lower(programs["apply"])
-    run = instrument_dispatch(in_mesh, what="gspmd_train_step")
+    run = instrument_dispatch(in_mesh, what="gspmd_train_step", lower=lower)
     run.lower = lower
     if sentinel is not None:
         run.lower_probe = _mesh_lower(programs["probe"])

@@ -168,12 +168,30 @@ def make_dispatch(programs: Dict[str, Any], *, sentinel=None,
     return dispatch
 
 
-def instrument_dispatch(dispatch: Callable, *, what: str) -> Callable:
+def _abstract(x):
+    """One argument leaf as the shape, dtype and sharding jit keyed its
+    compilation on. An uncommitted array is wherever jit puts it: its
+    sharding is left out, as jit leaves it out of the key."""
+    if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None)
+    aval = jax.typeof(x)
+    return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                weak_type=aval.weak_type)
+
+
+def instrument_dispatch(dispatch: Callable, *, what: str,
+                        lower: Optional[Callable] = None) -> Callable:
     """The host-side boundary of every step kind (DP, pipeline, GSPMD):
     one ``step.dispatch`` span round the call of ``dispatch`` with the
     call's ordinal as ``seq``, ``hvd_dispatches_total``, and the
     reference's MARK_CYCLES timeline record (``<WHAT>/DISPATCH`` activity
     + cycle marker; device phases live in the jax.profiler xplane).
+
+    The first call, with telemetry on, also keeps the abstract signature of
+    its arguments beside ``lower`` (the apply program's AOT handle): what
+    ``telemetry.program(what)`` needs to read the optimized program back
+    without compiling it again. No later call keeps or walks anything.
 
     The timeline is read PER CALL so start_timeline/stop_timeline work in
     any order relative to building the step, and a closed timeline is
@@ -187,6 +205,9 @@ def instrument_dispatch(dispatch: Callable, *, what: str) -> Callable:
     def run(*args, **kwargs):
         seq = calls[0]
         calls[0] = seq + 1
+        if seq == 0 and lower is not None and _telemetry.enabled():
+            _telemetry.register_program(what, lower, jax.tree_util.tree_map(
+                _abstract, (args, kwargs)))
         _telemetry.inc_series("hvd_dispatches_total", counted)
         tl = _ctx.context().timeline if _ctx.is_initialized() else None
         if tl is not None and getattr(tl, "_closed", False):
@@ -384,7 +405,8 @@ def make_pipeline_train_step(stage_fn: Callable, loss_fn: Callable,
                                  pair=pair)
     dispatch = make_dispatch(programs,
                              every=pair.every if pair is not None else 1)
-    run = instrument_dispatch(dispatch, what="pipeline_train_step")
+    run = instrument_dispatch(dispatch, what="pipeline_train_step",
+                              lower=programs["apply"].lower)
     run.lower = programs["apply"].lower
     if pair is not None:
         run.lower_apply = programs["apply"].lower
