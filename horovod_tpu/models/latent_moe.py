@@ -43,9 +43,10 @@ import jax
 import jax.numpy as jnp
 
 from ..collectives.ops import effective_axis_size
-from ..parallel.moe import balance_bias, dropless_share
+from ..core import telemetry as _telemetry
+from ..parallel.moe import ROUTE, balance_bias, dropless_share
 from ._flash import resolve_flash as _resolve_flash
-from .llama import RMSNorm, _part, _remat
+from .llama import RMSNorm, _part, _remat, remat_saves
 
 
 @dataclasses.dataclass(frozen=True)
@@ -207,6 +208,11 @@ class ExpertLayer(nn.Module):
             tokens, logits, bias.value, w1.astype(c.dtype),
             w3.astype(c.dtype), w2.astype(c.dtype), first=c.first_expert,
             top_k=c.num_experts_per_tok, scaling=c.routed_scaling_factor)
+        # trace-time, one a traced layer: whether the backward gets the
+        # forward's route or makes it again (the block's remat policy)
+        saved = not c.remat or remat_saves(c.remat_policy, ROUTE)
+        _telemetry.inc("hvd_moe_route_total", placement="count",
+                       saved=str(saved).lower())
         if self.train and self.is_mutable_collection("batch_stats"):
             if c.axis_name is not None \
                     and effective_axis_size(c.axis_name) != 1:

@@ -28,6 +28,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from flax.linen import partitioning as nn_partitioning
+from ..parallel.moe import ROUTE
 from ._flash import resolve_flash as _resolve_flash
 
 # Logical → mesh axis rules (see parallel/mesh.py for axis vocabulary).
@@ -122,25 +123,40 @@ def llama_tiny(vocab: int = 256) -> LlamaConfig:
                        dtype=jnp.float32, remat=False, scan_layers=False)
 
 
+# What each policy saves by name (``checkpoint_name``) besides what its rule
+# saves: the flash-attention kernel outputs (ops/flash_attention.py
+# ::_fa_fwd_impl) and an expert layer's route (parallel/moe.py ROUTE).
+_FLASH_OUT = ("attn_out", "attn_lse_m", "attn_lse_l")
+_NAMES_SAVED = {"full": (), "dots": (), "dots_attn": (*_FLASH_OUT, ROUTE),
+                "attn": _FLASH_OUT}
+
 _REMAT_POLICIES = {
     "full": None,
     "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-    # "dots" + the flash-attention kernel outputs (named in
-    # ops/flash_attention.py::_fa_fwd_impl): saving (o, m, l) hands the
-    # flash custom-vjp its residuals directly, so the backward runs ONLY
-    # the dedicated bwd kernels — no fwd-kernel re-run inside the remat
-    # block. Costs [B,T,H,D] bf16 + 2x[B,H,T] f32 per layer.
+    # "dots" + the flash-attention kernel outputs: saving (o, m, l) hands
+    # the flash custom-vjp its residuals directly, so the backward runs
+    # ONLY the dedicated bwd kernels — no fwd-kernel re-run inside the
+    # remat block. Costs [B,T,H,D] bf16 + 2x[B,H,T] f32 per layer. An
+    # expert layer's route (ints, bools and float32 [T, k], about 2 MB a
+    # layer at 8,192 tokens and 10 choices) is kept too, so the backward
+    # neither chooses nor places again.
     "dots_attn": jax.checkpoint_policies.save_from_both_policies(
         jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         jax.checkpoint_policies.save_only_these_names(
-            "attn_out", "attn_lse_m", "attn_lse_l")),
+            *_NAMES_SAVED["dots_attn"])),
     # Save ONLY the flash outputs: everything else recomputes as under
     # "full", but the backward skips the fwd-kernel re-run — the +HBM is
     # just the kernel residuals, so it composes with the HBM-bound batch
     # that made "full" win over "dots" in the first place.
     "attn": jax.checkpoint_policies.save_only_these_names(
-        "attn_out", "attn_lse_m", "attn_lse_l"),
+        *_NAMES_SAVED["attn"]),
 }
+
+
+def remat_saves(policy_name: str, name: str) -> bool:
+    """Whether the values named ``name`` outlive the forward pass of a
+    block rematerialised under ``policy_name``."""
+    return name in _NAMES_SAVED[policy_name]
 
 
 def _remat(cls, policy_name: str, prevent_cse: bool = False):

@@ -139,7 +139,8 @@ class WindowMoEConfig:
     @property
     def expert_layer(self) -> LatentMoEConfig:
         """An expert layer, in ``models/latent_moe.py``'s names: no
-        selection bias (its rate is 0, so the zero it starts from stays)."""
+        selection bias (its rate is 0, so the zero it starts from stays),
+        and the rematerialisation of the block it lies in."""
         return LatentMoEConfig(
             hidden_size=self.hidden_size,
             moe_intermediate_size=self.moe_intermediate_size,
@@ -149,8 +150,8 @@ class WindowMoEConfig:
             num_experts_per_tok=self.num_experts_per_tok,
             routed_scaling_factor=self.moe_routed_scaling_factor,
             experts_held=self.experts_held, first_expert=self.first_expert,
-            bias_update_rate=0.0, dtype=self.dtype,
-            axis_name=self.axis_name)
+            bias_update_rate=0.0, dtype=self.dtype, remat=self.remat,
+            remat_policy=self.remat_policy, axis_name=self.axis_name)
 
 
 def window_moe_tiny(vocab: int = 128, **over) -> WindowMoEConfig:

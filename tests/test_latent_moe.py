@@ -430,6 +430,77 @@ def test_the_rows_side_gives_the_pair_forms_sums_and_gate_gradient(
                * gate_row[:, None]).astype(out.dtype))
 
 
+def _sorted_plan(choice, first, held):
+    """The plan as a sort makes all of it: the stable sort of the pairs by
+    expert, its permutation turned round by a scatter for each pair's row,
+    the experts' rows by a one-hot sum."""
+    T, k = choice.shape
+    local = choice - first
+    on = (local >= 0) & (local < held)
+    key = jnp.where(on, local, held).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    group_sizes = jnp.sum(
+        jax.nn.one_hot(key, held + 1, dtype=jnp.int32), axis=0)[:held]
+    rows = jnp.arange(k * T, dtype=jnp.int32)
+    dest = jnp.zeros_like(order).at[order].set(rows).reshape(T, k)
+    return moe.HeldRouting(order // k, order, dest, on,
+                           rows < group_sizes.sum(), group_sizes)
+
+
+def _gathered_gates(logits, bias, top_k, scaling):
+    """``sigmoid_topk_gates`` with the chosen scores gathered."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, choice = jax.lax.top_k(scores + bias, top_k)
+    picked = jnp.take_along_axis(scores, choice, axis=-1)
+    return choice, scaling * picked / picked.sum(-1, keepdims=True)
+
+
+@pytest.mark.parametrize("T, k, held, routed, case", [
+    pytest.param(512, 10, 8, 256, "router", id="8_of_256_at_k10"),
+    pytest.param(512, 8, 16, 256, "router", id="16_of_256_at_k8"),
+    pytest.param(256, 4, 8, 64, "one_expert", id="every_pair_on_one_expert"),
+    pytest.param(256, 4, 8, 64, "none", id="no_pair_held"),
+    pytest.param(512, 10, 8, 256, "crowded", id="filled_past_plan_rows"),
+])
+def test_the_counted_plan_is_the_sorted_plan(T, k, held, routed, case):
+    """Every field of :func:`moe.held_plan` equals the sorted plan's, bit
+    for bit and type for type; from a router's choices the choice, the gates
+    and the gates' gradient to the logits equal those of gathered scores."""
+    first = 8
+    logits = jax.random.normal(jax.random.PRNGKey(T + k + held), (T, routed))
+    bias = jnp.zeros(routed)
+    if case == "crowded":       # every token on the held experts: windows
+        bias = bias.at[first:first + held].set(10.0)
+    choice, gates = jax.jit(moe.sigmoid_topk_gates, static_argnums=(2, 3))(
+        logits, bias, k, 2.5)
+    if case == "one_expert":
+        choice = jnp.full((T, k), first + 3, jnp.int32)
+    elif case == "none":
+        choice = jnp.asarray(np.random.RandomState(0).randint(
+            first + held, routed, (T, k)), jnp.int32)
+    else:
+        want_choice, want_gates = jax.jit(
+            _gathered_gates, static_argnums=(2, 3))(logits, bias, k, 2.5)
+        np.testing.assert_array_equal(choice, want_choice)
+        np.testing.assert_array_equal(gates, want_gates)
+
+        def pull(gates_of):
+            return jax.grad(lambda lg: (gates_of(lg, bias, k, 2.5)[1]
+                                        * jnp.arange(k)).sum())(logits)
+        np.testing.assert_array_equal(pull(moe.sigmoid_topk_gates),
+                                      pull(_gathered_gates))
+    got = jax.jit(moe.held_plan, static_argnums=(1, 2))(choice, first, held)
+    want = _sorted_plan(choice, first, held)
+    for name in moe.HeldRouting._fields:
+        a, b = getattr(got, name), getattr(want, name)
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    filled = int(got.group_sizes.sum())
+    assert filled == {"one_expert": k * T, "none": 0}.get(case, filled)
+    if case == "crowded":
+        assert filled > moe.plan_rows(k * T, held, routed)
+
+
 def test_the_shares_add_up_to_the_uncut_layer():
     """8 experts as 4 shares of 2: the routed parts that the shares give,
     with the shared expert counted once, are the whole layer's output as

@@ -29,6 +29,7 @@ from horovod_tpu.models.window_moe import (FULL, SLIDING, GatedAttention,
 from horovod_tpu.optimizer import distributed
 from horovod_tpu.parallel import moe
 from horovod_tpu.train import create_train_state, make_train_step
+from test_latent_moe import _eqns
 
 OPTIMIZER = {"name": "adamw", "learning_rate": 1e-3, "b1": 0.9, "b2": 0.95,
              "eps": 1e-8, "weight_decay": 0.1}
@@ -332,6 +333,51 @@ def test_the_expert_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose((routed + shared).reshape(32, -1), want,
                                atol=1e-5, rtol=1e-5)
     assert float(jnp.abs(routed).max()) > 1e-2
+
+
+def test_the_route_is_made_once_under_dots_attn():
+    """Two expert layers under the policy that keeps the route: the
+    differentiated loss chooses once a layer (one ``top_k``, one sort of
+    the k*T pairs, no integer scatter: the pairs' rows are counted), the
+    gradients are those of a policy that makes the route again in the
+    backward, and the counter says so once a traced layer."""
+    from horovod_tpu.core import telemetry as T
+    two = dict(layer_types=(FULL, SLIDING, FULL),
+               mlp_layer_types=("dense", "sparse", "sparse"),
+               num_attention_heads_per_layer=(8, 12, 8))
+    tokens, labels = _tokens(5, 3, 16)
+    pairs = 3 * 16 * 3
+    route = lambda saved: T.active().registry.counter_value(
+        "hvd_moe_route_total", placement="count", saved=saved)
+    found = {}
+    for policy in ("dots_attn", "dots"):
+        c = window_moe_tiny(remat=True, remat_policy=policy, **two)
+        variables = _init(c, tokens)
+        before = {s: route(s) for s in ("true", "false")}
+        jaxpr = jax.make_jaxpr(lambda p: _system_loss(
+            c, {**variables, "params": p}, tokens, labels))(
+                variables["params"]).jaxpr
+        found[policy] = (
+            {s: route(s) - before[s] for s in before},
+            len(_eqns(jaxpr, "top_k")),
+            [eqn.invars[0].aval.shape for eqn in _eqns(jaxpr, "sort")],
+            len(_eqns(jaxpr, "scatter")),
+            _system_loss(c, variables, tokens, labels))
+    saved, top_k, sorts, scatters, ((loss, _), grads) = found["dots_attn"]
+    assert saved == {"true": 2.0, "false": 0.0}
+    assert (top_k, sorts, scatters) == (2, [(pairs,)] * 2, 0)
+    made_again, top_k, sorts, _, ((want, _), want_grads) = found["dots"]
+    assert made_again == {"true": 0.0, "false": 2.0}
+    assert (top_k, sorts) == (4, [(pairs,)] * 4)
+    np.testing.assert_allclose(loss, want, rtol=1e-7)
+    got, want_grads = named_leaves(grads), named_leaves(want_grads)
+    assert set(got) == set(want_grads)
+    for name, w in want_grads.items():
+        np.testing.assert_allclose(got[name], w, rtol=1e-6,
+                                   atol=1e-7 * float(np.abs(w).max()),
+                                   err_msg=name)
+    router = [n for n in got if n.endswith("router/kernel")]
+    assert len(router) == 2 and all(np.abs(got[n]).max() > 0 for n in router)
 
 
 def test_yarns_32_frequencies_are_the_published_schemes():
