@@ -179,7 +179,8 @@ class SwiGLU(nn.Module):
 
 
 class ExpertLayer(nn.Module):
-    """This rank's share of the routed experts plus the shared expert."""
+    """This rank's share of the routed experts plus the shared expert
+    (none, and no ``shared`` parameters, at ``n_shared_experts == 0``)."""
 
     cfg: LatentMoEConfig
     train: bool = False
@@ -220,6 +221,8 @@ class ExpertLayer(nn.Module):
             bias.value = balance_bias(bias.value, counts,
                                       c.bias_update_rate)
             seen.value, rows.value = counts, filled
+        if not c.n_shared_experts:
+            return y.astype(c.dtype).reshape(B, T, D)
         with jax.named_scope("hvd_moe_shared"):
             shared = SwiGLU(c, c.n_shared_experts * M, name="shared")(x)
         return y.astype(c.dtype).reshape(B, T, D) + shared

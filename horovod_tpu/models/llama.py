@@ -96,6 +96,9 @@ class LlamaConfig:
     # (parallel/ulysses.py; needs n_heads % sp == 0). Both engage only
     # when the ambient mesh has an "sp" axis of size > 1.
     attention_impl: "str | None" = None
+    # RMSNorm over each head's depths of q and k before the rotary
+    # embedding (LFM2's q_layernorm / k_layernorm); off: the Llama form.
+    qk_norm: bool = False
 
 
 #: ``scan_layers="auto"`` unrolls at or below this layer count. 8 unrolled
@@ -261,6 +264,9 @@ class Attention(nn.Module):
         q = q.reshape(B, T, c.n_heads, head_dim)
         k = k.reshape(B, T, c.n_kv_heads, head_dim)
         v = v.reshape(B, T, c.n_kv_heads, head_dim)
+        if c.qk_norm:
+            q = RMSNorm(c.norm_eps, c.dtype, name="q_norm")(q)
+            k = RMSNorm(c.norm_eps, c.dtype, name="k_norm")(k)
         q = nn_partitioning.with_sharding_constraint(
             q, ("batch", "seq", "heads", "head_dim"))
         q = rope(q, positions, c.rope_theta)
