@@ -44,7 +44,7 @@ import jax.numpy as jnp
 
 from ..collectives.ops import effective_axis_size
 from ..core import telemetry as _telemetry
-from ..parallel.moe import ROUTE, balance_bias, dropless_share
+from ..parallel.moe import PRODUCTS, ROUTE, balance_bias, dropless_share
 from ._flash import resolve_flash as _resolve_flash
 from .llama import RMSNorm, _part, _remat, remat_saves
 
@@ -205,15 +205,17 @@ class ExpertLayer(nn.Module):
         bias = self.variable("batch_stats", "bias", jnp.zeros, (E,))
         seen = self.variable("batch_stats", "counts", jnp.zeros, (E,))
         rows = self.variable("batch_stats", "rows", jnp.zeros, (held,))
+        # whether the backward gets what the forward made or makes it
+        # again: the block's remat policy
+        saved = lambda name: not c.remat or remat_saves(c.remat_policy, name)
         y, counts, filled = dropless_share(
             tokens, logits, bias.value, w1.astype(c.dtype),
             w3.astype(c.dtype), w2.astype(c.dtype), first=c.first_expert,
-            top_k=c.num_experts_per_tok, scaling=c.routed_scaling_factor)
-        # trace-time, one a traced layer: whether the backward gets the
-        # forward's route or makes it again (the block's remat policy)
-        saved = not c.remat or remat_saves(c.remat_policy, ROUTE)
+            top_k=c.num_experts_per_tok, scaling=c.routed_scaling_factor,
+            keep_products=saved(PRODUCTS))
+        # trace-time, one a traced layer
         _telemetry.inc("hvd_moe_route_total", placement="count",
-                       saved=str(saved).lower())
+                       saved=str(saved(ROUTE)).lower())
         if self.train and self.is_mutable_collection("batch_stats"):
             if c.axis_name is not None \
                     and effective_axis_size(c.axis_name) != 1:

@@ -28,7 +28,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from flax.linen import partitioning as nn_partitioning
-from ..parallel.moe import ROUTE
+from ..parallel.moe import PRODUCTS, ROUTE
 from ._flash import resolve_flash as _resolve_flash
 
 # Logical → mesh axis rules (see parallel/mesh.py for axis vocabulary).
@@ -128,9 +128,11 @@ def llama_tiny(vocab: int = 256) -> LlamaConfig:
 
 # What each policy saves by name (``checkpoint_name``) besides what its rule
 # saves: the flash-attention kernel outputs (ops/flash_attention.py
-# ::_fa_fwd_impl) and an expert layer's route (parallel/moe.py ROUTE).
+# ::_fa_fwd_impl) and an expert layer's route and grouped products
+# (parallel/moe.py ROUTE, PRODUCTS).
 _FLASH_OUT = ("attn_out", "attn_lse_m", "attn_lse_l")
-_NAMES_SAVED = {"full": (), "dots": (), "dots_attn": (*_FLASH_OUT, ROUTE),
+_NAMES_SAVED = {"full": (), "dots": (),
+                "dots_attn": (*_FLASH_OUT, ROUTE, PRODUCTS),
                 "attn": _FLASH_OUT}
 
 _REMAT_POLICIES = {
@@ -142,7 +144,8 @@ _REMAT_POLICIES = {
     # remat block. Costs [B,T,H,D] bf16 + 2x[B,H,T] f32 per layer. An
     # expert layer's route (ints, bools and float32 [T, k], about 2 MB a
     # layer at 8,192 tokens and 10 choices) is kept too, so the backward
-    # neither chooses nor places again.
+    # neither chooses nor places again, and so are its small plan's rows
+    # and grouped products, so that it runs none of them forward again.
     "dots_attn": jax.checkpoint_policies.save_from_both_policies(
         jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         jax.checkpoint_policies.save_only_these_names(

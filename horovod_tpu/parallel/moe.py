@@ -311,6 +311,11 @@ def routed_experts(x, router_logits, expert_fn: Callable, *,
 # policy that saves it (``models/llama.py`` "dots_attn") hands the backward
 # the forward's route.
 ROUTE = "moe_route"
+# The name of the small plan's rows and its three grouped products
+# (``a = rows w1``, ``b = rows w3``, ``out = silu(a) b w2``). A policy that
+# saves it ("dots_attn") hands them to the window's transpose, which then
+# runs no product forward again.
+PRODUCTS = "moe_products"
 
 
 class HeldRouting(NamedTuple):
@@ -522,11 +527,26 @@ def grouped_swiglu(rows, w1, w3, w2, plan: HeldRouting):
     [held, D, M], ``w2`` [held, M, D], already in the compute type. What a
     grouped product leaves in the rows past the filled ones is undefined,
     so they are zeroed before anything else reads them."""
-    dot = _partial(lax.ragged_dot, group_sizes=plan.group_sizes,
-                   preferred_element_type=rows.dtype)
+    return _expert_products(rows, w1, w3, w2, plan)[-1]
+
+
+def _grouped_dot(plan: HeldRouting, dtype):
+    return _partial(lax.ragged_dot, group_sizes=plan.group_sizes,
+                    preferred_element_type=dtype)
+
+
+def _gated(a, b, filled):
+    return jnp.where(filled, jax.nn.silu(a) * b, 0)
+
+
+def _expert_products(rows, w1, w3, w2, plan: HeldRouting):
+    """:func:`grouped_swiglu` and the products it is made of: ``(a = rows
+    w1, b = rows w3, out = silu(a) b w2)`` by expert."""
+    dot = _grouped_dot(plan, rows.dtype)
     filled = plan.valid[:, None]
-    h = jnp.where(filled, jax.nn.silu(dot(rows, w1)) * dot(rows, w3), 0)
-    return jnp.where(filled, dot(h.astype(rows.dtype), w2), 0)
+    a, b = dot(rows, w1), dot(rows, w3)
+    h = _gated(a, b, filled)
+    return a, b, jnp.where(filled, dot(h.astype(rows.dtype), w2), 0)
 
 
 def plan_rows(pairs: int, held: int, routed: int) -> int:
@@ -552,86 +572,171 @@ def _window(plan: HeldRouting, start, n: int) -> HeldRouting:
         cut(ends) - cut(ends - plan.group_sizes))
 
 
-def _routed_rows(x, gates, w1, w3, w2, plan: HeldRouting):
-    """Dispatch, experts and combine over the plan's rows: [T, D] float32."""
+def _routed_rows(x, gates, w1, w3, w2, plan: HeldRouting,
+                 kept: bool = False):
+    """Dispatch, experts and combine over the plan's rows: [T, D] float32;
+    with ``kept``, ``(y, (rows, a, b, out))``, the rows and grouped
+    products that :func:`_window_grads` reads."""
     with jax.named_scope("hvd_moe_dispatch"):
         rows = held_dispatch(x, plan)
     with jax.named_scope("hvd_moe_experts"):
-        out = grouped_swiglu(rows, w1, w3, w2, plan)
+        a, b, out = _expert_products(rows, w1, w3, w2, plan)
     with jax.named_scope("hvd_moe_combine"):
-        return held_combine(out, gates, plan)
+        y = held_combine(out, gates, plan)
+    return (y, (rows, a, b, out)) if kept else y
 
 
-@_partial(jax.jit, static_argnums=(0,))
-def _routed_window(n: int, start, plan: HeldRouting, x, gates, w1, w3, w2):
+@_partial(jax.jit, static_argnums=(0, 1))
+def _routed_window(n: int, kept: bool, start, plan: HeldRouting, x, gates,
+                   w1, w3, w2):
     """:func:`_routed_rows` over rows ``[start, start + n)`` of the plan.
     Jitted so that both branches, forward and backward, of every layer of
     one shape are traced and lowered once."""
-    return _routed_rows(x, gates, w1, w3, w2, _window(plan, start, n))
+    return _routed_rows(x, gates, w1, w3, w2, _window(plan, start, n), kept)
+
+
+def _transposed(linear, like, ct):
+    return jax.linear_transpose(linear, like)(ct)[0]
+
+
+@_partial(jax.jit, static_argnums=(0,))
+def _window_grads(n: int, plan: HeldRouting, kept, dy, gates, w1, w3, w2):
+    """The transpose of :func:`_routed_window` over the first ``n`` rows
+    from the rows and products its forward kept (``(rows, a, b, out)``):
+    the combine's, the six grouped products' own transposes and the
+    dispatch's, as differentiation would make them, with no product made
+    again. Keeping the rows rather than gathering them again read 0.68%
+    more samples a second in the conv cell on a v5e, for 0.43 GB. Returns
+    the gradients of ``(x, gates, w1, w3, w2)``."""
+    plan = _window(plan, jnp.int32(0), n)
+    rows, a, b, out = kept
+    with jax.named_scope("hvd_moe_combine"):
+        dout, dgates, _ = _held_sum_bwd((out, gates, plan), dy)
+    with jax.named_scope("hvd_moe_experts"):
+        dot = _grouped_dot(plan, rows.dtype)
+        filled = plan.valid[:, None]
+        dout = jnp.where(filled, dout, 0)
+        h, gated_vjp = jax.vjp(_partial(_gated, filled=filled), a, b)
+        dw2 = _transposed(lambda w: dot(h, w), w2, dout)
+        da, db = gated_vjp(_transposed(lambda h: dot(h, w2), h, dout))
+        dw1 = _transposed(lambda w: dot(rows, w), w1, da)
+        dw3 = _transposed(lambda w: dot(rows, w), w3, db)
+        drows = _transposed(lambda r: dot(r, w1), rows, da) \
+            + _transposed(lambda r: dot(r, w3), rows, db)
+    with jax.named_scope("hvd_moe_dispatch"):
+        dx, _ = _held_rows_bwd(plan, drows)
+    return dx, dgates, dw1, dw3, dw2
+
+
+def _fits(plan: HeldRouting, small: int):
+    """Whether the filled rows fit the first window of ``small``."""
+    return plan.group_sizes.sum() <= small
+
+
+def _every_window(window, like, small: int, plan: HeldRouting, *operands):
+    """``window(start, plan, *operands)`` summed in float32 over as many
+    windows of ``small`` rows as are filled, so that no array of the
+    program has the bound's rows. ``like`` has the result's shapes and
+    types."""
+    pad = -plan.valid.shape[0] % small      # whole windows
+    if pad:
+        plan = plan._replace(**{
+            name: jnp.pad(getattr(plan, name), (0, pad))
+            for name in ("row_token", "row_entry", "valid")})
+    total = lax.fori_loop(
+        0, (plan.group_sizes.sum() + small - 1) // small,
+        lambda w, total: jax.tree_util.tree_map(
+            lambda t, part: t + part.astype(t.dtype), total,
+            window(w * small, plan, *operands)),
+        jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape, jnp.float32), like))
+    return jax.tree_util.tree_map(lambda t, a: t.astype(a.dtype),
+                                  total, like)
 
 
 def _first_window_or_every(window, like, plan: HeldRouting, small: int,
                            *operands):
     """``window(start, plan, *operands)`` over the first ``small`` rows when
-    the filled ones fit there; when they do not, summed in float32 over as
-    many windows of ``small`` rows as are filled, so that no array of the
-    program has the bound's rows. ``like`` has the result's shapes and
-    types."""
+    the filled ones fit there, else by :func:`_every_window`."""
     def first(plan, *operands):
         return window(jnp.int32(0), plan, *operands)
 
-    def every(plan, *operands):
-        pad = -plan.valid.shape[0] % small      # whole windows
-        if pad:
-            plan = plan._replace(**{
-                name: jnp.pad(getattr(plan, name), (0, pad))
-                for name in ("row_token", "row_entry", "valid")})
-        total = lax.fori_loop(
-            0, (plan.group_sizes.sum() + small - 1) // small,
-            lambda w, total: jax.tree_util.tree_map(
-                lambda t, part: t + part.astype(t.dtype), total,
-                window(w * small, plan, *operands)),
-            jax.tree_util.tree_map(
-                lambda a: jnp.zeros(a.shape, jnp.float32), like))
-        return jax.tree_util.tree_map(lambda t, a: t.astype(a.dtype),
-                                      total, like)
-
-    return lax.cond(plan.group_sizes.sum() <= small, first, every,
+    return lax.cond(_fits(plan, small), first,
+                    _partial(_every_window, window, like, small),
                     plan, *operands)
 
 
-@_partial(jax.custom_vjp, nondiff_argnums=(6,))
-def _routed_two_plans(x, gates, w1, w3, w2, plan: HeldRouting, small: int):
+@_partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _routed_two_plans(x, gates, w1, w3, w2, plan: HeldRouting, small: int,
+                      kept: bool):
     """:func:`_routed_rows` over the smaller plan or over the bound by
     windows (:func:`_first_window_or_every`). The choice lies outside the
     residual path: differentiating through a ``lax.cond`` makes the
-    residuals of BOTH branches its outputs, so the forward returns ``y``
-    alone, saves its inputs, and the backward recomputes and transposes
-    inside the branch it takes."""
+    residuals of BOTH branches its outputs. So the forward returns ``y``
+    and, with ``kept``, the first window's rows and products named
+    :data:`PRODUCTS` (zeros of their shapes from the other branch), and
+    saves its inputs; the backward transposes inside the branch it takes,
+    the first window from its kept products (:func:`_window_grads`), the
+    windows of the other branch by making them again."""
     return _first_window_or_every(
-        _partial(_routed_window, small),
+        _partial(_routed_window, small, False),
         jax.ShapeDtypeStruct(x.shape, jnp.float32), plan, small,
         x, gates, w1, w3, w2)
 
 
-def _routed_two_plans_fwd(x, gates, w1, w3, w2, plan, small):
-    return _routed_two_plans(x, gates, w1, w3, w2, plan, small), \
-        (x, gates, w1, w3, w2, plan)
+def _routed_two_plans_fwd(x, gates, w1, w3, w2, plan, small, kept):
+    args = (x, gates, w1, w3, w2)
+    if not kept:
+        return _routed_two_plans(*args, plan, small, kept), \
+            (*args, plan, None)
+
+    def first(plan, *args):
+        return _routed_window(small, True, jnp.int32(0), plan, *args)
+
+    like = jax.eval_shape(first, plan, *args)
+
+    def every(plan, *args):
+        return (_every_window(_partial(_routed_window, small, False),
+                              like[0], small, plan, *args),
+                jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype),
+                                       like[1]))
+
+    y, products = lax.cond(_fits(plan, small), first, every, plan, *args)
+    return y, (*args, plan, checkpoint_name(products, PRODUCTS))
 
 
-def _routed_two_plans_bwd(small, res, dy):
-    *args, plan = res
+def _routed_two_plans_bwd(small, kept, res, dy):
+    *args, plan, products = res
 
     def pull(start, plan, dy, *args):
-        return jax.vjp(_partial(_routed_window, small, start, plan),
+        return jax.vjp(_partial(_routed_window, small, False, start, plan),
                        *args)[1](dy)
 
+    if products is None:
+        grads = _first_window_or_every(pull, tuple(args), plan, small, dy,
+                                       *args)
+    else:
+        # Two conditionals on the one predicate: an operand of a
+        # conditional is live through either branch, so with one the kept
+        # products would lie beside the other windows' loop (0.59 GB more
+        # of the conv cell's step, by the compiler's account).
+        like, fits = tuple(args), _fits(plan, small)
+        first = lax.cond(
+            fits, lambda plan, products, dy, x, *rest: _window_grads(
+                small, plan, products, dy, *rest),
+            lambda *_: jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, a.dtype), like),
+            plan, products, dy, *args)
+        grads = lax.cond(
+            fits, lambda first, plan, dy, *args: first,
+            lambda first, plan, dy, *args: _every_window(
+                pull, like, small, plan, dy, *args),
+            first, plan, dy, *args)
     # The barrier keeps XLA from moving the optimizer's float32 copy and
     # square of each weight gradient into both branches, where they become
     # outputs that are all live at once (0.28 GB more of the step's memory
     # at 16 experts of 2048 x 768).
-    grads = lax.optimization_barrier(_first_window_or_every(
-        pull, tuple(args), plan, small, dy, *args))
+    grads = lax.optimization_barrier(grads)
     return (*grads, _no_tangent(plan))
 
 
@@ -639,7 +744,7 @@ _routed_two_plans.defvjp(_routed_two_plans_fwd, _routed_two_plans_bwd)
 
 
 def dropless_share(x, router_logits, bias, w1, w3, w2, *, first: int,
-                   top_k: int, scaling: float):
+                   top_k: int, scaling: float, keep_products: bool = True):
     """The routed part of an expert layer that this rank's experts give:
     ``sum over chosen experts i in [first, first + held) of g_i * E_i(x)``.
     No capacity and no dropped pair, whatever the imbalance: the plan's
@@ -651,6 +756,11 @@ def dropless_share(x, router_logits, bias, w1, w3, w2, *, first: int,
     under a rematerialisation that saves it the backward reuses the
     forward's route instead of choosing and placing again, and only what
     the gates' gradient reads of the scores is made again.
+    ``keep_products`` says whether the backward gets the small plan's rows
+    and grouped products (:data:`PRODUCTS`) from the forward: true where
+    nothing rematerialises the layer or the policy saves that name, so that
+    no product is made twice; false where a rematerialisation would make
+    them again anyway, and the backward makes them itself.
 
     x [T, D]; router_logits [T, E] over ALL experts; bias [E]; w1, w3
     [held, D, M], w2 [held, M, D]. Returns ``(y [T, D] float32, counts [E]
@@ -672,7 +782,11 @@ def dropless_share(x, router_logits, bias, w1, w3, w2, *, first: int,
     if small == pairs:
         y = _routed_rows(x, gates, w1, w3, w2, plan)
     else:
-        y = _routed_two_plans(x, gates, w1, w3, w2, plan, small)
+        # trace-time, one a traced layer with a second plan
+        _telemetry.inc("hvd_moe_products_total",
+                       saved=str(keep_products).lower())
+        y = _routed_two_plans(x, gates, w1, w3, w2, plan, small,
+                              keep_products)
     return y, counts, plan.group_sizes.astype(jnp.float32)
 
 

@@ -194,6 +194,27 @@ def test_loss_and_every_gradient_agree_with_the_reference(over):
                                    err_msg=name)
 
 
+def test_the_backward_keeps_the_forwards_products_under_dots_attn():
+    """Three expert layers, three held of eight experts at two choices: 96
+    pairs, a plan of 72 rows."""
+    from horovod_tpu.parallel import moe
+    from test_latent_moe import check_products_kept
+    tokens, labels = _tokens(0, 3, 16)
+    assert moe.plan_rows(2 * 48, 3, 8) == 72
+
+    def build(policy):
+        c = conv_moe_tiny(remat=True, remat_policy=policy)
+        variables = _init(c, tokens)
+        model = ConvMoE(c)
+
+        def loss(params):
+            out, _ = model.apply({**variables, "params": params}, tokens,
+                                 train=True, mutable=["batch_stats"])
+            return next_tokens_loss()(out, labels)
+        return loss, variables["params"]
+    check_products_kept(build, 3)
+
+
 def test_the_flash_path_with_qk_norm_agrees_with_the_reference():
     """The attention layer through the Pallas kernels (interpret mode), q and
     k normed a head before them, under the cell's policy that saves the

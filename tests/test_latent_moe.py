@@ -209,11 +209,11 @@ def _plan_case(held, filled=None, biased=0, T=PLAN_T, routed=PLAN_E):
     return (x, logits, w1, w3, w2), bias
 
 
-def _share_with_grads(bias, top_k=PLAN_K):
+def _share_with_grads(bias, top_k=PLAN_K, kept=True):
     def share(x, logits, w1, w3, w2):
         y, counts, rows = moe.dropless_share(
             x, logits, bias, w1, w3, w2, first=PLAN_FIRST, top_k=top_k,
-            scaling=2.5)
+            scaling=2.5, keep_products=kept)
         return (y ** 2).sum(), (y, counts, rows)
     return jax.value_and_grad(share, argnums=(0, 1, 2, 3, 4), has_aux=True)
 
@@ -232,6 +232,66 @@ def _subjaxprs(jaxpr):
 def _eqns(jaxpr, primitive):
     return [eqn for sub in _subjaxprs(jaxpr) for eqn in sub.eqns
             if eqn.primitive.name == primitive]
+
+
+def check_products_kept(build, layers):
+    """``build(policy)`` -> ``(loss of the parameters, parameters)`` of a
+    model with ``layers`` expert layers whose plan is smaller than the
+    pairs, rematerialised under ``policy``. Under the policy that keeps the
+    small plan's products (``dots_attn``) that branch of a layer's backward
+    holds the six products' transposes and no product (6 grouped products,
+    not 9; its other branch, the windows made again, lies in a second
+    conditional), and no forward conditional runs twice; the gradients are
+    those of the policy that makes the products again (``dots``); the
+    counter says which once a traced layer."""
+    from horovod_tpu.core import telemetry as T
+    counted = lambda saved: T.active().registry.counter_value(
+        "hvd_moe_products_total", saved=saved)
+    found = {}
+    for policy in ("dots_attn", "dots"):
+        loss, params = build(policy)
+        before = {s: counted(s) for s in ("true", "false")}
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(params).jaxpr
+        saved = {s: counted(s) - before[s] for s in before}
+        # the first window's branch (index = the predicate) of each
+        # conditional, forward and backward
+        products = sorted(
+            len(_eqns(cond.params["branches"][1].jaxpr,
+                      "ragged_dot_general"))
+            for cond in _eqns(jaxpr, "cond"))
+        found[policy] = saved, products, jax.jit(jax.grad(loss))(params)
+    saved, products, grads = found["dots_attn"]
+    assert saved == {"true": layers, "false": 0}
+    assert products == [0] * layers + [3] * layers + [6] * layers
+    saved, products, want = found["dots"]
+    assert saved == {"true": 0, "false": layers}
+    assert products == [3] * layers + [9] * layers
+    got, want = named_leaves(grads), named_leaves(want)
+    assert set(got) == set(want)
+    for name, w in want.items():
+        assert float(np.abs(w).max()) > 0, name
+        np.testing.assert_allclose(got[name], w, rtol=1e-6,
+                                   atol=1e-7 * float(np.abs(w).max()),
+                                   err_msg=name)
+
+
+def test_the_backward_keeps_the_forwards_products_under_dots_attn():
+    """Two trunk expert layers and the module's, two held of eight experts
+    at three choices: 144 pairs, a plan of 72 rows."""
+    tokens, labels = _tokens(0, 3, 16)
+    assert moe.plan_rows(3 * 48, 2, 8) == 72
+
+    def build(policy):
+        c = latent_moe_tiny(remat=True, remat_policy=policy)
+        variables, _ = _init(c, tokens)
+        stats = _stats(_biases(reference.build(reference_config(c)), 5))
+
+        def loss(params):
+            out = LatentMoE(c).apply({"params": params, "batch_stats": stats},
+                                     tokens, train=False)
+            return next_tokens_loss(0.3)(out, labels)
+        return loss, variables["params"]
+    check_products_kept(build, 3)
 
 
 @pytest.mark.parametrize("T, k, held, filled, biased, small, fits, routed", [
@@ -281,33 +341,46 @@ def test_both_plans_give_the_masked_loops_layer_and_drop_nothing(
     assert float(np.abs(y).max()) > 0
     for g, w in zip(grads, want_grads):
         np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4)
-    # one conditional forward and one backward, or today's program
+    # one conditional forward and two backward (the first window from the
+    # kept products, the other windows made again), or today's program
     assert len(_eqns(jax.make_jaxpr(share)(*args).jaxpr, "cond")) \
-        == (2 if small < k * T else 0)
+        == (3 if small < k * T else 0)
 
 
-def test_no_branch_holds_an_array_of_the_bounds_rows():
+@pytest.mark.parametrize("kept", [True, False],
+                         ids=["products_kept", "products_made_again"])
+def test_no_branch_holds_an_array_of_the_bounds_rows(kept):
     """Forward and backward, the ``filled <= plan_rows`` branch works on
     ``[1024, .]`` rows with no loop, the other on windows of as many, and
     nothing in either has the bound's 4,096; and the forward conditional
-    returns ``y`` alone, so differentiation has made no residual of either
-    branch its output."""
+    returns ``y`` and, where they are kept, the first window's rows and
+    products (zeros of their shapes from the other branch), so
+    differentiation has made no residual of either branch its output."""
     args, bias = _plan_case(2)
-    forward, backward = sorted(
-        _eqns(jax.make_jaxpr(_share_with_grads(bias))(*args).jaxpr, "cond"),
-        key=lambda cond: len(cond.outvars))
-    assert [v.aval.shape for v in forward.outvars] == [(PLAN_T, PLAN_D)]
-    assert [v.aval.shape for v in backward.outvars] == [
-        args[0].shape, (PLAN_T, PLAN_K), *(a.shape for a in args[2:])]
-    for cond in (forward, backward):
-        every, first = cond.params["branches"]      # index = the predicate
-        for branch in (every, first):
-            rows = {v.aval.shape[0] for sub in _subjaxprs(branch.jaxpr)
+    small = moe.plan_rows(PLAN_T * PLAN_K, 2, PLAN_E)
+    shapes = lambda cond: [v.aval.shape for v in cond.outvars]
+    grads = [args[0].shape, (PLAN_T, PLAN_K), *(a.shape for a in args[2:])]
+    conds = _eqns(jax.make_jaxpr(_share_with_grads(bias, kept=kept))(
+        *args).jaxpr, "cond")
+    # with the products kept, the backward's two branches are two
+    # conditionals on the one predicate
+    backward = [cond for cond in conds if shapes(cond) == grads]
+    forward, = [cond for cond in conds
+                if all(cond is not other for other in backward)]
+    assert len(backward) == 1 + kept
+    assert shapes(forward) == [(PLAN_T, PLAN_D)] + kept * [
+        (small, PLAN_D), (small, PLAN_M), (small, PLAN_M), (small, PLAN_D)]
+    for direction in ([forward], backward):
+        # index = the predicate
+        every, first = zip(*(cond.params["branches"] for cond in direction))
+        for branches in (every, first):
+            rows = {v.aval.shape[0] for branch in branches
+                    for sub in _subjaxprs(branch.jaxpr)
                     for eqn in sub.eqns for v in (*eqn.invars, *eqn.outvars)
                     if len(getattr(v.aval, "shape", ())) >= 2}
             assert 1024 in rows and PLAN_T * PLAN_K not in rows
-        assert len(_eqns(every.jaxpr, "while")) == 1
-        assert not _eqns(first.jaxpr, "while")
+        assert sum(len(_eqns(b.jaxpr, "while")) for b in every) == 1
+        assert not any(_eqns(b.jaxpr, "while") for b in first)
 
 
 def _pair_arrays(jaxpr, k, width):
@@ -346,11 +419,17 @@ def test_the_token_side_takes_the_form_the_windows_rows_give(
         assert not conds
         assert [e.primitive.name for e in pairs].count("gather") == 3
         return
-    assert len(conds) == 2
+    assert len(conds) == 3
+    working = 0
     for cond in conds:
         for branch in cond.params["branches"]:
             pairs = _pair_arrays(branch.jaxpr, PLAN_K, PLAN_D)
-            assert bool(pairs) == (form == "pairs")
+            # the backward's other two branches (zeros, and the first
+            # window's gradients passed on) compute nothing
+            works = bool(_eqns(branch.jaxpr, "ragged_dot_general"))
+            working += works
+            assert bool(pairs) == (works and form == "pairs")
+    assert working == 4
 
 
 def _window_case(name):

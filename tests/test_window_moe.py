@@ -29,7 +29,7 @@ from horovod_tpu.models.window_moe import (FULL, SLIDING, GatedAttention,
 from horovod_tpu.optimizer import distributed
 from horovod_tpu.parallel import moe
 from horovod_tpu.train import create_train_state, make_train_step
-from test_latent_moe import _eqns
+from test_latent_moe import _eqns, check_products_kept
 
 OPTIMIZER = {"name": "adamw", "learning_rate": 1e-3, "b1": 0.9, "b2": 0.95,
              "eps": 1e-8, "weight_decay": 0.1}
@@ -378,6 +378,26 @@ def test_the_route_is_made_once_under_dots_attn():
                                    err_msg=name)
     router = [n for n in got if n.endswith("router/kernel")]
     assert len(router) == 2 and all(np.abs(got[n]).max() > 0 for n in router)
+
+
+def test_the_backward_keeps_the_forwards_products_under_dots_attn():
+    """Three expert layers, two held of eight experts at three choices:
+    144 pairs, a plan of 72 rows."""
+    tokens, labels = _tokens(5, 3, 16)
+    assert moe.plan_rows(3 * 48, 2, 8) == 72
+
+    def build(policy):
+        c = window_moe_tiny(remat=True, remat_policy=policy,
+                            experts_held=2, first_expert=4)
+        variables = _init(c, tokens)
+        model = WindowMoE(c)
+
+        def loss(params):
+            out, _ = model.apply({**variables, "params": params}, tokens,
+                                 train=True, mutable=["batch_stats"])
+            return next_tokens_loss()(out, labels)
+        return loss, variables["params"]
+    check_products_kept(build, 3)
 
 
 def test_yarns_32_frequencies_are_the_published_schemes():
