@@ -12,7 +12,8 @@ def __getattr__(name):
     lazy = {"bert": ".bert", "llama": ".llama", "mixtral": ".mixtral",
             "dlrm": ".dlrm", "decode": ".decode",
             "latent_moe": ".latent_moe", "looped": ".looped",
-            "window_moe": ".window_moe", "conv_moe": ".conv_moe"}
+            "window_moe": ".window_moe", "conv_moe": ".conv_moe",
+            "delta_moe": ".delta_moe"}
     for mod, path in lazy.items():
         if name == mod:
             try:

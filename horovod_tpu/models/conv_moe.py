@@ -146,21 +146,25 @@ def conv_moe_tiny(vocab: int = 128, **over) -> ConvMoEConfig:
         dtype=jnp.float32, remat=False), **over})
 
 
-def gated_short_conv(bcu, taps):
-    """``C * conv(B * u)`` of an input projection ``bcu [..., T, 3D]``
-    (``B``, ``C``, ``u`` in that order along the features) with depthwise
-    causal ``taps [L, D]``: ``z_t = sum_j taps[j] * v_{t - (L - 1) + j}``
-    over ``v = B * u``, zero before position 0 (PyTorch's
-    ``Conv1d(groups=D, padding=L - 1)`` cut to ``T``). Shifted products and
-    sums, in the input's type."""
-    b, c, u = jnp.split(bcu, 3, axis=-1)
-    v = b * u
+def causal_taps(v, taps):
+    """The depthwise causal convolution of ``v [..., T, D]`` with ``taps
+    [L, D]``: ``z_t = sum_j taps[j] * v_{t - (L - 1) + j}``, zero before
+    position 0 (PyTorch's ``Conv1d(groups=D, padding=L - 1)`` cut to
+    ``T``). Shifted products and sums, in the input's type."""
     T, L = v.shape[-2], taps.shape[0]
     padded = jnp.pad(v, [(0, 0)] * (v.ndim - 2) + [(L - 1, 0), (0, 0)])
     z = taps[0] * padded[..., :T, :]
     for j in range(1, L):
         z = z + taps[j] * padded[..., j:j + T, :]
-    return c * z
+    return z
+
+
+def gated_short_conv(bcu, taps):
+    """``C * conv(B * u)`` of an input projection ``bcu [..., T, 3D]``
+    (``B``, ``C``, ``u`` in that order along the features) with depthwise
+    causal ``taps [L, D]`` (:func:`causal_taps` over ``v = B * u``)."""
+    b, c, u = jnp.split(bcu, 3, axis=-1)
+    return c * causal_taps(b * u, taps)
 
 
 def _dense(c, features, names, name):

@@ -10,11 +10,16 @@ Named for its mechanisms, configured by the keys of the published
   position shared by all heads; query/key depth ``qk_nope_head_dim +
   qk_rope_head_dim``, value depth ``v_head_dim``. In training the keys and
   values are expanded per head and go through the flash kernels, which take
-  a value depth of their own (``ops/flash_attention.py``).
+  a value depth of their own (``ops/flash_attention.py``). Off by default
+  and used by ``models/delta_moe.py``: a direct query projection
+  (``q_lora_rank`` None), an RMSNorm a head over q's and k's depths before
+  the rotary embedding (``use_qk_norm``), and a sigmoid gate a head and
+  position on the output (``head_gate``, ``window_moe.head_gate``).
 - **Layer pattern**: ``first_k_dense_replace`` dense SwiGLU layers, then
   expert layers, unrolled.
 - **Expert layer**: ``parallel/moe.py dropless_share`` (float32 sigmoid
-  scores over ALL ``n_routed_experts``, top-k of score + bias, gates
+  scores over ALL ``n_routed_experts``, top-k of score + bias within the
+  ``topk_group`` best of ``n_group`` groups, gates
   renormalised and scaled, no capacity) for the experts this rank holds,
   ``[first_expert, first_expert + experts_held)``, plus the shared expert on
   every token. The selection bias and the per-expert token counts are model
@@ -59,7 +64,7 @@ class LatentMoEConfig:
     num_hidden_layers: int = 40
     first_k_dense_replace: int = 1
     num_attention_heads: int = 32
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536   # None: one direct query projection
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -69,8 +74,14 @@ class LatentMoEConfig:
     n_routed_experts: int = 256         # the router's width
     n_shared_experts: int = 1
     num_experts_per_tok: int = 8
+    n_group: int = 1
+    topk_group: int = 1
     routed_scaling_factor: float = 2.5
     num_nextn_predict_layers: int = 1
+    # an RMSNorm a head over q's and k's depths before the rotary
+    # embedding, and a sigmoid gate a head and position on the output
+    use_qk_norm: bool = False
+    head_gate: bool = False
     # this rank's share of each expert layer (None: every expert)
     experts_held: Optional[int] = None
     first_expert: int = 0
@@ -131,20 +142,29 @@ class LatentAttention(nn.Module):
                          c.qk_rope_head_dim, c.v_head_dim)
         norm = lambda name: RMSNorm(c.rms_norm_eps, c.dtype, name=name)
         with jax.named_scope("hvd_mla"):
-            cq = norm("q_norm")(_dense(c, c.q_lora_rank, ("embed", None),
-                                       "wq_a")(x))
-            q = _dense(c, H * (dn + dr), (None, "heads"), "wq_b")(cq)
+            if c.q_lora_rank is None:
+                q = _dense(c, H * (dn + dr), ("embed", "heads"), "wq")(x)
+            else:
+                cq = norm("q_norm")(_dense(c, c.q_lora_rank, ("embed", None),
+                                           "wq_a")(x))
+                q = _dense(c, H * (dn + dr), (None, "heads"), "wq_b")(cq)
             q = q.reshape(B, T, H, dn + dr)
             kva = _dense(c, c.kv_lora_rank + dr, ("embed", None), "wkv_a")(x)
             ckv = norm("kv_norm")(kva[..., :c.kv_lora_rank])
             k_rope = kva[..., c.kv_lora_rank:].reshape(B, T, 1, dr)
             kv = _dense(c, H * (dn + dv), (None, "heads"), "wkv_b")(ckv)
             kv = kv.reshape(B, T, H, dn + dv)
+            k_nope = kv[..., :dn]
+            if c.use_qk_norm:
+                q = norm("q_head_norm")(q)
+                k = norm("k_head_norm")(jnp.concatenate(
+                    [k_nope, jnp.broadcast_to(k_rope, (B, T, H, dr))], -1))
+                k_nope, k_rope = k[..., :dn], k[..., dn:]
             q = jnp.concatenate([
                 q[..., :dn],
                 rope_interleaved(q[..., dn:], positions, c.rope_theta)], -1)
             k = jnp.concatenate([
-                kv[..., :dn],
+                k_nope,
                 jnp.broadcast_to(
                     rope_interleaved(k_rope, positions, c.rope_theta),
                     (B, T, H, dr))], -1)
@@ -162,6 +182,11 @@ class LatentAttention(nn.Module):
                 from jax.ad_checkpoint import checkpoint_name
                 o = checkpoint_name(
                     jnp.einsum("bhqk,bkhd->bqhd", p, v), "attn_out")
+            if c.head_gate:
+                from .window_moe import head_gate
+                with jax.named_scope("hvd_head_gate"):
+                    g = head_gate(_dense(c, H, ("embed", "heads"), "wg")(x))
+                    o = o * g[..., None].astype(o.dtype)
             return _dense(c, c.hidden_size, ("heads", "embed"), "wo")(
                 o.reshape(B, T, H * dv))
 
@@ -212,7 +237,8 @@ class ExpertLayer(nn.Module):
             tokens, logits, bias.value, w1.astype(c.dtype),
             w3.astype(c.dtype), w2.astype(c.dtype), first=c.first_expert,
             top_k=c.num_experts_per_tok, scaling=c.routed_scaling_factor,
-            keep_products=saved(PRODUCTS))
+            keep_products=saved(PRODUCTS), n_group=c.n_group,
+            topk_group=c.topk_group)
         # trace-time, one a traced layer
         _telemetry.inc("hvd_moe_route_total", placement="count",
                        saved=str(saved(ROUTE)).lower())
